@@ -18,10 +18,10 @@ These rules enforce that contract statically:
 - PT302: ``pl.BlockSpec`` block shapes built from ``min(...)``/
   ``max(...)`` clamps without a ``%`` guard — "merely fits" is exactly
   the pre-fix varlen bug.
-- PT303: version-fragile ``pltpu`` attribute access: jax renamed
-  ``TPUCompilerParams`` -> ``CompilerParams``; direct attribute use of
-  either breaks on the other side of the rename (use the getattr
-  pattern in ops/pallas/flash_attention.py `_dim_semantics`).
+- PT303: ``pltpu.TPUCompilerParams`` — the name jax dropped when it
+  renamed the class to ``CompilerParams``; it does not exist on the
+  installed jax (pyproject.toml pins the floor), so any use is an
+  AttributeError waiting for its first call.
 """
 from __future__ import annotations
 
@@ -30,7 +30,7 @@ from typing import Optional
 
 from .engine import call_name, rule
 
-_PLTPU_RENAMED = {"CompilerParams", "TPUCompilerParams"}
+_PLTPU_REMOVED = {"TPUCompilerParams"}
 
 
 # ---------------------------------------------------------------------------
@@ -284,16 +284,13 @@ def check_blockspec_clamp(mod):
 
 
 @rule("PT303", "warning",
-      "version-fragile pltpu attribute (TPUCompilerParams/CompilerParams "
-      "rename) used directly")
+      "pltpu.TPUCompilerParams: removed from jax (renamed CompilerParams)")
 def check_pltpu_renamed_attr(mod):
     for node in ast.walk(mod.tree):
         if isinstance(node, ast.Attribute) and \
                 isinstance(node.value, ast.Name) and \
                 node.value.id == "pltpu" and \
-                node.attr in _PLTPU_RENAMED:
+                node.attr in _PLTPU_REMOVED:
             yield (node.lineno, node.col_offset,
-                   f"direct 'pltpu.{node.attr}' breaks across the jax "
-                   f"TPUCompilerParams->CompilerParams rename; resolve "
-                   f"via getattr with a fallback "
-                   f"(ops/pallas/flash_attention.py _dim_semantics)")
+                   f"'pltpu.{node.attr}' does not exist on the installed "
+                   f"jax; use pltpu.CompilerParams")

@@ -21,7 +21,6 @@ from ...core.dispatch import apply
 from ...core.tensor import Tensor
 from .. import collective
 from ..topology import get_hybrid_communicate_group
-from ...utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["ScatterOp", "GatherOp", "AllGatherOp", "ReduceScatterOp",
            "ColumnSequenceParallelLinear", "RowSequenceParallelLinear",
@@ -59,7 +58,7 @@ class ScatterOp(PyLayer):
         def fn(x):
             if _in_shard_map(x, ax_name):
                 idx = jax.lax.axis_index(ax_name)
-                size = x.shape[axis] // _axis_size(ax_name)
+                size = x.shape[axis] // jax.lax.axis_size(ax_name)
                 return jax.lax.dynamic_slice_in_dim(x, idx * size, size,
                                                     axis)
             return x
@@ -96,7 +95,7 @@ class GatherOp(PyLayer):
         def fn(g):
             if _in_shard_map(g, ctx.ax_name):
                 idx = jax.lax.axis_index(ctx.ax_name)
-                size = g.shape[ctx.axis] // _axis_size(ctx.ax_name)
+                size = g.shape[ctx.axis] // jax.lax.axis_size(ctx.ax_name)
                 return jax.lax.dynamic_slice_in_dim(
                     g, idx * size, size, ctx.axis)
             return g

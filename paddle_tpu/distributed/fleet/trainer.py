@@ -77,9 +77,17 @@ class HybridTrainer:
             lambda s: NamedSharding(mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P))
 
-        # init directly INTO the sharded layout (no host-side full copy)
+        self._init_state(seed)
+        self.step_count = 0
+        self._compiled = self._build()
+
+    def _init_state(self, seed: int):
+        """Materialize `params` and `opt_state` directly INTO the sharded
+        layout (no host-side full copy). The one step of construction
+        that needs real devices: tests/test_tpu_compile.py overrides it
+        with shapes to compile the step for a described chip."""
         init = jax.jit(
-            functools.partial(llama_mod.init_stacked_params, config),
+            functools.partial(llama_mod.init_stacked_params, self.config),
             out_shardings=self.param_shardings)
         self.params = init(jax.random.key(seed))
         self.opt_state = jax.jit(
@@ -92,8 +100,6 @@ class HybridTrainer:
             out_shardings={"m": self.param_shardings,
                            "v": self.param_shardings},
         )(self.params)
-        self.step_count = 0
-        self._compiled = self._build()
 
     def _build(self):
         cfg = self.config
@@ -114,10 +120,11 @@ class HybridTrainer:
                     p, (input_ids, labels), cfg, mesh, remat=remat,
                     overlap_sends=overlap_sends)
             else:
-                # sep>1: ring-attention context parallel inside the trunk
-                sep_mesh = mesh if mesh.shape.get("sep", 1) > 1 else None
+                # the mesh rides along: sep>1 runs ring-attention context
+                # parallel inside the trunk, and the Pallas kernels run
+                # per shard on a multi-device mesh
                 loss_of = lambda p: llama_mod.loss_fn_stacked(  # noqa: E731
-                    p, (input_ids, labels), cfg, remat=remat, mesh=sep_mesh)
+                    p, (input_ids, labels), cfg, remat=remat, mesh=mesh)
             loss, grads = jax.value_and_grad(loss_of)(params)
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
             if clip is not None:
@@ -241,8 +248,10 @@ class HybridTrainer:
                            on_restore=self.load_elastic_state,
                            start_step=self.step_count)
 
-    def lower_text(self, batch_shape):
-        """Compiled HLO text (for inspection/debugging of sharding)."""
+    def lower(self, batch_shape):
+        """The train step lowered for `batch_shape` ([B, S]) without
+        running it: `.as_text()` shows the program (shardings, kernels),
+        `.compile().memory_analysis()` what it needs on each device."""
         if self.pipelined and len(batch_shape) == 2:
             b, s = batch_shape
             if b % self.n_micro != 0:
@@ -250,8 +259,11 @@ class HybridTrainer:
                     f"batch {b} not divisible by "
                     f"pipeline_micro_batches={self.n_micro}")
             batch_shape = (self.n_micro, b // self.n_micro, s)
-        ids = jnp.zeros(batch_shape, jnp.int32)
+        spec = llama_mod.microbatch_spec() if self.pipelined \
+            else data_spec()
+        ids = jax.ShapeDtypeStruct(
+            batch_shape, jnp.int32,
+            sharding=NamedSharding(self.mesh, spec))
+        scalar = jax.ShapeDtypeStruct((), jnp.float32)
         return self._compiled.lower(
-            self.params, self.opt_state, ids, ids,
-            jnp.asarray(self.lr, jnp.float32),
-            jnp.asarray(1.0, jnp.float32)).as_text()
+            self.params, self.opt_state, ids, ids, scalar, scalar)

@@ -45,7 +45,10 @@ def parse_args(argv=None):
                         help="node rank (default: assigned by the store)")
     parser.add_argument("--nproc_per_node", type=int, default=1,
                         help="worker processes per node (1 = "
-                             "single-controller over all local chips)")
+                             "single-controller over all local chips; "
+                             "more than 1 is refused on a TPU host "
+                             "unless JAX_PLATFORMS keeps workers off "
+                             "the chips)")
     parser.add_argument("--devices", "--gpus", "--xpus", default=None,
                         help="accepted for reference compat; TPU chips are "
                              "addressed by the controller process")
@@ -73,6 +76,41 @@ def parse_args(argv=None):
     parser.add_argument("training_script")
     parser.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return parser.parse_args(argv)
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device nodes
+    (/dev/accel<n>, or /dev/vfio/<n> as the v5e host has them): the
+    launcher parent never imports JAX (a process that touches JAX takes
+    the chip from its workers). A vfio node is any passed-through device,
+    so this can count what is no TPU; the refusal says how it counted."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) \
+        or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_one_process_per_chip(nproc_per_node: int, env) -> None:
+    """A chip belongs to one process at a time. Several local workers on
+    a chip host would all open every local chip and fight for them, so
+    the launcher refuses — unless the workers are told to stay off the
+    chip (JAX_PLATFORMS without "tpu", as the CPU test suite sets)."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if nproc_per_node <= 1:
+        return
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"[launch] refusing --nproc_per_node {nproc_per_node} on a "
+            f"host with {chips} TPU chip(s) (device nodes /dev/accel<n> "
+            f"or /dev/vfio/<n>; JAX is not asked): every worker would "
+            f"open all local chips, and a chip belongs to one process at "
+            f"a time. Run one worker per host (--nproc_per_node 1; one "
+            f"controller process drives all local chips). If the workers "
+            f"are to run on the CPU, or those nodes are no TPU chips, say "
+            f"where they run: JAX_PLATFORMS=cpu.")
 
 
 class Pod:
@@ -238,7 +276,6 @@ class Controller:
             "PADDLE_MASTER": self.args.master
             or f"127.0.0.1:{self.store.port}",
             "PADDLE_ELASTIC_GENERATION": str(self.generation),
-            "FLAGS_selected_tpus": "all",
         })
         # elastic-supervisor contract (distributed/resilience/supervisor):
         # restart budget follows the launcher's, and a worker spawned
@@ -454,6 +491,7 @@ class Controller:
 
 def launch(argv=None) -> int:
     args = parse_args(argv)
+    check_one_process_per_chip(args.nproc_per_node, os.environ)
     return Controller(args).run()
 
 

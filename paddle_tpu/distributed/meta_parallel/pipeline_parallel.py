@@ -28,7 +28,6 @@ from ...core.tensor import Tensor
 from ...nn.layer.layers import Layer
 from ...profiler import metrics as _metrics
 from .pp_layers import PipelineLayer
-from ...utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["PipelineParallel", "PipelineParallelWithInterleave",
            "PipelineParallelZeroBubble", "spmd_pipeline",
@@ -362,7 +361,7 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x, n_micro: int,
     otherwise the call falls back to the unsplit schedule.  Numerics
     are identical either way (the halves are independent rows).
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     n_steps = n_micro + p - 1
     mb_shape = x.shape[1:]
@@ -427,7 +426,7 @@ def spmd_pipeline_interleaved(stage_fn: Callable, chunked_params, x,
     x              : [n_micro, mb, ...] (consumed on stage 0)
     Returns [n_micro, mb, ...] outputs valid on the LAST stage.
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     v = n_chunks
     q = p * v

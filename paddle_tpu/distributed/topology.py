@@ -44,6 +44,28 @@ def build_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1, devices=None) -> Mesh:
     return Mesh(dev_array, _AXES)
 
 
+def _hybrid_device_array(ici, dcn, devices=None):
+    """Device array for a two-tier mesh: per axis the extent is
+    ``ici[i] * dcn[i]`` with the dcn factor outermost. Devices that carry
+    a ``slice_index`` (multi-slice TPU) go through jax's own
+    ``create_hybrid_device_mesh``; hosts whose devices have none (the CPU
+    simulation) get a plain row-major reshape — the axis ORDER is
+    preserved, which is all the static analyses consume."""
+    devices = jax.devices() if devices is None else devices
+    if hasattr(devices[0], "slice_index"):
+        from jax.experimental import mesh_utils
+
+        return mesh_utils.create_hybrid_device_mesh(
+            tuple(ici), tuple(dcn), devices=devices)
+    shape = tuple(int(d) * int(i) for i, d in zip(ici, dcn))
+    total = int(np.prod(shape))
+    if total > len(devices):
+        raise ValueError(
+            f"hybrid topology {shape} needs {total} devices, "
+            f"have {len(devices)}")
+    return np.asarray(devices[:total]).reshape(shape)
+
+
 def build_hybrid_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1,
                       dcn_dp=1, dcn_pp=1, dcn_sharding=1,
                       devices=None) -> Mesh:
@@ -60,11 +82,9 @@ def build_hybrid_mesh(dp=1, pp=1, sharding=1, sep=1, mp=1,
     tier the PT9xx reshard cost estimates (PT901 messages name the tier
     so a spec typo on a two-tier mesh is diagnosable from the text).
     """
-    from ..utils.jax_compat import hybrid_device_mesh
-
     ici = (dp, pp, sharding, sep, mp)
     dcn = (dcn_dp, dcn_pp, dcn_sharding, 1, 1)
-    dev_array = hybrid_device_mesh(ici, dcn, devices=devices)
+    dev_array = _hybrid_device_array(ici, dcn, devices)
     mesh = Mesh(dev_array, _AXES)
     dcn_axes = tuple(n for n, d in zip(_AXES, dcn) if int(d) > 1)
     try:

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 from ...core.dispatch import apply
 from ...core.tensor import Tensor
 from .. import collective
-from ...utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["global_scatter", "global_gather"]
 
@@ -20,7 +19,7 @@ def global_scatter(x, local_count, global_count, group=None):
 
     def fn(v, lc, gc):
         if collective._in_shard_map(v, group):
-            n = _axis_size(ax)
+            n = jax.lax.axis_size(ax)
             per = v.shape[0] // n
             return jax.lax.all_to_all(
                 v.reshape(n, per, *v.shape[1:]), ax, 0, 0, tiled=False

@@ -219,15 +219,17 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     # cu_seqlens pattern (the per-segment fallback below compiles one
     # program per pattern). Identical q/k layouts make the kernel's
     # packed-position causal exactly FA2's per-segment causal.
+    from ....ops import pallas as _pallas
     from ....ops.pallas import varlen_attention as VA
-    from ....ops.pallas import use_pallas as _use_pallas
 
     d_head = int(query.shape[-1])
     kernel_ok = ((dropout == 0.0 or not training)
                  and scale is None
-                 and (_use_pallas() or VA._interpret())
+                 and _pallas.kernels_enabled()
                  and d_head % 64 == 0
                  and np.array_equal(cq, ck))
+    if not kernel_ok and _pallas.kernels_enabled():
+        _pallas.note_reference_dispatch("flash_attn_unpadded")
     if kernel_ok:
         total = int(query.shape[0])
         padded = 128 * ((total + 127) // 128)

@@ -742,7 +742,15 @@ class SubprocessReplicaFactory(ReplicaFactory):
     ``FleetSupervisor`` via ``make_engine_factory()`` (respawn on
     restart).  ``close()`` tears down every child and the transport;
     an ``atexit`` hook SIGKILLs whatever is still alive if the parent
-    exits without closing."""
+    exits without closing.
+
+    ``child_platform`` is the JAX platform every worker runs on, set as
+    the child's ``JAX_PLATFORMS`` whatever the parent's environment
+    says.  The default is ``"cpu"``: a chip belongs to one process, and
+    a parent that has touched JAX holds it, so workers beside such a
+    parent can only be CPU workers.  Pass ``"tpu"`` only from a parent
+    that stays off JAX, with one chip per worker.  Each worker echoes
+    its platform in the first line of its log."""
 
     def __init__(self, cfg_kwargs: dict, *, model_seed: int = 0,
                  seed_base: int = 100, name_prefix: str = "proc",
@@ -754,6 +762,7 @@ class SubprocessReplicaFactory(ReplicaFactory):
                  artifact: Optional[str] = None,
                  env_extra: Optional[dict] = None,
                  backend_kind: str = "tpu", cost_weight: float = 1.0,
+                 child_platform: str = "cpu",
                  hb_interval_s: Optional[float] = None,
                  hb_miss_n: Optional[int] = None,
                  restore_after: int = 3):
@@ -772,6 +781,7 @@ class SubprocessReplicaFactory(ReplicaFactory):
         self.env_extra = dict(env_extra) if env_extra else {}
         self.backend_kind = backend_kind
         self.cost_weight = float(cost_weight)
+        self.child_platform = child_platform
         self._hb_interval = hb_interval_s
         self._hb_miss = hb_miss_n
         self.restore_after = int(restore_after)
@@ -807,7 +817,7 @@ class SubprocessReplicaFactory(ReplicaFactory):
     def _child_env(self, rank: int, spec: dict) -> dict:
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = self.child_platform
         env["PADDLE_JAX_DISTRIBUTED"] = "0"
         env["PADDLE_TRAINER_ID"] = str(rank)
         env["PADDLE_TRAINERS_NUM"] = str(self.world_size)

@@ -306,9 +306,16 @@ def serve(tp, engine, collector=None) -> int:
 
 
 def main() -> int:
+    # first log line: the platform this worker was told to run on (the
+    # factory's child_platform; JAX fails at start-up if it cannot)
+    print(f"[replica_host] pid={os.getpid()} "
+          f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '(unset)')}",
+          flush=True)
     from ..distributed.transport import init_transport
     from ..profiler.aggregate import MetricsCollector
+    from ..utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     spec = json.loads(os.environ[SPEC_ENV])
     tp = init_transport()
     assert tp is not None, "replica host needs a multi-process world"

@@ -36,8 +36,9 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..incubate.nn.functional import fused_rotary_position_embedding, swiglu
 from ..ops.pallas import flash_attention as fa
+from ..ops.pallas import per_shard
+from ..ops.pallas import ring_attention as ra
 from ..ops.pallas import rms_norm as rn
-from ..utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel",
            "forward_stacked", "loss_fn_stacked", "loss_fn_pipelined",
@@ -468,21 +469,37 @@ def _rope(q, k, theta):
     return rot(q), rot(k)
 
 
+# layout of the trunk's [..., B, S, H] activations over the hybrid mesh,
+# for the kernels' per-shard calls (ops.pallas.per_shard): batch over the
+# data axes, sequence over 'sep', hidden whole
+_ACT_SPEC = (("dp", "sharding"), "sep", None)
+
+
+def _rms_norm(x, weight, eps, mesh):
+    """rms_norm of [..., B, S, H] activations; per shard on a multi-device
+    mesh (rows are independent, so any row sharding is exact)."""
+    spec = P(*(None,) * (x.ndim - 3), *_ACT_SPEC)
+    return per_shard(lambda a, w: rn.rms_norm(a, w, eps), mesh,
+                     (spec, P(None)), spec)(x, weight)
+
+
 def _block(params, x, config: LlamaConfig, mesh=None):
-    """One decoder block on raw arrays (used inside lax.scan). When `mesh`
-    is given and its 'sep' axis is >1, attention runs as a ring over the
-    sequence shards (ops/pallas/ring_attention: ppermute of K/V blocks
-    with online-softmax merge and a hand-written ring VJP) inside a
-    shard_map manual over 'sep' ONLY — dp/sharding/mp stay GSPMD-auto.
+    """One decoder block on raw arrays (used inside lax.scan). `mesh` is
+    the mesh the enclosing jit runs over, if any: on several devices
+    rms-norm and attention run per shard (ops.pallas.per_shard), every
+    other op under GSPMD. When its 'sep' axis is >1, attention runs as a
+    ring over the sequence shards (ops/pallas/ring_attention: ppermute of
+    K/V blocks with online-softmax merge and a hand-written ring VJP).
     This is the TPU-native SEP/context-parallel engine (SURVEY §2.5
     segment_parallel.py:26; the reference delegates ring-style attention
-    to fused kernels + sep process groups)."""
+    to fused kernels + sep process groups). Inside the pipeline's 'pp'
+    ring it attends over the gathered sequence instead."""
     h = config.hidden_size
     nh, kvh, hd = (config.num_attention_heads, config.num_key_value_heads,
                    config.head_dim)
     b, s, _ = x.shape
 
-    hx = rn.rms_norm(x, params["ln_attn"], config.rms_norm_eps)
+    hx = _rms_norm(x, params["ln_attn"], config.rms_norm_eps, mesh)
     q = (hx @ params["wq"]).reshape(b, s, nh, hd)
     k = (hx @ params["wk"]).reshape(b, s, kvh, hd)
     v = (hx @ params["wv"]).reshape(b, s, kvh, hd)
@@ -493,26 +510,26 @@ def _block(params, x, config: LlamaConfig, mesh=None):
         v = jnp.repeat(v, rep, axis=2)
     from jax.ad_checkpoint import checkpoint_name
 
-    if mesh is not None and mesh.shape.get("sep", 1) > 1:
-        from ..ops.pallas import ring_attention as ra
+    # the ring asks axis_index('sep'), which jax 0.9 cannot lower in a
+    # region nested inside another manual region (the pipeline's, over 'pp')
+    ring = (mesh is not None and mesh.shape.get("sep", 1) > 1
+            and not jax.sharding.get_abstract_mesh().manual_axes)
 
-        def ring_attn(qq, kk, vv):
+    def attend(qq, kk, vv):
+        if ring:
             return ra.ring_attention_bshd(qq, kk, vv, axis_name="sep",
                                           is_causal=True)
+        return fa.flash_attention_bshd(qq, kk, vv, is_causal=True)
 
-        from ..utils.jax_compat import shard_map as _shard_map
-
-        seq_spec = P(None, "sep")
-        attn = _shard_map(
-            ring_attn, mesh=mesh,
-            in_specs=(seq_spec, seq_spec, seq_spec), out_specs=seq_spec,
-            axis_names={"sep"}, check_vma=False)(q, k, v)
-    else:
-        attn = fa.flash_attention_bshd(q, k, v, is_causal=True)
+    # [B, S, heads, D]; batch and heads are independent: each device
+    # attends for its own (batch, heads) shard — as a ring over the
+    # sequence shards, or over the whole sequence
+    spec = P(("dp", "sharding"), "sep" if ring else None, "mp", None)
+    attn = per_shard(attend, mesh, (spec,) * 3, spec)(q, k, v)
     attn = checkpoint_name(attn, "flash_attn_out")
     x = x + attn.reshape(b, s, h) @ params["wo"]
 
-    hx = rn.rms_norm(x, params["ln_mlp"], config.rms_norm_eps)
+    hx = _rms_norm(x, params["ln_mlp"], config.rms_norm_eps, mesh)
     gated = jax.nn.silu(hx @ params["w_gate"]) * (hx @ params["w_up"])
     x = x + gated @ params["w_down"]
     return x
@@ -553,10 +570,10 @@ def forward_stacked(params, input_ids, config: LlamaConfig,
     return logits
 
 
-def _head_loss(params, h, labels, config: LlamaConfig):
+def _head_loss(params, h, labels, config: LlamaConfig, mesh=None):
     """Shared tail of both training paths: final norm -> LM head ->
     mean next-token NLL. h: [..., S, H], labels: [..., S]."""
-    h = rn.rms_norm(h, params["final_norm"], config.rms_norm_eps)
+    h = _rms_norm(h, params["final_norm"], config.rms_norm_eps, mesh)
     logits = h.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
     # lse - picked, not log_softmax: avoids materializing a second
     # [.., S, V] fp32 array (reductions fuse into one pass over logits)
@@ -569,11 +586,13 @@ def _head_loss(params, h, labels, config: LlamaConfig):
 
 def loss_fn_stacked(params, batch, config: LlamaConfig, remat: bool = True,
                     mesh=None):
-    """Next-token LM loss; batch = (input_ids[B,S], labels[B,S]). Pass
-    `mesh` with a 'sep' axis >1 to run ring-attention context parallel."""
+    """Next-token LM loss; batch = (input_ids[B,S], labels[B,S]). Pass the
+    `mesh` the step is jitted over: a 'sep' axis >1 runs ring-attention
+    context parallel, and on any multi-device mesh the Pallas kernels run
+    per shard (ops.pallas.per_shard)."""
     input_ids, labels = batch
     x = _trunk(params, input_ids, config, remat, mesh=mesh)
-    return _head_loss(params, x, labels, config)
+    return _head_loss(params, x, labels, config, mesh=mesh)
 
 
 def microbatch_spec():
@@ -599,7 +618,10 @@ def loss_fn_pipelined(params, batch, config: LlamaConfig, mesh,
     2(P-1)/(2M+2(P-1))). Embedding and the LM head run under plain GSPMD
     outside the ring (they are not layer-striped in the reference either).
 
-    batch = (input_ids[n_micro, mb, S], labels[n_micro, mb, S]).
+    batch = (input_ids[n_micro, mb, S], labels[n_micro, mb, S]); `mesh`
+    is the hybrid mesh (distributed.topology.build_mesh): inside the ring
+    the Pallas kernels run per shard over its other axes
+    (ops.pallas.per_shard nests in the 'pp' region).
     Requires num_hidden_layers % pp == 0.  ``overlap_sends=True``
     half-splits each tick's micro-batch so the first half's ICI hop
     overlaps the second half's block compute (latency-hidden pipeline
@@ -615,14 +637,14 @@ def loss_fn_pipelined(params, batch, config: LlamaConfig, mesh,
 
     def stage_fn(stage_blocks, h):
         def body(c, bp):
-            return _block(bp, c, config), None
+            return _block(bp, c, config, mesh=mesh), None
 
         body_fn = jax.checkpoint(body) if remat else body
         y, _ = jax.lax.scan(body_fn, h, stage_blocks)
         return y
 
     def ring(stage_blocks, xm):
-        p = _axis_size("pp")
+        p = jax.lax.axis_size("pp")
         stage = jax.lax.axis_index("pp")
         ys = spmd_pipeline(stage_fn, stage_blocks, xm, n_micro,
                            axis_name="pp", overlap_sends=overlap_sends)
@@ -631,10 +653,8 @@ def loss_fn_pipelined(params, batch, config: LlamaConfig, mesh,
         return jax.lax.psum(
             jnp.where(stage == p - 1, ys, jnp.zeros_like(ys)), "pp")
 
-    from ..utils.jax_compat import shard_map as _shard_map
-
     block_specs = jax.tree.map(lambda _: P("pp"), params["blocks"])
-    ys = _shard_map(
+    ys = jax.shard_map(
         ring, mesh=mesh, in_specs=(block_specs, P()), out_specs=P(),
         axis_names={"pp"}, check_vma=False)(params["blocks"], x)
-    return _head_loss(params, ys, labels, config)
+    return _head_loss(params, ys, labels, config, mesh=mesh)

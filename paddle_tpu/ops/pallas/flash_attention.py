@@ -37,15 +37,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import use_pallas
-
-
-def _interpret():
-    """PT_PALLAS_INTERPRET=1 runs the Pallas kernels in interpreter mode on
-    any backend — CI coverage for the kernel code paths on the CPU suite."""
-    import os
-
-    return os.environ.get("PT_PALLAS_INTERPRET", "0") == "1"
+from . import interpret as _interpret
+from . import kernels_enabled, note_reference_dispatch
 
 # 512 blocks measured ~2x over 128 blocks on v5e (bigger MXU tiles amortize
 # the VPU online-softmax work); the bh grid axis is parallel, q/kv arbitrary.
@@ -59,10 +52,7 @@ _MASK_MIN = -1e30
 
 
 def _dim_semantics(*sems):
-    # jax renamed TPUCompilerParams -> CompilerParams; accept either
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=sems)
+    return pltpu.CompilerParams(dimension_semantics=sems)
 
 
 # ---------------------------------------------------------------------------
@@ -255,12 +245,23 @@ def _pallas_ok(q, k, causal, block_q, block_k):
     multiple of 64 (d=64 runs the MXU at half the contraction width but
     still beat the XLA fallback by ~1.1x end-to-end on BERT-base train
     steps; the earlier 25x regression came from PADDING d 64->128, not from
-    native-64 operands), and (for causal) aligned q/k windows (sq == sk)."""
-    return ((use_pallas() or _interpret()) and q.shape[2] % block_q == 0
+    native-64 operands), and (for causal) aligned q/k windows (sq == sk).
+    Pure predicate; `_use_kernel` is the counted dispatch decision."""
+    return (kernels_enabled() and q.shape[2] % block_q == 0
             and k.shape[2] % block_k == 0
             and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0
             and q.shape[-1] % 64 == 0
             and (not causal or q.shape[2] == k.shape[2]))
+
+
+def _use_kernel(q, k, causal, block_q, block_k, site):
+    """The dispatch decision at one call site: True runs the Pallas
+    kernel; False runs the jnp path and — when kernels are enabled, i.e.
+    the shape is what refused — counts a reference dispatch."""
+    ok = _pallas_ok(q, k, causal, block_q, block_k)
+    if not ok and kernels_enabled():
+        note_reference_dispatch(site)
+    return ok
 
 
 def _forward_with_lse(q, k, v, kmask, seed, causal, dropout_p):
@@ -269,7 +270,7 @@ def _forward_with_lse(q, k, v, kmask, seed, causal, dropout_p):
     mask, so Pallas and XLA paths agree bit-for-bit on which probs drop."""
     block_q = min(DEFAULT_BLOCK_Q, q.shape[2])
     block_k = min(DEFAULT_BLOCK_K, k.shape[2])
-    if _pallas_ok(q, k, causal, block_q, block_k):
+    if _use_kernel(q, k, causal, block_q, block_k, "flash_attention_fwd"):
         return _pallas_forward(q, k, v, kmask, seed, causal, dropout_p,
                                block_q, block_k)
     # XLA fallback (still O(S^2) HBM for logits, fine for small S / CPU tests)
@@ -563,7 +564,7 @@ def _flash_bwd(causal, dropout_p, res, do):
     pbk = min(DEFAULT_BLOCK_K, sk)
     km_zero = None if kmask is None else jnp.zeros_like(kmask)
     seed_zero = np.zeros(seed.shape, jax.dtypes.float0)
-    if _pallas_ok(q, k, causal, pbq, pbk):
+    if _use_kernel(q, k, causal, pbq, pbk, "flash_attention_bwd"):
         dq, dk, dv = _pallas_backward(q, k, v, kmask, seed, o, lse, do,
                                       causal, dropout_p, pbq, pbk)
         return dq, dk, dv, km_zero, seed_zero
@@ -666,11 +667,15 @@ def flash_attention_bhsd(q, k, v, mask=None, is_causal=False,
         dropout_key = next_key()
     if mask is not None and kmask is None:
         # generic [B, H, Sq, Sk] masks: materialized-attention fallback
+        if kernels_enabled():
+            note_reference_dispatch("flash_attention_mask")
         return _attention_ref(q, k, v, mask, is_causal, dropout_p,
                               dropout_key)
     if dropout_p > 0.0 and not pallas:
         # off-TPU / unaligned: plain autodiff through the reference is
         # cheaper than the blockwise bwd at these (small) shapes
+        if kernels_enabled():
+            note_reference_dispatch("flash_attention_dropout")
         return _attention_ref(q, k, v, mask, is_causal, dropout_p,
                               dropout_key)
     if dropout_p > 0.0:

@@ -31,7 +31,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from ...utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["ring_attention_bshd", "ring_attention_bhsd"]
 
@@ -70,7 +69,7 @@ def _merge(o, lse, o_new, lse_new):
 def _ring_fwd_impl(q, k, v, axis_name: str, causal: bool):
     """q,k,v: [B,H,Sl,D] local shards inside shard_map over axis_name.
     Returns (o normalized in q.dtype, lse [B,H,Sl] f32)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, sl, d = q.shape
     scale = 1.0 / math.sqrt(d)
@@ -113,7 +112,7 @@ def _ring_core_bwd(axis_name, causal, res, do):
     """Flash backward per hop; dk/dv accumulators ride the ring with their
     KV shards and arrive home after n hops."""
     q, k, v, o, lse = res
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, sl, d = q.shape
     scale = 1.0 / math.sqrt(d)

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import use_pallas
+from . import interpret, kernels_enabled, note_reference_dispatch
 
 _BLOCK_ROWS = 256
 
@@ -28,7 +28,7 @@ def _rms_norm_ref(x, weight, eps):
     return out.astype(x.dtype)
 
 
-def _kernel(x_ref, w_ref, o_ref, *, eps):
+def _rms_norm_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[:].astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     out = x * jax.lax.rsqrt(var + eps)
@@ -36,7 +36,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
     o_ref[:] = out.astype(o_ref.dtype)
 
 
-def _kernel_nw(x_ref, o_ref, *, eps):
+def _rms_norm_nw_kernel(x_ref, o_ref, *, eps):
     x = x_ref[:].astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     o_ref[:] = (x * jax.lax.rsqrt(var + eps)).astype(o_ref.dtype)
@@ -50,11 +50,12 @@ def _pallas_forward(x, weight, eps):
     block = min(_BLOCK_ROWS, n)
     if n % block != 0:
         # row-count not tileable; XLA path handles the remainder fine
+        note_reference_dispatch("rms_norm")
         return _rms_norm_ref(x, weight, eps)
     grid = (n // block,)
     if weight is not None:
         out = pl.pallas_call(
-            functools.partial(_kernel, eps=eps),
+            functools.partial(_rms_norm_kernel, eps=eps),
             out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
             grid=grid,
             in_specs=[
@@ -62,21 +63,23 @@ def _pallas_forward(x, weight, eps):
                 pl.BlockSpec((h,), lambda i: (0,)),
             ],
             out_specs=pl.BlockSpec((block, h), lambda i: (i, 0)),
+            interpret=interpret(),
         )(x2, weight)
     else:
         out = pl.pallas_call(
-            functools.partial(_kernel_nw, eps=eps),
+            functools.partial(_rms_norm_nw_kernel, eps=eps),
             out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
             grid=grid,
             in_specs=[pl.BlockSpec((block, h), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((block, h), lambda i: (i, 0)),
+            interpret=interpret(),
         )(x2)
     return out.reshape(orig_shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _rms_norm(x, weight, eps, has_weight):
-    if use_pallas():
+    if kernels_enabled():
         return _pallas_forward(x, weight if has_weight else None, eps)
     return _rms_norm_ref(x, weight if has_weight else None, eps)
 

@@ -29,8 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from . import use_pallas
-from .flash_attention import _MASK_MIN, _dim_semantics, _interpret
+from . import interpret as _interpret
+from . import kernels_enabled, note_reference_dispatch
+from .flash_attention import _MASK_MIN, _dim_semantics
 
 __all__ = ["varlen_flash_attention_packed", "segment_ids_from_cu_seqlens"]
 
@@ -367,7 +368,7 @@ def _vfa_ok(q, k):
     # a valid block must divide each packed length exactly (sq % block_q
     # == 0 and sk % block_k == 0 by construction of _vfa_block); packed
     # lengths with no such block (e.g. 600) fall back to _varlen_ref
-    return ((use_pallas() or _interpret())
+    return (kernels_enabled()
             and _vfa_block(q.shape[2]) > 0 and _vfa_block(k.shape[2]) > 0
             and q.shape[-1] % 64 == 0)
 
@@ -379,4 +380,6 @@ def varlen_flash_attention_packed(q, k, v, seg_q, seg_k, is_causal=False):
     is_causal applies per-sequence causality via packed positions."""
     if _vfa_ok(q, k):
         return _varlen_attention(q, k, v, seg_q, seg_k, bool(is_causal))
+    if kernels_enabled():
+        note_reference_dispatch("varlen_attention")
     return _varlen_ref(q, k, v, seg_q, seg_k, bool(is_causal))

@@ -1,5 +1,7 @@
 """Distributed stack tests on the 8-device CPU mesh (SURVEY §4: the
 hardware-free collective test strategy)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -49,7 +51,7 @@ def test_hcg_modes():
 def test_collectives_in_shard_map():
     from functools import partial
 
-    from paddle_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh((8,), ("world",))
     from paddle_tpu.distributed import collective
@@ -81,7 +83,7 @@ def test_collectives_in_shard_map():
 def test_ring_attention_matches_full():
     from functools import partial
 
-    from paddle_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas.ring_attention import ring_attention_bhsd
@@ -109,7 +111,7 @@ def test_ring_attention_matches_full():
 def test_ring_attention_grad():
     from functools import partial
 
-    from paddle_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas.ring_attention import ring_attention_bhsd
@@ -139,7 +141,7 @@ def test_ring_attention_grad_distinct_qkv():
     the custom VJP and must land home with full accumulation)."""
     from functools import partial
 
-    from paddle_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas.ring_attention import ring_attention_bhsd
@@ -300,7 +302,7 @@ def test_distributed_checkpoint_roundtrip(tmp_path):
 def test_spmd_pipeline():
     from functools import partial
 
-    from paddle_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from paddle_tpu.distributed.meta_parallel import spmd_pipeline
 
@@ -337,7 +339,7 @@ def test_pipelined_loss_matches_stacked():
     flagship trainer (reference pipeline_parallel.py:459 semantics)."""
     from paddle_tpu.models import llama
 
-    mesh = _mesh((2, 2, 2), ("dp", "pp", "mp"))
+    mesh = _mesh((2, 2, 1, 1, 2), ("dp", "pp", "sharding", "sep", "mp"))
     cfg = llama.LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,
         num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=2,
@@ -363,13 +365,17 @@ def test_pipelined_loss_matches_stacked():
                                    rtol=2e-3, atol=1e-5)
 
 
-def test_hybrid_trainer_pipelined_steps():
+@pytest.mark.parametrize("shape", [
+    (2, 2, 1, 1, 2),
+    (1, 2, 1, 2, 2),    # sep>1: no sequence ring inside the 'pp' region
+])
+def test_hybrid_trainer_pipelined_steps(shape):
     """HybridTrainer(pipeline_micro_batches=4) trains: losses finite and
     decreasing-ish over a few steps on the 8-device virtual mesh."""
     from paddle_tpu.distributed.fleet.trainer import HybridTrainer
     from paddle_tpu.models import llama
 
-    mesh = _mesh((2, 2, 1, 1, 2), ("dp", "pp", "sharding", "sep", "mp"))
+    mesh = _mesh(shape, ("dp", "pp", "sharding", "sep", "mp"))
     cfg = llama.LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,
         num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=2,
@@ -384,11 +390,15 @@ def test_hybrid_trainer_pipelined_steps():
     assert losses[-1] < losses[0]
 
 
+_GRAFT_ENTRY = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "__graft_entry__.py")
+
+
 def test_graft_entry_dryrun():
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
-        "graft_entry", "/root/repo/__graft_entry__.py")
+        "graft_entry", _GRAFT_ENTRY)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     fn, args = mod.entry()
@@ -412,7 +422,7 @@ def test_graft_entry_dryrun_16_devices():
     code = (
         "import importlib.util\n"
         "spec = importlib.util.spec_from_file_location("
-        "'graft_entry', '/root/repo/__graft_entry__.py')\n"
+        f"'graft_entry', {_GRAFT_ENTRY!r})\n"
         "mod = importlib.util.module_from_spec(spec)\n"
         "spec.loader.exec_module(mod)\n"
         "mod.dryrun_multichip(16)\n")

@@ -9,6 +9,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import nn, optimizer
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_llama_eager_trains():
     from paddle_tpu.models import llama
@@ -104,7 +106,7 @@ def test_launch_cli_two_workers(tmp_path):
     )
     env = dict(os.environ)
     env["OUT_DIR"] = str(tmp_path)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", "--log_dir", str(tmp_path / "log"),
@@ -126,7 +128,7 @@ def test_launch_cli_restarts_failed_worker(tmp_path):
     )
     env = dict(os.environ)
     env["OUT_DIR"] = str(tmp_path)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--max_restart", "2", "--log_dir", str(tmp_path / "log"),
@@ -149,7 +151,7 @@ def test_launch_cli_dataparallel_grad_sync(tmp_path):
         "os.environ.setdefault('JAX_PLATFORMS', 'cpu')\n"
         "os.environ.setdefault('PADDLE_JAX_DISTRIBUTED', '0')\n"
         "import sys\n"
-        "sys.path.insert(0, '/root/repo')\n"
+        f"sys.path.insert(0, {REPO!r})\n"
         "import numpy as np\n"
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import paddle_tpu as paddle\n"
@@ -183,7 +185,7 @@ def test_launch_cli_dataparallel_grad_sync(tmp_path):
     env["OUT_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     env["PADDLE_JAX_DISTRIBUTED"] = "0"
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get(
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get(
         "PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
@@ -228,7 +230,7 @@ _ELASTIC_WORKER = """\
 import os
 os.environ.setdefault('PADDLE_JAX_DISTRIBUTED', '0')
 import sys, time
-sys.path.insert(0, '/root/repo')
+sys.path.insert(0, REPO)
 import jax; jax.config.update('jax_platforms', 'cpu')
 import numpy as np
 import paddle_tpu as paddle
@@ -278,8 +280,8 @@ log.flush()
 
 def _write_elastic_worker(tmp_path, target_steps):
     worker = tmp_path / "elastic_worker.py"
-    worker.write_text(_ELASTIC_WORKER.replace("TARGET",
-                                              str(target_steps)))
+    worker.write_text(_ELASTIC_WORKER.replace("TARGET", str(target_steps))
+                      .replace("REPO", repr(REPO)))
     return worker
 
 
@@ -339,7 +341,7 @@ def test_elastic_end_to_end_kill_reform_resume(tmp_path):
     master_port = _elastic_master_port()
     env = dict(os.environ)
     env["OUT_DIR"] = str(tmp_path)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH",
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH",
                                                             "")
     ctl_a = _elastic_controller("a", tmp_path, master_port, "elastic_e2e",
                                 worker, env)
@@ -394,7 +396,7 @@ def test_hapi_fit_distributed_aware(tmp_path):
         "import os\n"
         "os.environ.setdefault('PADDLE_JAX_DISTRIBUTED', '0')\n"
         "import sys\n"
-        "sys.path.insert(0, '/root/repo')\n"
+        f"sys.path.insert(0, {REPO!r})\n"
         "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import numpy as np\n"
         "import paddle_tpu as paddle\n"
@@ -424,7 +426,7 @@ def test_hapi_fit_distributed_aware(tmp_path):
     )
     env = dict(os.environ)
     env["OUT_DIR"] = str(tmp_path)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", "--log_dir", str(tmp_path / "log"),
@@ -472,7 +474,7 @@ def test_elastic_scale_out_node_joins(tmp_path):
     master_port = _elastic_master_port()
     env = dict(os.environ)
     env["OUT_DIR"] = str(tmp_path)
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH",
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH",
                                                             "")
     ctl_a = _elastic_controller("a", tmp_path, master_port,
                                 "scaleout_e2e", worker, env)

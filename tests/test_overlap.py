@@ -18,7 +18,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
-from paddle_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _mesh(shape, names):
@@ -156,7 +156,7 @@ def test_llama_pipelined_loss_and_grads_with_overlap_sends():
     stage3_forward tests above.)"""
     from paddle_tpu.models import llama
 
-    mesh = _mesh((2, 2, 2), ("dp", "pp", "mp"))
+    mesh = _mesh((2, 2, 1, 1, 2), ("dp", "pp", "sharding", "sep", "mp"))
     cfg = llama.LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,
         num_hidden_layers=4, num_attention_heads=2,

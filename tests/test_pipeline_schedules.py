@@ -178,7 +178,6 @@ def test_spmd_pipeline_interleaved_matches_sequential():
     out_ref = np.stack(out_ref)
 
     mesh = Mesh(np.array(jax.devices()[:pp]), ("pp",))
-    from jax.experimental.shard_map import shard_map
 
     def run(wv, xv):
         out = spmd_pipeline_interleaved(
@@ -187,9 +186,9 @@ def test_spmd_pipeline_interleaved_matches_sequential():
         mask = (jax.lax.axis_index("pp") == pp - 1).astype(out.dtype)
         return jax.lax.psum(out * mask, "pp")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh, in_specs=(P("pp"), P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     out = jax.jit(fn)(jnp.asarray(w), jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(out), out_ref,
                                rtol=2e-5, atol=2e-5)

@@ -344,14 +344,13 @@ def test_pt303_direct_renamed_pltpu_attr(tmp_path):
     assert "PT303" in ids(rep)
 
 
-def test_pt303_getattr_pattern_is_clean(tmp_path):
+def test_pt303_current_name_is_clean(tmp_path):
     rep = lint(tmp_path, """
         from jax.experimental.pallas import tpu as pltpu
 
         def params():
-            cls = getattr(pltpu, "CompilerParams", None) \\
-                or getattr(pltpu, "TPUCompilerParams")
-            return cls(dimension_semantics=("parallel",))
+            return pltpu.CompilerParams(
+                dimension_semantics=("parallel",))
     """)
     assert "PT303" not in ids(rep)
 

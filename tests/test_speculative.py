@@ -401,8 +401,10 @@ def test_lower_fused_decode_single_module(model):
 def test_fusereport_decode_preset(tmp_path):
     """tools/fusereport.py --preset decode: verified auto_fuse over the
     captured decode iteration, with roofline + .mlir artifacts."""
+    import os
     import sys
-    sys.path.insert(0, "/root/repo/tools")
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
     try:
         import fusereport
     finally:

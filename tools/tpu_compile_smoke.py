@@ -1,0 +1,135 @@
+"""Compile chip_smoke.py's programs for a DESCRIBED TPU v5e — no chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (`jax.experimental.topologies`). This script
+hands the trainer and the engine the described devices and abstract
+shapes, compiles the whole train step (one chip, and the four-chip
+layouts) and the engine's prefill, mixed and decode-window steps at
+chip_smoke's real sizes, and prints what each needs on a device
+(`memory_analysis()`), which kernels it holds and which collectives the
+compiler put in. Nothing runs: it says nothing about results or times.
+It is how chip_smoke.chip_config's depth and batch were settled (edit
+that function to try another size), and what to re-run before a chip call
+after a change to those programs:
+
+    python tools/tpu_compile_smoke.py
+
+`jax.default_backend()` is the CPU here, so the kernels are switched on
+through PT_USE_PALLAS=1, which `main` sets for this process.
+"""
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [REPO, os.path.join(REPO, "tests")]
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.distributed.topology import build_mesh  # noqa: E402
+from test_tpu_compile import abstract_trainer  # noqa: E402
+
+
+def report(name, lowered, t0):
+    text = lowered.as_text()
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    collectives = {k: len(re.findall(rf"\b{k}(-start)?\(", hlo))
+                   for k in ("all-reduce", "all-gather", "reduce-scatter",
+                             "all-to-all", "collective-permute")}
+    out = {"program": name,
+           "per_device_bytes": chip_smoke.predicted_bytes(compiled),
+           "argument": m.argument_size_in_bytes,
+           "output": m.output_size_in_bytes,
+           "temp": m.temp_size_in_bytes, "alias": m.alias_size_in_bytes,
+           "kernel_calls": chip_smoke.kernel_calls_in(text),
+           "collectives": {k: v for k, v in collectives.items() if v},
+           "compile_s": round(time.perf_counter() - t0, 1)}
+    print(json.dumps(out), flush=True)
+
+
+def compile_train(model, cfg, devices, layout, options):
+    t0 = time.perf_counter()
+    tr = abstract_trainer(model, build_mesh(devices=devices, **layout),
+                          **options)
+    name = (f"train L{model.num_hidden_layers} b{cfg.batch} s{cfg.seq} "
+            f"{layout or 'one chip'}")
+    report(name, tr.lower((cfg.batch, cfg.seq)), t0)
+
+
+def compile_serve(cfg, device):
+    """The engine's three step programs at the smoke's serving config: the
+    fresh-prefill step (varlen flash kernel), the mixed prefill/decode
+    step (page-pool gather) and one decode window."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import (PagedCausalLM,
+                                              PagedServingConfig,
+                                              ServingEngine, _next_pow2)
+
+    paddle.seed(cfg.seed)
+    scfg = PagedServingConfig(**cfg.serving)
+    model = PagedCausalLM(scfg)
+    model.eval()
+    eng = ServingEngine.from_model(model, scfg, seed=cfg.seed)
+    one = SingleDeviceSharding(device)
+
+    def shp(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    B1 = scfg.max_batch + 1
+    fp = [shp(a) for a in eng._params]
+    fb = [shp(a) for a in eng._buffers]
+    kc, vc = shp(eng._kc), shp(eng._vc)
+    T = scfg.token_budget
+    step_args = (fp, fb, i32(T), i32(B1), i32(B1), i32(B1), i32(B1 + 1),
+                 i32(B1, scfg.max_blocks_per_seq), kc, vc)
+    t0 = time.perf_counter()
+    report(f"serve fresh-prefill L{scfg.num_layers} T{T}",
+           eng._compiled_fresh.lower(*step_args), t0)
+    t0 = time.perf_counter()
+    report(f"serve mixed step L{scfg.num_layers} T{T}",
+           eng._compiled.lower(*step_args), t0)
+    rows = min(_next_pow2(len(cfg.prompt_lens)), scfg.max_batch)
+    n = 8
+    t0 = time.perf_counter()
+    window = eng._decode_window_fn(rows, n, "greedy")
+    report(f"serve decode window L{scfg.num_layers} rows{rows} n{n}",
+           window.lower(fp, fb, i32(rows), i32(B1), i32(B1), i32(B1),
+                        i32(B1 + 1), i32(B1, scfg.max_blocks_per_seq), kc,
+                        vc, (), f32(B1), i32(B1), f32(B1), i32(n, B1)), t0)
+
+
+def main():
+    # before the first backend or compiler start-up in this process
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ["PT_USE_PALLAS"] = "1"
+    jax.config.update("jax_platforms", "cpu")
+    from jax.experimental import topologies
+
+    cfg = chip_smoke.chip_config()
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    compile_train(cfg.llama, cfg, topo.devices[:1], {}, {})
+    even = chip_smoke.even_depth(cfg.llama)     # the four-chip phase's
+    compile_train(even, cfg, topo.devices[:1], {}, {})
+    for layout, options in chip_smoke.LAYOUTS:
+        compile_train(even, cfg, topo.devices, layout, options)
+    compile_serve(cfg, topo.devices[0])
+
+
+if __name__ == "__main__":
+    main()

@@ -34,6 +34,10 @@ KNOWN_METRICS = (
     "dispatch/calls", "dispatch/cache_hit", "dispatch/cache_miss",
     "dispatch/uncacheable", "dispatch/cache_disabled_calls",
     "dispatch/cache_evictions", "dispatch/cache_fallbacks",
+    # Pallas kernels (ops/pallas/__init__.py): trace-time decisions to
+    # run a kernel's jnp reference although kernels are on (the shape
+    # does not tile) — chip_smoke.py asserts zero on its path
+    "pallas/reference_dispatch", "pallas/reference_dispatch/*",
     # jit compile bridge (jit/api.py, jit/partial_capture.py)
     "jit/compile_count", "jit/compile_ms", "jit/retrace_count",
     "jit/retrace_cause/*", "jit/graph_break_count",
