@@ -614,8 +614,10 @@ def bench_fleet_serving(on_tpu):
         while any(r.length - r.cached > 1 for r in eng.pending()):
             eng.step()
         eng.decode_run(stream_win)          # warm the window executable
-        # child registry AFTER the warm window: the tpot digest sees
-        # only the timed steady-state windows
+        # child registry AFTER the warm window: the tpot digest holds
+        # only requests that finish in the timed windows (`serving/tpot_ms`
+        # is observed once a request finishes, on every path; a window
+        # that finishes none observes nothing and `tpot_qs` reads empty)
         eng.set_metrics_namespace(f"stream-{weight_stream or 'bf16'}")
         return eng
 

@@ -158,13 +158,13 @@ def assert_on(tree, devices) -> None:
 
 def kernel_calls_in(text: str) -> Dict[str, int]:
     """tpu_custom_calls in a lowered program's text, by kernel family.
-    Kernel names are the Pallas kernel functions' (ops/pallas/*)."""
+    Kernel names are the `name=` of each `pl.pallas_call` (ops/pallas/*):
+    `flash_attention_fwd`, `varlen_attention_dq`, `rms_norm_noweight` ..."""
     import re
 
     names = re.findall(r'kernel_name = "([^"]+)"', text)
-    fam = {"flash_attention": "_fa_", "varlen_attention": "_vfa_",
-           "rms_norm": "_rms_norm"}
-    out = {k: sum(n.startswith(p) for n in names) for k, p in fam.items()}
+    out = {k: sum(n.startswith(k) for n in names)
+           for k in ("flash_attention", "varlen_attention", "rms_norm")}
     out["total"] = text.count("tpu_custom_call")
     return out
 

@@ -304,7 +304,7 @@ def check_metric_names(mod):
 # PT404 — span names passed to tracing helpers must be literal strings
 # ---------------------------------------------------------------------------
 
-_SPAN_HELPERS = {"span", "record_span"}
+_SPAN_HELPERS = {"span", "phase", "record_span"}
 
 
 def _is_tracing_receiver(node) -> bool:

@@ -21,6 +21,8 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ...models import llama as llama_mod
+from ...profiler import scopes as _scopes
+from ...profiler import tracing as _tracing
 
 __all__ = ["HybridTrainer", "data_spec"]
 
@@ -80,6 +82,7 @@ class HybridTrainer:
         self._init_state(seed)
         self.step_count = 0
         self._compiled = self._build()
+        self._registered = False    # with profiler.scopes, at first step
 
     def _init_state(self, seed: int):
         """Materialize `params` and `opt_state` directly INTO the sharded
@@ -126,12 +129,14 @@ class HybridTrainer:
                 loss_of = lambda p: llama_mod.loss_fn_stacked(  # noqa: E731
                     p, (input_ids, labels), cfg, remat=remat, mesh=mesh)
             loss, grads = jax.value_and_grad(loss_of)(params)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-            if clip is not None:
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads)))
-                scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-12))
-                grads = jax.tree.map(lambda g: g * scale, grads)
+            with _scopes.scope("clip"):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+                if clip is not None:
+                    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                         for g in jax.tree.leaves(grads)))
+                    scale = jnp.minimum(
+                        1.0, clip / jnp.maximum(gnorm, 1e-12))
+                    grads = jax.tree.map(lambda g: g * scale, grads)
 
             def upd(p, g, m, v):
                 m = b1 * m + (1 - b1) * g
@@ -142,8 +147,9 @@ class HybridTrainer:
                     + wd * p.astype(jnp.float32)
                 return (p.astype(jnp.float32) - lr * step).astype(p.dtype), \
                     m, v
-            out = jax.tree.map(upd, params, grads, opt_state["m"],
-                               opt_state["v"])
+            with _scopes.scope("adamw"):
+                out = jax.tree.map(upd, params, grads, opt_state["m"],
+                                   opt_state["v"])
             new_p = jax.tree.map(lambda o: o[0], out,
                                  is_leaf=lambda x: isinstance(x, tuple))
             new_m = jax.tree.map(lambda o: o[1], out,
@@ -183,12 +189,23 @@ class HybridTrainer:
                 jax.device_put(labs, sharding))
 
     def step(self, input_ids, labels):
-        ids, labs = self.place_batch(input_ids, labels)
-        self.step_count += 1
-        self.params, self.opt_state, loss = self._compiled(
-            self.params, self.opt_state, ids, labs,
-            jnp.asarray(self.lr, jnp.float32),
-            jnp.asarray(self.step_count, jnp.float32))
+        with _tracing.span("trainer::step"):
+            with _tracing.phase("trainer::place_batch"):
+                ids, labs = self.place_batch(input_ids, labels)
+            self.step_count += 1
+            args = (self.params, self.opt_state, ids, labs,
+                    jnp.asarray(self.lr, jnp.float32),
+                    jnp.asarray(self.step_count, jnp.float32))
+            if not self._registered:
+                # shapes and shardings only (the step's closure holds no
+                # array either), so the entry outlives this trainer: the
+                # phase map is asked for once the run is over, and the
+                # newest trainer's step is the one kept under the name
+                self._registered = True
+                _scopes.register_program("train_step", self._compiled,
+                                         _scopes.abstract(args))
+            with _tracing.phase("trainer::dispatch"):
+                self.params, self.opt_state, loss = self._compiled(*args)
         return loss
 
     # -- elastic supervisor wiring (distributed/resilience/supervisor) -----

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from ....core.dispatch import apply
 from ....core.tensor import Tensor
+from ....profiler.scopes import scope
 
 __all__ = ["fused_rms_norm", "fused_layer_norm",
            "fused_rotary_position_embedding", "swiglu", "fused_matmul_bias",
@@ -698,15 +699,17 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                     .astype(jnp.int8)
                 return xi, s.astype(jnp.float32)
 
-            k8, k_s = q8(k)
-            v8, v_s = q8(v)
-            kc = kc.at[li + (page, slice(None), slot)].set(k8)
-            vc = vc.at[li + (page, slice(None), slot)].set(v8)
-            ks = ks.at[li + (page, slice(None), slot)].set(k_s)
-            vs = vs.at[li + (page, slice(None), slot)].set(v_s)
+            with scope("kv_write"):
+                k8, k_s = q8(k)
+                v8, v_s = q8(v)
+                kc = kc.at[li + (page, slice(None), slot)].set(k8)
+                vc = vc.at[li + (page, slice(None), slot)].set(v8)
+                ks = ks.at[li + (page, slice(None), slot)].set(k_s)
+                vs = vs.at[li + (page, slice(None), slot)].set(v_s)
         else:
-            kc = kc.at[li + (page, slice(None), slot)].set(k)
-            vc = vc.at[li + (page, slice(None), slot)].set(v)
+            with scope("kv_write"):
+                kc = kc.at[li + (page, slice(None), slot)].set(k)
+                vc = vc.at[li + (page, slice(None), slot)].set(v)
         if fresh_prefill:
             # every scheduled row starts at cache position 0, so keys ==
             # this step's packed tokens: block-diagonal varlen flash over
@@ -732,34 +735,37 @@ def block_multihead_attention(qkv, key_cache, value_cache,
         # strided element slices: the [B, S] advanced-index gather
         # lowered to a scalar-slice gather that dominated the decode and
         # chunked-prefill steps on TPU
-        kp = kc[li + (bt,)]                          # [B, MB, HKV, bs, D]
-        vp = vc[li + (bt,)]
-        kd = kp.transpose(0, 2, 1, 3, 4).reshape(
-            B, HKV, max_seq, D)                      # [B, HKV, S, D]
-        vd = vp.transpose(0, 2, 1, 3, 4).reshape(B, HKV, max_seq, D)
-        if quant:
-            # dequant the gathered view: int8 pages * per-slot scales
-            # (cache HBM traffic already halved at this point)
-            ksd = ks[li + (bt,)].transpose(0, 2, 1, 3).reshape(
-                B, HKV, max_seq)[..., None]          # [B, HKV, S, 1]
-            vsd = vs[li + (bt,)].transpose(0, 2, 1, 3).reshape(
-                B, HKV, max_seq)[..., None]
-            kd = (kd.astype(jnp.float32) * ksd).astype(qkva.dtype)
-            vd = (vd.astype(jnp.float32) * vsd).astype(qkva.dtype)
+        with scope("kv_gather"):
+            kp = kc[li + (bt,)]                      # [B, MB, HKV, bs, D]
+            vp = vc[li + (bt,)]
+            kd = kp.transpose(0, 2, 1, 3, 4).reshape(
+                B, HKV, max_seq, D)                  # [B, HKV, S, D]
+            vd = vp.transpose(0, 2, 1, 3, 4).reshape(B, HKV, max_seq, D)
+            if quant:
+                # dequant the gathered view: int8 pages * per-slot scales
+                # (cache HBM traffic already halved at this point)
+                ksd = ks[li + (bt,)].transpose(0, 2, 1, 3).reshape(
+                    B, HKV, max_seq)[..., None]      # [B, HKV, S, 1]
+                vsd = vs[li + (bt,)].transpose(0, 2, 1, 3).reshape(
+                    B, HKV, max_seq)[..., None]
+                kd = (kd.astype(jnp.float32) * ksd).astype(qkva.dtype)
+                vd = (vd.astype(jnp.float32) * vsd).astype(qkva.dtype)
+            kt = kd[t2b]                             # each token's row
         G = HQ // HKV
         qg = q.reshape(T, HKV, G, D)
         # MXU dots take the low-precision operands directly with f32
         # ACCUMULATION (preferred_element_type) — operand .astype(f32)
         # casts materialized an f32 copy of every gathered KV view
         # (~1.6 GB/step at flagship decode dims)
-        logits = jnp.einsum("tkgd,tksd->tkgs", qg, kd[t2b],
+        logits = jnp.einsum("tkgd,tksd->tkgs", qg, kt,
                             preferred_element_type=jnp.float32) \
             / jnp.sqrt(jnp.float32(D))
         valid = jnp.arange(max_seq)[None, :] <= pos[:, None]   # [T, S]
         logits = jnp.where(valid[:, None, None, :], logits, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("tkgs,tksd->tkgd", probs.astype(qkva.dtype),
-                         vd[t2b],
+        with scope("kv_gather"):
+            vt = vd[t2b]
+        out = jnp.einsum("tkgs,tksd->tkgd", probs.astype(qkva.dtype), vt,
                          preferred_element_type=jnp.float32) \
             .astype(qkva.dtype)
         if quant:

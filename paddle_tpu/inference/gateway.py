@@ -758,7 +758,7 @@ class FleetGateway:
                 sampling=entry.sampling,
                 eos_token_id=entry.eos_token_id,
                 deadline_s=cls.deadline_s, tenant=entry.tenant,
-                prefer=prefer)
+                prefer=prefer, arrival_t=entry.submit_t)
         except EngineOverloadedError:
             entry.attempts += 1
             self._queues[entry.tenant][entry.slo].appendleft(entry)

@@ -316,26 +316,37 @@ class ReplicaRouter:
 
     def submit(self, prompt_tokens, max_new_tokens=8, sampling=None,
                eos_token_id=None, deadline_s=None, tenant=None,
-               prefer: Optional[int] = None) -> int:
+               prefer: Optional[int] = None,
+               arrival_t: Optional[float] = None) -> int:
         """Admit on the least-loaded healthy replica; an overloaded
         replica is skipped (counted as a reroute) instead of failing the
         request.  ``prefer`` tries that replica index first regardless
         of load (the gateway's prefix-affinity placement); ``tenant``
-        scopes the request's prefix-cache namespace.  Raises
+        scopes the request's prefix-cache namespace; ``arrival_t`` is
+        when the request reached the caller (``time.perf_counter()``),
+        from which an in-process engine then counts queue time and TTFT
+        (default: when this router admits it).  Raises
         EngineOverloadedError only when EVERY healthy replica sheds (the
         fleet is genuinely saturated — or fully demoted), or when the
         ``retry_gate`` vetoes rerouting past a shed."""
         reps = self._snapshot()
         order = self._ordered()
+        if arrival_t is None:
+            arrival_t = _time.perf_counter()
         if prefer is not None and prefer in order:
             order.remove(prefer)
             order.insert(0, prefer)
         for idx in order:
             try:
-                rid = reps[idx].engine.add_request(
+                eng = reps[idx].engine
+                # a clock reading means nothing in another process: only
+                # an in-process engine takes the arrival time
+                extra = {"arrival_t": arrival_t} \
+                    if isinstance(eng, ServingEngine) else {}
+                rid = eng.add_request(
                     prompt_tokens, max_new_tokens=max_new_tokens,
                     sampling=sampling, eos_token_id=eos_token_id,
-                    deadline_s=deadline_s, tenant=tenant)
+                    deadline_s=deadline_s, tenant=tenant, **extra)
             except EngineOverloadedError:
                 _m_reroutes.inc()
                 if self.retry_gate is not None \

@@ -42,19 +42,23 @@ from ..nn.layer.layers import Layer
 from ..core.dispatch import apply
 from ..profiler import RecordEvent, host_tracing_active
 from ..profiler import metrics as _metrics
+from ..profiler import scopes as _scopes
 from ..profiler import tracing as _tracing
 
 
 class _EngineMetrics:
     """Handle bundle for the serving/* series one engine writes: TTFT
-    from request submit to its first sampled token, TPOT from
-    decode_run windows (window wall / steps), plus scheduler gauges the
+    from request submit (or the arrival time its caller passed) to its
+    first sampled token, TPOT once a request finishes ((last - first
+    token) / (tokens - 1); decode_run observes window wall / steps
+    instead), plus scheduler gauges the
     capacity story needs. Built from a registry so a fleet replica can
     bind its engine to a per-replica child registry (writes roll up to
     the global one) instead of conflating co-hosted replicas in the
     process-wide series — see ServingEngine.set_metrics_namespace."""
 
     __slots__ = ("ttft", "tpot", "steps", "tokens", "requests",
+                 "step_rows", "step_tokens", "step_pad", "step_prefill",
                  "preempt", "occupancy", "kv_util", "deadline", "shed",
                  "prefix_rate", "prefix_pages", "spec_steps",
                  "spec_drafted", "spec_accepted", "spec_accept_rate",
@@ -65,6 +69,13 @@ class _EngineMetrics:
         self.ttft = reg.histogram("serving/ttft_ms")
         self.tpot = reg.histogram("serving/tpot_ms")
         self.steps = reg.counter("serving/steps")
+        # what each step held (bumped once a step): scheduled rows, real
+        # tokens, the padding up to the step's static token length, and
+        # the tokens of rows still inside their prompt
+        self.step_rows = reg.counter("serving/step_rows")
+        self.step_tokens = reg.counter("serving/step_tokens")
+        self.step_pad = reg.counter("serving/step_pad_tokens")
+        self.step_prefill = reg.counter("serving/step_prefill_tokens")
         self.tokens = reg.counter("serving/tokens_generated")
         self.requests = reg.counter("serving/requests")
         self.preempt = reg.counter("serving/preemptions")
@@ -305,10 +316,23 @@ def _next_pow2(n):
     return 1 << (int(n) - 1).bit_length()
 
 
-_greedy_tokens_dev = jax.jit(
+def _sampler(name, core):
+    """A sampler as a program of its own: a named function, so a device
+    trace's "XLA Modules" line reads `jit_serving_sample_greedy`, not
+    `jit__lambda`, and its operations carry the `pt.sample` scope."""
+    def fn(*args):
+        with _scopes.scope("sample"):
+            return core(*args)
+
+    fn.__name__ = name
+    return jax.jit(fn)
+
+
+_greedy_tokens_dev = _sampler(
+    "serving_sample_greedy",
     lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
-_sample_tokens_dev = jax.jit(_sample_core)
-_sample_topk_dev = jax.jit(_sample_topk_core)
+_sample_tokens_dev = _sampler("serving_sample", _sample_core)
+_sample_topk_dev = _sampler("serving_sample_topk", _sample_topk_core)
 
 
 def sample_logits(logits, sampling: SamplingParams, salt: int) -> int:
@@ -405,7 +429,8 @@ class PagedCausalLM(Layer):
         from ..incubate.nn import functional as IF
 
         cfg = self.cfg
-        x = self.embed(tokens)                               # [T, H]
+        with _scopes.scope("embed"):
+            x = self.embed(tokens)                           # [T, H]
         # batch/seq dims come from the INPUTS, not cfg: one model serves
         # engines of different max_batch/max_seq (each jit-specializes)
         B1 = int(seq_lens_encoder.shape[0])
@@ -444,48 +469,51 @@ class PagedCausalLM(Layer):
                 # no-prefetch baseline: dequant issued AT use — no
                 # overlap window (what the micro-bench prices against)
                 cur_w = ws.dequant_layer(li)
-            h = self.ln1[li](x)
-            qkv = self._lin("qkv", li, h, cur_w)       # [T, (HQ+2HKV)*D]
-            # stacked-cache mode: each layer reads/writes its slice of
-            # the ONE [L, pool] cache pair (single dynamic-update-slice
-            # chain — the list+jnp.stack pattern rebuilt the full cache
-            # every step)
-            outs = IF.block_multihead_attention(
-                qkv, new_kc, new_vc,
-                seq_lens_encoder, seq_lens_decoder,
-                seq_lens_this_time, None, None, cu_seqlens_q, None,
-                block_tables,
-                cache_k_quant_scales=new_ks if quant else None,
-                cache_v_quant_scales=new_vs if quant else None,
-                use_dynamic_cachekv_quant=quant,
-                rope_emb=rope, layer_idx=li,
-                max_seq_len=cfg.max_seq, block_size=cfg.block_size,
-                fresh_prefill=getattr(self, "_step_mode", None)
-                == "fresh_prefill")
-            if quant:
-                out, _, new_kc, new_vc, new_ks, new_vs = outs
+            with _scopes.scope("attention"):
+                h = self.ln1[li](x)
+                qkv = self._lin("qkv", li, h, cur_w)   # [T, (HQ+2HKV)*D]
+                # stacked-cache mode: each layer reads/writes its slice
+                # of the ONE [L, pool] cache pair (single
+                # dynamic-update-slice chain — the list+jnp.stack pattern
+                # rebuilt the full cache every step)
+                outs = IF.block_multihead_attention(
+                    qkv, new_kc, new_vc,
+                    seq_lens_encoder, seq_lens_decoder,
+                    seq_lens_this_time, None, None, cu_seqlens_q, None,
+                    block_tables,
+                    cache_k_quant_scales=new_ks if quant else None,
+                    cache_v_quant_scales=new_vs if quant else None,
+                    use_dynamic_cachekv_quant=quant,
+                    rope_emb=rope, layer_idx=li,
+                    max_seq_len=cfg.max_seq, block_size=cfg.block_size,
+                    fresh_prefill=getattr(self, "_step_mode", None)
+                    == "fresh_prefill")
+                if quant:
+                    out, _, new_kc, new_vc, new_ks, new_vs = outs
+                else:
+                    out, _, new_kc, new_vc = outs
+                x = x + self._lin("proj", li, out, cur_w)
+            with _scopes.scope("mlp"):
+                h = self.ln2[li](x)
+                x = x + self._mlp(li, h, cur_w)
+        with _scopes.scope("head"):
+            x = self.ln_f(x)
+            if getattr(self, "_step_mode", None) == "spec_verify":
+                # speculative verify: logits at EVERY packed position
+                # (the engine samples each drafted slot with its own salt
+                # and accepts the longest matching run), not pick_last
+                logits = self.head(x)                    # [T, V]
             else:
-                out, _, new_kc, new_vc = outs
-            x = x + self._lin("proj", li, out, cur_w)
-            h = self.ln2[li](x)
-            x = x + self._mlp(li, h, cur_w)
-        x = self.ln_f(x)
-        if getattr(self, "_step_mode", None) == "spec_verify":
-            # speculative verify: logits at EVERY packed position (the
-            # engine samples each drafted slot with its own salt and
-            # accepts the longest matching run) instead of pick_last
-            logits = self.head(x)                        # [T, V]
-            if quant:
-                return logits, new_kc, new_vc, new_ks, new_vs
-            return logits, new_kc, new_vc
-        # last token of each row: cu_q[i+1]-1 (rows with 0 tokens this
-        # step read their previous row's last token — masked host-side)
-        def pick_last(xa, cu):
-            idx = jnp.maximum(cu[1:] - 1, 0)
-            return xa[idx]
+                # last token of each row: cu_q[i+1]-1 (rows with 0 tokens
+                # this step read their previous row's last token — masked
+                # host-side)
+                def pick_last(xa, cu):
+                    idx = jnp.maximum(cu[1:] - 1, 0)
+                    return xa[idx]
 
-        last = apply(pick_last, x, cu_seqlens_q, op_name="pick_last")
-        logits = self.head(last)                             # [B+1, V]
+                last = apply(pick_last, x, cu_seqlens_q,
+                             op_name="pick_last")
+                logits = self.head(last)                 # [B+1, V]
         if quant:
             return logits, new_kc, new_vc, new_ks, new_vs
         return logits, new_kc, new_vc
@@ -547,13 +575,14 @@ class PagedCausalLM(Layer):
 class _Request:
     __slots__ = ("rid", "prompt", "generated", "max_new", "pages",
                  "cached", "done", "sampling", "eos_token_id",
-                 "submit_t", "first_tok_t", "deadline_t", "timed_out",
+                 "submit_t", "first_tok_t", "last_tok_t", "deadline_t",
+                 "timed_out",
                  "shared_keys", "prefix_registered", "salt_rid",
                  "salt_seed", "trace", "sched_t0", "requeues", "tenant",
                  "spec_observed", "weight_version")
 
     def __init__(self, rid, prompt, max_new, sampling, eos_token_id,
-                 deadline_s=None):
+                 deadline_s=None, arrival_t=None):
         self.rid = rid
         self.prompt = list(int(t) for t in prompt)
         self.generated = []
@@ -563,10 +592,14 @@ class _Request:
         self.done = False
         self.sampling = sampling or GREEDY
         self.eos_token_id = eos_token_id
-        self.submit_t = time.perf_counter()
+        now = time.perf_counter()
+        # where the request's own clock starts: when it reached whoever
+        # called add_request, if they say, else this call
+        self.submit_t = now if arrival_t is None else float(arrival_t)
         self.first_tok_t = None
+        self.last_tok_t = None     # when the newest token arrived
         self.deadline_t = None if deadline_s is None \
-            else self.submit_t + float(deadline_s)
+            else now + float(deadline_s)
         self.timed_out = False
         # prefix-cache bookkeeping: trie node keys this request holds a
         # ref on (leading shared pages), and whether its own full prompt
@@ -704,6 +737,8 @@ class ServingEngine:
         # a per-replica child registry (Replica does this at wrap time)
         self.metrics_namespace = None
         self._m = _EngineMetrics(_metrics.registry())
+        # step programs already registered with profiler.scopes
+        self._programs = {}     # (name, token length) -> scopes.Program
         # rank the chaos injector sees for this engine's fault sites, so
         # PT_FAULT_PLAN ":rank=R" clauses target one replica of a fleet
         self.fault_rank = 0
@@ -830,6 +865,10 @@ class ServingEngine:
             finally:
                 object.__setattr__(model, "_step_mode", None)
 
+        # the programs' names in a device trace's "XLA Modules" line
+        pure.__name__ = "serving_step"
+        pure_fresh.__name__ = "serving_fresh_prefill"
+        pure_verify.__name__ = "serving_spec_verify"
         eng._params = jax.device_put(flat_p)
         eng._buffers = jax.device_put(flat_b)
         eng._compiled = jax.jit(pure)
@@ -843,8 +882,14 @@ class ServingEngine:
 
     # -- scheduling ------------------------------------------------------
     def add_request(self, prompt_tokens, max_new_tokens=8, sampling=None,
-                    eos_token_id=None, deadline_s=None, tenant=None):
-        """Admit one request. `deadline_s` (seconds from submit) bounds
+                    eos_token_id=None, deadline_s=None, tenant=None,
+                    arrival_t=None):
+        """Admit one request. `arrival_t` is the `time.perf_counter()`
+        at which the request reached the caller (a gateway, a router, a
+        load generator that knows when it was due): the request's
+        `submit_t`, its `serving::queue` span and `serving/ttft_ms` then
+        start there, not at this call, so time spent waiting to be
+        admitted counts. `deadline_s` (seconds from this call) bounds
         its total latency: a request still unfinished past its deadline
         is evicted at the next step (pages released, `timed_out` set)
         so a stuck/starved request cannot pin pool pages forever.
@@ -854,6 +899,7 @@ class ServingEngine:
         page quota.  Raises EngineOverloadedError when cfg.max_queue
         live requests already exist (load shedding at admission, not
         deep in the queue)."""
+        admit_t0 = time.perf_counter()
         self._check_alive()
         if len(prompt_tokens) == 0:
             raise ValueError("prompt must contain at least one token "
@@ -871,7 +917,8 @@ class ServingEngine:
         rid = self._next_rid
         self._next_rid += 1
         req = _Request(rid, prompt_tokens, max_new_tokens,
-                       sampling, eos_token_id, deadline_s=deadline_s)
+                       sampling, eos_token_id, deadline_s=deadline_s,
+                       arrival_t=arrival_t)
         req.tenant = tenant
         # pin the whole stream to the version serving at admission: KV
         # depends on params, so a mid-stream swap would mix versions —
@@ -882,7 +929,7 @@ class ServingEngine:
         # root (or ambient-parented) span of this request's trace; the
         # request adopts its context so every later lifecycle span links
         req.trace = _tracing.record_span(
-            "serving::admit", req.submit_t, time.perf_counter(),
+            "serving::admit", admit_t0, time.perf_counter(),
             args={"rid": rid, "engine": self.name})
         self._m.requests.inc()
         return rid
@@ -1308,6 +1355,7 @@ class ServingEngine:
         return sampling_salt(seed, r.salt_rid, n_generated)
 
     def _note_first_token(self, req, now):
+        req.last_tok_t = now
         if req.first_tok_t is None:
             req.first_tok_t = now
             self._m.ttft.observe((now - req.submit_t) * 1e3)
@@ -1414,43 +1462,73 @@ class ServingEngine:
         """One engine iteration: schedule <= max_batch live requests
         (prefill chunks + decode mixed) within the token budget, run the
         step function once, sample one token per request that reached its
-        sequence tip."""
-        with RecordEvent("serving::step"):
-            return self._step()
+        sequence tip.
 
-    def _step(self):
+        The step's own account of itself: one `serving::step` span (ring,
+        flight recorder, and a `TraceAnnotation` on the device trace's
+        clock) with what it held in its args, and a child for each phase
+        — `serving::schedule`, `serving::pack`, `serving::sample_sync`
+        (the wait for the chip), `serving::emit`."""
+        with _tracing.span("serving::step") as sp:
+            return self._step(sp.args)
+
+    def _count_step(self, note, rows, tokens, pad, prefill_tokens):
+        """What one step held: the `serving/step_*` counters, each bumped
+        once a step, and the same numbers as the step span's args."""
+        m = self._m
+        m.step_rows.inc(rows)
+        m.step_tokens.inc(tokens)
+        m.step_pad.inc(pad)
+        m.step_prefill.inc(prefill_tokens)
+        note.update(rows=rows, tokens=tokens, pad=pad,
+                    prefill_tokens=prefill_tokens)
+
+    def _finish(self, r, now):
+        """A request's last token has arrived: pages back, the decode span
+        closed, and its time per output token observed."""
+        r.done = True
+        self._release(r)
+        self._trace_done(r, now)
+        if len(r.generated) > 1 and r.first_tok_t is not None:
+            self._m.tpot.observe((r.last_tok_t - r.first_tok_t)
+                                 / (len(r.generated) - 1) * 1e3)
+
+    def _step(self, note):
         cfg = self.cfg
 
-        self._check_alive()
-        self._evict_expired()
-        rows = self._schedule()
-        preempted = set()
-        while not rows and self.pending():
-            # pool deadlock: in-flight requests hold pages but none can
-            # grow — preempt the NEWEST holder (FCFS priority: the oldest
-            # request always makes progress, so symmetric requests cannot
-            # thrash each other's pages), vLLM-style. The victim releases
-            # its pages and re-prefills prompt+generated in chunks later.
-            holders = [r for r in self.pending() if r.pages]
-            if not holders:
-                raise RuntimeError(
-                    "KV page pool exhausted: no pending request fits in "
-                    f"{len(self._free_pages)} free pages — raise "
-                    "num_blocks or lower concurrency")
-            victim = max(holders, key=lambda r: r.rid)
-            self._release(victim)
-            victim.cached = 0
-            victim.prefix_registered = False
-            if victim.rid not in preempted:
-                # its shared prefix may still be resident: re-match so
-                # the re-prefill only covers tokens past the cached
-                # blocks — but only ONCE per sweep (a re-acquired prefix
-                # makes the victim a page holder again; re-matching it
-                # every pass would spin this loop forever)
-                self._try_prefix_match(victim)
-            preempted.add(victim.rid)
-            self._m.preempt.inc()
+        with _tracing.phase("serving::schedule"):
+            self._check_alive()
+            self._evict_expired()
             rows = self._schedule()
+            preempted = set()
+            while not rows and self.pending():
+                # pool deadlock: in-flight requests hold pages but none
+                # can grow — preempt the NEWEST holder (FCFS priority: the
+                # oldest request always makes progress, so symmetric
+                # requests cannot thrash each other's pages), vLLM-style.
+                # The victim releases its pages and re-prefills
+                # prompt+generated in chunks later.
+                holders = [r for r in self.pending() if r.pages]
+                if not holders:
+                    raise RuntimeError(
+                        "KV page pool exhausted: no pending request fits "
+                        f"in {len(self._free_pages)} free pages — raise "
+                        "num_blocks or lower concurrency")
+                victim = max(holders, key=lambda r: r.rid)
+                self._release(victim)
+                victim.cached = 0
+                victim.prefix_registered = False
+                if victim.rid not in preempted:
+                    # its shared prefix may still be resident: re-match so
+                    # the re-prefill only covers tokens past the cached
+                    # blocks — but only ONCE per sweep (a re-acquired
+                    # prefix makes the victim a page holder again;
+                    # re-matching it every pass would spin this loop
+                    # forever)
+                    self._try_prefix_match(victim)
+                preempted.add(victim.rid)
+                self._m.preempt.inc()
+                rows = self._schedule()
         if not rows:
             return []
         # chaos sites, consulted BEFORE any page allocation or cache
@@ -1480,103 +1558,143 @@ class ServingEngine:
         if self._drafter is not None and all(
                 chunk == 1 and r.cached == r.length - 1
                 for r, chunk in rows):
-            return self._spec_step(rows)
+            return self._spec_step(rows, note)
 
-        B1 = cfg.max_batch + 1
-        enc = np.zeros(B1, np.int32)
-        dec = np.zeros(B1, np.int32)
-        this = np.zeros(B1, np.int32)
-        bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)  # 0 = trash
-        packed = []
-        for i, (r, chunk) in enumerate(rows):
-            seq = r.prompt + r.generated
-            dec[i] = r.cached                # chunk starts at this pos
-            this[i] = chunk
-            self._ensure_pages(r, r.cached + chunk)
-            bt[i, :len(r.pages)] = r.pages
-            packed.extend(seq[r.cached:r.cached + chunk])
-        self._update_pool_gauges(len(rows))
-        # padding tokens -> trash row (index B1-1, block table all page 0)
-        n_pad = cfg.token_budget - len(packed)
-        this[B1 - 1] = n_pad
-        enc[B1 - 1] = n_pad
-        tokens = np.asarray(packed + [0] * n_pad, np.int32)
-        cu = np.zeros(B1 + 1, np.int32)
-        cu[1:] = np.cumsum(this)
+        with _tracing.phase("serving::pack"):
+            B1 = cfg.max_batch + 1
+            enc = np.zeros(B1, np.int32)
+            dec = np.zeros(B1, np.int32)
+            this = np.zeros(B1, np.int32)
+            bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)  # 0=trash
+            packed = []
+            prefill_tokens = 0
+            for i, (r, chunk) in enumerate(rows):
+                seq = r.prompt + r.generated
+                dec[i] = r.cached                # chunk starts at this pos
+                this[i] = chunk
+                if r.cached < len(r.prompt):
+                    prefill_tokens += chunk
+                self._ensure_pages(r, r.cached + chunk)
+                bt[i, :len(r.pages)] = r.pages
+                packed.extend(seq[r.cached:r.cached + chunk])
+            self._update_pool_gauges(len(rows))
+            # padding tokens -> trash row (index B1-1, block table all
+            # page 0)
+            n_pad = cfg.token_budget - len(packed)
+            this[B1 - 1] = n_pad
+            enc[B1 - 1] = n_pad
+            tokens = np.asarray(packed + [0] * n_pad, np.int32)
+            cu = np.zeros(B1 + 1, np.int32)
+            cu[1:] = np.cumsum(this)
+            self._count_step(note, len(rows), len(packed), n_pad,
+                             prefill_tokens)
 
-        # fresh-prefill steps (every scheduled row starts at cache pos 0)
-        # run the varlen-flash specialization: block-diagonal attention
-        # over the packed tokens instead of the page-pool gather
-        fresh = self._compiled_fresh is not None \
-            and all(r.cached == 0 for r, _ in rows)
-        compiled = self._compiled_fresh if fresh else self._compiled
-        extra = (self._ks, self._vs) if self._ks is not None else ()
-        # bind the step's pinned weight version (_schedule guarantees
-        # every scheduled row shares it); shapes/dtypes are identical
-        # across versions so no retrace happens on a swap
-        fp = self._params_for(rows[0][0].weight_version)
-        out = compiled(fp, self._buffers, tokens,
-                       enc, dec, this, cu, bt, self._kc, self._vc,
-                       *extra)
-        logits = out[0]
-        self._set_caches(out[1], out[2])
-        if self._ks is not None:
-            self._ks, self._vs = out[3], out[4]
+            # fresh-prefill steps (every scheduled row starts at cache pos
+            # 0) run the varlen-flash specialization: block-diagonal
+            # attention over the packed tokens instead of the page-pool
+            # gather
+            fresh = self._compiled_fresh is not None \
+                and all(r.cached == 0 for r, _ in rows)
+            compiled = self._compiled_fresh if fresh else self._compiled
+            extra = (self._ks, self._vs) if self._ks is not None else ()
+            # bind the step's pinned weight version (_schedule guarantees
+            # every scheduled row shares it); shapes/dtypes are identical
+            # across versions so no retrace happens on a swap
+            fp = self._params_for(rows[0][0].weight_version)
+            args = (fp, self._buffers, tokens, enc, dec, this, cu, bt,
+                    self._kc, self._vc, *extra)
+            self._register_program(
+                "serving_fresh_prefill" if fresh else "serving_step",
+                compiled, args)
+            out = compiled(*args)
+            logits = out[0]
+            self._set_caches(out[1], out[2])
+            if self._ks is not None:
+                self._ks, self._vs = out[3], out[4]
 
-        # device-side sampling for rows that reached their sequence tip
-        temps = np.zeros(B1, np.float32)
-        topks = np.zeros(B1, np.int32)
-        topps = np.ones(B1, np.float32)
-        salts = np.zeros(B1, np.int32)
-        tip = [False] * len(rows)
-        for i, (r, chunk) in enumerate(rows):
-            if r.cached + chunk == r.length:
-                tip[i] = True
-                sp = r.sampling
-                temps[i] = sp.temperature
-                topks[i] = sp.top_k
-                topps[i] = sp.top_p
-                salts[i] = self._salt(r, len(r.generated))
+            # device-side sampling for rows that reached their sequence
+            # tip
+            temps = np.zeros(B1, np.float32)
+            topks = np.zeros(B1, np.int32)
+            topps = np.ones(B1, np.float32)
+            salts = np.zeros(B1, np.int32)
+            tip = [False] * len(rows)
+            for i, (r, chunk) in enumerate(rows):
+                if r.cached + chunk == r.length:
+                    tip[i] = True
+                    sp = r.sampling
+                    temps[i] = sp.temperature
+                    topks[i] = sp.top_k
+                    topps[i] = sp.top_p
+                    salts[i] = self._salt(r, len(r.generated))
         if not any(tip):
             # pure prefill-chunk step: nothing to sample — skip the
             # sampler dispatch AND the host round-trip entirely
-            for r, chunk in rows:
+            with _tracing.phase("serving::emit"):
+                for r, chunk in rows:
+                    r.cached += chunk
+                    self._maybe_register_prefix(r)
+            return []
+        with _tracing.phase("serving::sample_sync"):
+            sampled = self._sample(logits, temps, topks, topps, salts)
+
+        with _tracing.phase("serving::emit"):
+            produced = []
+            now = time.perf_counter()
+            for i, (r, chunk) in enumerate(rows):
                 r.cached += chunk
                 self._maybe_register_prefix(r)
-            return []
-        # fast paths: skip the full-vocab sort when no row samples, or
-        # when every sampling row fits the exact top-k candidate sampler
-        if not np.any(temps > 0):
-            sampled = np.asarray(_greedy_tokens_dev(logits))
-        elif _topk_fast_ok(temps, topks):
-            sampled = np.asarray(_sample_topk_dev(
-                logits, temps, topks, topps, salts))
-        else:
-            sampled = np.asarray(_sample_tokens_dev(
-                logits, temps, topks, topps, salts))
-
-        produced = []
-        now = time.perf_counter()
-        for i, (r, chunk) in enumerate(rows):
-            r.cached += chunk
-            self._maybe_register_prefix(r)
-            if not tip[i]:
-                continue
-            nxt = int(sampled[i])
-            r.generated.append(nxt)
-            produced.append((r.rid, nxt))
-            self._note_first_token(r, now)
-            if len(r.generated) >= r.max_new \
-                    or (r.eos_token_id is not None
-                        and nxt == r.eos_token_id):
-                r.done = True
-                self._release(r)
-                self._trace_done(r, now)
-        self._m.tokens.inc(len(produced))
+                if not tip[i]:
+                    continue
+                nxt = int(sampled[i])
+                r.generated.append(nxt)
+                produced.append((r.rid, nxt))
+                self._note_first_token(r, now)
+                if len(r.generated) >= r.max_new \
+                        or (r.eos_token_id is not None
+                            and nxt == r.eos_token_id):
+                    self._finish(r, now)
+            self._m.tokens.inc(len(produced))
         return produced
 
+    def _register_program(self, name, jitted, args, tok_len=None):
+        """First call of a step program (of each token length, where it
+        has several): keep how to compile it again (shapes and shardings
+        of this call), so device time can be split by its named scopes.
+        The entry is this engine's own (every engine of a process has a
+        `serving_step`) and references the function weakly: the step's
+        closure holds the model, and nothing may keep that alive once the
+        engine and the model are dropped."""
+        if (name, tok_len) not in self._programs:
+            self._programs[name, tok_len] = _scopes.register_program(
+                name, jitted, _scopes.abstract(args), weak=True)
+
+    def phase_map(self, program="serving_step", tok_len=None):
+        """{HLO instruction name: (pass, block)} of one of this engine's
+        step programs once it has run, None before: `serving_step`,
+        `serving_fresh_prefill`, or `serving_spec_verify` at one of its
+        token lengths (`tok_len`, a verify shape this engine has run).
+        Compiles the program again (a hit in the persistent compilation
+        cache): ask after the window, while the engine lives."""
+        prog = self._programs.get((program, tok_len))
+        return prog.phases() if prog is not None else None
+
+    @staticmethod
+    def _sample(logits, temps, topks, topps, salts):
+        """Sampler dispatch and the fetch of its tokens: the step's wait
+        for the chip. Fast paths: skip the full-vocab sort when no row
+        samples, or when every sampling row fits the exact top-k
+        candidate sampler."""
+        if not np.any(temps > 0):
+            return np.asarray(_greedy_tokens_dev(logits))
+        if _topk_fast_ok(temps, topks):
+            return np.asarray(_sample_topk_dev(
+                logits, temps, topks, topps, salts))
+        return np.asarray(_sample_tokens_dev(
+            logits, temps, topks, topps, salts))
+
     # -- speculative decode (draft k, verify in one paged step) ----------
-    def _spec_step(self, rows):
+    def _spec_step(self, rows, note):
         """One speculative iteration over decode-tip rows: the drafter
         proposes up to ``_spec_k`` tokens per row, the target model
         scores tip+drafts in ONE paged-attention dispatch (the verify
@@ -1588,158 +1706,155 @@ class ServingEngine:
         identical to non-speculative decoding; KV pages holding only
         rejected-tail slots roll back to the pool, leaving each row at
         its decode tip (migratable/requeueable) after every step."""
-        cfg = self.cfg
-        B1 = cfg.max_batch + 1
-        drafter = self._drafter
+        with _tracing.phase("serving::pack"):
+            cfg = self.cfg
+            B1 = cfg.max_batch + 1
+            drafter = self._drafter
 
-        # plan: per-row draft length, clamped to the remaining max_new
-        # budget (later rows keep >= 1 slot each) and the page pool
-        budget = cfg.token_budget
-        avail = len(self._free_pages)
-        if self._prefix_cache is not None:
-            avail += self._prefix_cache.evictable_count()
-        plans = []
-        for idx, (r, _chunk) in enumerate(rows):
-            self._spec_observe(r)
-            rows_after = len(rows) - idx - 1
-            cap = min(self._spec_k,
-                      r.max_new - len(r.generated) - 1,
-                      budget - 1 - rows_after)
-            drafts = []
-            if cap > 0:
-                proposed = drafter.propose(r.prompt + r.generated, cap)
-                for t in list(proposed)[:cap]:
-                    t = int(t)
-                    if not 0 <= t < cfg.vocab_size:
-                        break      # alien draft vocab: stop the run
-                    drafts.append(t)
-            while drafts and max(
-                    math.ceil((r.cached + 1 + len(drafts))
-                              / cfg.block_size) - len(r.pages),
-                    0) > avail:
-                drafts.pop()       # page-limited: shorten the proposal
-            avail -= max(math.ceil((r.cached + 1 + len(drafts))
-                                   / cfg.block_size) - len(r.pages), 0)
-            budget -= 1 + len(drafts)
-            plans.append((r, drafts))
+            # plan: per-row draft length, clamped to the remaining max_new
+            # budget (later rows keep >= 1 slot each) and the page pool
+            budget = cfg.token_budget
+            avail = len(self._free_pages)
+            if self._prefix_cache is not None:
+                avail += self._prefix_cache.evictable_count()
+            plans = []
+            for idx, (r, _chunk) in enumerate(rows):
+                self._spec_observe(r)
+                rows_after = len(rows) - idx - 1
+                cap = min(self._spec_k,
+                          r.max_new - len(r.generated) - 1,
+                          budget - 1 - rows_after)
+                drafts = []
+                if cap > 0:
+                    proposed = drafter.propose(r.prompt + r.generated, cap)
+                    for t in list(proposed)[:cap]:
+                        t = int(t)
+                        if not 0 <= t < cfg.vocab_size:
+                            break      # alien draft vocab: stop the run
+                        drafts.append(t)
+                while drafts and max(
+                        math.ceil((r.cached + 1 + len(drafts))
+                                  / cfg.block_size) - len(r.pages),
+                        0) > avail:
+                    drafts.pop()       # page-limited: shorten the proposal
+                avail -= max(math.ceil((r.cached + 1 + len(drafts))
+                                       / cfg.block_size) - len(r.pages), 0)
+                budget -= 1 + len(drafts)
+                plans.append((r, drafts))
 
-        enc = np.zeros(B1, np.int32)
-        dec = np.zeros(B1, np.int32)
-        this = np.zeros(B1, np.int32)
-        bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)
-        packed = []
-        spans = []
-        for i, (r, drafts) in enumerate(plans):
-            n_feed = 1 + len(drafts)
-            dec[i] = r.cached
-            this[i] = n_feed
-            self._ensure_pages(r, r.cached + n_feed)
-            bt[i, :len(r.pages)] = r.pages
-            spans.append((len(packed), n_feed))
-            packed.append((r.prompt + r.generated)[-1])
-            packed.extend(drafts)
-        self._update_pool_gauges(len(plans))
-        # pad to a power-of-two token length (the trash row absorbs the
-        # padding, exactly as in _step) so verify executables stay
-        # bounded at log2(token_budget) shapes
-        tok_len = self._fixed_token_len \
-            or min(_next_pow2(len(packed)), cfg.token_budget)
-        if tok_len not in self._spec_shapes:
-            if self._spec_shapes:
-                from ..jit.api import note_retrace
+            enc = np.zeros(B1, np.int32)
+            dec = np.zeros(B1, np.int32)
+            this = np.zeros(B1, np.int32)
+            bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)
+            packed = []
+            spans = []
+            for i, (r, drafts) in enumerate(plans):
+                n_feed = 1 + len(drafts)
+                dec[i] = r.cached
+                this[i] = n_feed
+                self._ensure_pages(r, r.cached + n_feed)
+                bt[i, :len(r.pages)] = r.pages
+                spans.append((len(packed), n_feed))
+                packed.append((r.prompt + r.generated)[-1])
+                packed.extend(drafts)
+            self._update_pool_gauges(len(plans))
+            # pad to a power-of-two token length (the trash row absorbs the
+            # padding, exactly as in _step) so verify executables stay
+            # bounded at log2(token_budget) shapes
+            tok_len = self._fixed_token_len \
+                or min(_next_pow2(len(packed)), cfg.token_budget)
+            if tok_len not in self._spec_shapes:
+                if self._spec_shapes:
+                    from ..jit.api import note_retrace
 
-                note_retrace("spec_verify")
-            self._spec_shapes.add(tok_len)
-            self._m.fused_regions.inc()
-        n_pad = tok_len - len(packed)
-        this[B1 - 1] = n_pad
-        enc[B1 - 1] = n_pad
-        tokens = np.asarray(packed + [0] * n_pad, np.int32)
-        cu = np.zeros(B1 + 1, np.int32)
-        cu[1:] = np.cumsum(this)
+                    note_retrace("spec_verify")
+                self._spec_shapes.add(tok_len)
+                self._m.fused_regions.inc()
+            n_pad = tok_len - len(packed)
+            this[B1 - 1] = n_pad
+            enc[B1 - 1] = n_pad
+            tokens = np.asarray(packed + [0] * n_pad, np.int32)
+            cu = np.zeros(B1 + 1, np.int32)
+            cu[1:] = np.cumsum(this)
+            self._count_step(note, len(plans), len(packed), n_pad, 0)
 
-        extra = (self._ks, self._vs) if self._ks is not None else ()
-        out = self._compiled_verify(
-            self._params_for(plans[0][0].weight_version),
-            self._buffers, tokens, enc, dec, this, cu,
-            bt, self._kc, self._vc, *extra)
-        logits = out[0]                                # [tok_len, V]
-        self._set_caches(out[1], out[2])
-        if self._ks is not None:
-            self._ks, self._vs = out[3], out[4]
+            extra = (self._ks, self._vs) if self._ks is not None else ()
+            args = (self._params_for(plans[0][0].weight_version),
+                    self._buffers, tokens, enc, dec, this, cu,
+                    bt, self._kc, self._vc, *extra)
+            self._register_program("serving_spec_verify",
+                                   self._compiled_verify, args, tok_len)
+            out = self._compiled_verify(*args)
+            logits = out[0]                                # [tok_len, V]
+            self._set_caches(out[1], out[2])
+            if self._ks is not None:
+                self._ks, self._vs = out[3], out[4]
 
-        # sample EVERY fed position under its own schedule-independent
-        # salt: position j of row r is generated-index g0+j, so the
-        # draw equals what the plain path would make there
-        P = len(packed)
-        Pb = min(_next_pow2(max(P, 1)), tok_len)
-        temps = np.zeros(Pb, np.float32)
-        topks = np.zeros(Pb, np.int32)
-        topps = np.ones(Pb, np.float32)
-        salts = np.zeros(Pb, np.int32)
-        for i, (r, _drafts) in enumerate(plans):
-            p0, n_feed = spans[i]
-            sp = r.sampling
-            g0 = len(r.generated)
-            for j in range(n_feed):
-                temps[p0 + j] = sp.temperature
-                topks[p0 + j] = sp.top_k
-                topps[p0 + j] = sp.top_p
-                salts[p0 + j] = self._salt(r, g0 + j)
-        lg = logits[:Pb]
-        if not np.any(temps > 0):
-            sampled = np.asarray(_greedy_tokens_dev(lg))
-        elif _topk_fast_ok(temps, topks):
-            sampled = np.asarray(_sample_topk_dev(
-                lg, temps, topks, topps, salts))
-        else:
-            sampled = np.asarray(_sample_tokens_dev(
-                lg, temps, topks, topps, salts))
+            # sample EVERY fed position under its own schedule-independent
+            # salt: position j of row r is generated-index g0+j, so the
+            # draw equals what the plain path would make there
+            P = len(packed)
+            Pb = min(_next_pow2(max(P, 1)), tok_len)
+            temps = np.zeros(Pb, np.float32)
+            topks = np.zeros(Pb, np.int32)
+            topps = np.ones(Pb, np.float32)
+            salts = np.zeros(Pb, np.int32)
+            for i, (r, _drafts) in enumerate(plans):
+                p0, n_feed = spans[i]
+                sp = r.sampling
+                g0 = len(r.generated)
+                for j in range(n_feed):
+                    temps[p0 + j] = sp.temperature
+                    topks[p0 + j] = sp.top_k
+                    topps[p0 + j] = sp.top_p
+                    salts[p0 + j] = self._salt(r, g0 + j)
+        with _tracing.phase("serving::sample_sync"):
+            sampled = self._sample(logits[:Pb], temps, topks, topps, salts)
 
-        produced = []
-        now = time.perf_counter()
-        for i, (r, drafts) in enumerate(plans):
-            p0, n_feed = spans[i]
-            # accept the longest run of drafts matching the target's
-            # own sampled choices; the first mismatch position still
-            # yields its (correct) target-sampled token
-            emitted = [int(sampled[p0])]
-            for j in range(1, n_feed):
-                if drafts[j - 1] != emitted[-1]:
-                    break
-                emitted.append(int(sampled[p0 + j]))
-            self._spec_drafted_total += len(drafts)
-            self._spec_accepted_total += len(emitted) - 1
-            self._m.spec_drafted.inc(len(drafts))
-            self._m.spec_accepted.inc(len(emitted) - 1)
-            for t in emitted:
-                r.generated.append(t)
-                produced.append((r.rid, t))
-                self._note_first_token(r, now)
-                if len(r.generated) >= r.max_new \
-                        or (r.eos_token_id is not None
-                            and t == r.eos_token_id):
-                    r.done = True
-                    break
-            # back to the decode tip: KV for the accepted run is valid;
-            # pages holding only rejected-tail slots return to the pool
-            r.cached = r.length - 1
-            self._maybe_register_prefix(r)
-            if r.done:
-                self._release(r)
-                self._trace_done(r, now)
-            else:
-                keep = math.ceil(r.cached / cfg.block_size)
-                if len(r.pages) > keep:
-                    self._free_pages.extend(r.pages[keep:])
-                    del r.pages[keep:]
-        self._m.spec_steps.inc()
-        self._m.tokens.inc(len(produced))
-        if self._spec_drafted_total:
-            self._m.spec_accept_rate.set(
-                self._spec_accepted_total / self._spec_drafted_total)
-        if plans:
-            self._m.spec_tokens_per_step.set(len(produced) / len(plans))
+        with _tracing.phase("serving::emit"):
+            produced = []
+            now = time.perf_counter()
+            for i, (r, drafts) in enumerate(plans):
+                p0, n_feed = spans[i]
+                # accept the longest run of drafts matching the target's
+                # own sampled choices; the first mismatch position still
+                # yields its (correct) target-sampled token
+                emitted = [int(sampled[p0])]
+                for j in range(1, n_feed):
+                    if drafts[j - 1] != emitted[-1]:
+                        break
+                    emitted.append(int(sampled[p0 + j]))
+                self._spec_drafted_total += len(drafts)
+                self._spec_accepted_total += len(emitted) - 1
+                self._m.spec_drafted.inc(len(drafts))
+                self._m.spec_accepted.inc(len(emitted) - 1)
+                for t in emitted:
+                    r.generated.append(t)
+                    produced.append((r.rid, t))
+                    self._note_first_token(r, now)
+                    if len(r.generated) >= r.max_new \
+                            or (r.eos_token_id is not None
+                                and t == r.eos_token_id):
+                        r.done = True
+                        break
+                # back to the decode tip: KV for the accepted run is valid;
+                # pages holding only rejected-tail slots return to the pool
+                r.cached = r.length - 1
+                self._maybe_register_prefix(r)
+                if r.done:
+                    self._finish(r, now)
+                else:
+                    keep = math.ceil(r.cached / cfg.block_size)
+                    if len(r.pages) > keep:
+                        self._free_pages.extend(r.pages[keep:])
+                        del r.pages[keep:]
+            self._m.spec_steps.inc()
+            self._m.tokens.inc(len(produced))
+            if self._spec_drafted_total:
+                self._m.spec_accept_rate.set(
+                    self._spec_accepted_total / self._spec_drafted_total)
+            if plans:
+                self._m.spec_tokens_per_step.set(len(produced) / len(plans))
         return produced
 
     # -- multi-step decode (one device program per window) ---------------
@@ -1834,7 +1949,6 @@ class ServingEngine:
 
     def _decode_run(self, n_steps):
         cfg = self.cfg
-        t_start = time.perf_counter()
         self._check_alive()
         self._evict_expired()
         rows = [r for r in self.pending()
@@ -1894,6 +2008,10 @@ class ServingEngine:
         enc[B1 - 1] = n_pad
         cu = np.zeros(B1 + 1, np.int32)
         cu[1:] = np.cumsum(this)
+        # the window's n steps in the step account: B rows of one token
+        self._m.step_rows.inc(B * n)
+        self._m.step_tokens.inc(B * n)
+        self._m.step_pad.inc(n_pad * n)
         bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)
         for i, r in enumerate(rows):
             bt[i, :len(r.pages)] = r.pages
@@ -1934,7 +2052,6 @@ class ServingEngine:
             self._ks, self._vs = scales
         fetched = np.asarray(samples)                    # [n, B1] — sync
         now = time.perf_counter()
-        self._m.tpot.observe((now - t_start) / n * 1e3)
         produced = []
         for j in range(n):
             for i, r in enumerate(rows):
@@ -1948,9 +2065,7 @@ class ServingEngine:
                 if len(r.generated) >= r.max_new \
                         or (r.eos_token_id is not None
                             and nxt == r.eos_token_id):
-                    r.done = True
-                    self._release(r)
-                    self._trace_done(r, now)
+                    self._finish(r, now)
         self._m.tokens.inc(len(produced))
         return produced
 

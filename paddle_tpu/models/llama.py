@@ -39,6 +39,7 @@ from ..ops.pallas import flash_attention as fa
 from ..ops.pallas import per_shard
 from ..ops.pallas import ring_attention as ra
 from ..ops.pallas import rms_norm as rn
+from ..profiler.scopes import scope
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel",
            "forward_stacked", "loss_fn_stacked", "loss_fn_pipelined",
@@ -499,15 +500,6 @@ def _block(params, x, config: LlamaConfig, mesh=None):
                    config.head_dim)
     b, s, _ = x.shape
 
-    hx = _rms_norm(x, params["ln_attn"], config.rms_norm_eps, mesh)
-    q = (hx @ params["wq"]).reshape(b, s, nh, hd)
-    k = (hx @ params["wk"]).reshape(b, s, kvh, hd)
-    v = (hx @ params["wv"]).reshape(b, s, kvh, hd)
-    q, k = _rope(q, k, config.rope_theta)
-    if nh != kvh:
-        rep = nh // kvh
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
     from jax.ad_checkpoint import checkpoint_name
 
     # the ring asks axis_index('sep'), which jax 0.9 cannot lower in a
@@ -521,17 +513,28 @@ def _block(params, x, config: LlamaConfig, mesh=None):
                                           is_causal=True)
         return fa.flash_attention_bshd(qq, kk, vv, is_causal=True)
 
-    # [B, S, heads, D]; batch and heads are independent: each device
-    # attends for its own (batch, heads) shard — as a ring over the
-    # sequence shards, or over the whole sequence
-    spec = P(("dp", "sharding"), "sep" if ring else None, "mp", None)
-    attn = per_shard(attend, mesh, (spec,) * 3, spec)(q, k, v)
-    attn = checkpoint_name(attn, "flash_attn_out")
-    x = x + attn.reshape(b, s, h) @ params["wo"]
+    with scope("attention"):
+        hx = _rms_norm(x, params["ln_attn"], config.rms_norm_eps, mesh)
+        q = (hx @ params["wq"]).reshape(b, s, nh, hd)
+        k = (hx @ params["wk"]).reshape(b, s, kvh, hd)
+        v = (hx @ params["wv"]).reshape(b, s, kvh, hd)
+        q, k = _rope(q, k, config.rope_theta)
+        if nh != kvh:
+            rep = nh // kvh
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        # [B, S, heads, D]; batch and heads are independent: each device
+        # attends for its own (batch, heads) shard — as a ring over the
+        # sequence shards, or over the whole sequence
+        spec = P(("dp", "sharding"), "sep" if ring else None, "mp", None)
+        attn = per_shard(attend, mesh, (spec,) * 3, spec)(q, k, v)
+        attn = checkpoint_name(attn, "flash_attn_out")
+        x = x + attn.reshape(b, s, h) @ params["wo"]
 
-    hx = _rms_norm(x, params["ln_mlp"], config.rms_norm_eps, mesh)
-    gated = jax.nn.silu(hx @ params["w_gate"]) * (hx @ params["w_up"])
-    x = x + gated @ params["w_down"]
+    with scope("mlp"):
+        hx = _rms_norm(x, params["ln_mlp"], config.rms_norm_eps, mesh)
+        gated = jax.nn.silu(hx @ params["w_gate"]) * (hx @ params["w_up"])
+        x = x + gated @ params["w_down"]
     return x
 
 
@@ -540,9 +543,10 @@ def _trunk(params, input_ids, config: LlamaConfig, remat: bool = True,
     """Embedding -> lax.scan over stacked blocks (constant compile time in
     depth; blocks rematerialized in backward when remat=True). The single
     source of the trunk pattern for the stacked forward/loss paths."""
-    x = jnp.take(params["embed"], input_ids, axis=0)
-    if config.dtype == "bfloat16":
-        x = x.astype(jnp.bfloat16)
+    with scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0)
+        if config.dtype == "bfloat16":
+            x = x.astype(jnp.bfloat16)
 
     def body(carry, layer_params):
         return _block(layer_params, carry, config, mesh=mesh), None
@@ -573,15 +577,17 @@ def forward_stacked(params, input_ids, config: LlamaConfig,
 def _head_loss(params, h, labels, config: LlamaConfig, mesh=None):
     """Shared tail of both training paths: final norm -> LM head ->
     mean next-token NLL. h: [..., S, H], labels: [..., S]."""
-    h = _rms_norm(h, params["final_norm"], config.rms_norm_eps, mesh)
-    logits = h.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    # lse - picked, not log_softmax: avoids materializing a second
-    # [.., S, V] fp32 array (reductions fuse into one pass over logits)
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-    lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1))
-    picked = jnp.take_along_axis(
-        logits, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    return jnp.mean(lse - picked)
+    with scope("head_loss"):
+        h = _rms_norm(h, params["final_norm"], config.rms_norm_eps, mesh)
+        logits = h.astype(jnp.float32) \
+            @ params["lm_head"].astype(jnp.float32)
+        # lse - picked, not log_softmax: avoids materializing a second
+        # [.., S, V] fp32 array (reductions fuse into one pass over logits)
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+        lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1))
+        picked = jnp.take_along_axis(
+            logits, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return jnp.mean(lse - picked)
 
 
 def loss_fn_stacked(params, batch, config: LlamaConfig, remat: bool = True,
@@ -631,9 +637,10 @@ def loss_fn_pipelined(params, batch, config: LlamaConfig, mesh,
 
     input_ids, labels = batch
     n_micro = input_ids.shape[0]
-    x = jnp.take(params["embed"], input_ids, axis=0)  # [NM, mb, S, H]
-    if config.dtype == "bfloat16":
-        x = x.astype(jnp.bfloat16)
+    with scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0)  # [NM, mb, S, H]
+        if config.dtype == "bfloat16":
+            x = x.astype(jnp.bfloat16)
 
     def stage_fn(stage_blocks, h):
         def body(c, bp):

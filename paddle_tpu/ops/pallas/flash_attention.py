@@ -232,6 +232,7 @@ def _pallas_forward(q, k, v, kmask, seed, causal, dropout_p,
             pl.BlockSpec((1, 1, 8, block_q), lambda i, j: (i, j, 0, 0)),
         ),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="flash_attention_fwd", metadata={"kernel": "flash_attention_fwd"},
         interpret=_interpret(),
     )(*operands)
     lse = lse[:, :, 0, :].reshape(bh, sq)
@@ -504,6 +505,7 @@ def _pallas_backward(q, k, v, kmask, seed, o, lse, do, causal, dropout_p,
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="flash_attention_dkv", metadata={"kernel": "flash_attention_dkv"},
         interpret=_interpret(),
     )(*dkv_operands)
 
@@ -533,6 +535,7 @@ def _pallas_backward(q, k, v, kmask, seed, o, lse, do, causal, dropout_p,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="flash_attention_dq", metadata={"kernel": "flash_attention_dq"},
         interpret=_interpret(),
     )(*dq_operands)
 

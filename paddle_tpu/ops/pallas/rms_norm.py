@@ -63,6 +63,7 @@ def _pallas_forward(x, weight, eps):
                 pl.BlockSpec((h,), lambda i: (0,)),
             ],
             out_specs=pl.BlockSpec((block, h), lambda i: (i, 0)),
+            name="rms_norm", metadata={"kernel": "rms_norm"},
             interpret=interpret(),
         )(x2, weight)
     else:
@@ -72,6 +73,7 @@ def _pallas_forward(x, weight, eps):
             grid=grid,
             in_specs=[pl.BlockSpec((block, h), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((block, h), lambda i: (i, 0)),
+            name="rms_norm_noweight", metadata={"kernel": "rms_norm_noweight"},
             interpret=interpret(),
         )(x2)
     return out.reshape(orig_shape)

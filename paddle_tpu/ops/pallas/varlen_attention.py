@@ -135,6 +135,7 @@ def _vfa_forward(q, k, v, segq, segk, causal, block_q, block_k):
             pl.BlockSpec((1, 1, 8, block_q), lambda i, j: (i, j, 0, 0)),
         ),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="varlen_attention_fwd", metadata={"kernel": "varlen_attention_fwd"},
         interpret=_interpret(),
     )(segq8, segk8, q3, k3, v3)
     lse = lse[:, :, 0, :].reshape(bh, sq)
@@ -278,6 +279,7 @@ def _vfa_backward(q, k, v, segq, segk, o, lse, do, causal,
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="varlen_attention_dkv", metadata={"kernel": "varlen_attention_dkv"},
         interpret=_interpret(),
     )(segq8, segk8, q3, do3, k3, v3, lse8, delta8)
 
@@ -298,6 +300,7 @@ def _vfa_backward(q, k, v, segq, segk, o, lse, do, causal,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         compiler_params=_dim_semantics("parallel", "arbitrary"),
+        name="varlen_attention_dq", metadata={"kernel": "varlen_attention_dq"},
         interpret=_interpret(),
     )(segq8, segk8, q3, do3, k3, v3, lse8, delta8)
 

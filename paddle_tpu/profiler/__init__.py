@@ -9,7 +9,10 @@ from __future__ import annotations
 
 import contextlib
 import enum
+import glob
 import os
+import re
+import tempfile
 import threading
 import time
 from collections import defaultdict
@@ -22,7 +25,8 @@ from . import metrics
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "export_protobuf",
            "load_profiler_result", "SummaryView", "metrics",
-           "host_tracing_active", "tracing", "digest", "aggregate",
+           "host_tracing_active", "tracing", "scopes", "digest",
+           "aggregate",
            "timeline", "slo", "headroom", "TraceContext"]
 
 
@@ -87,13 +91,29 @@ def host_tracing_active() -> bool:
 
 
 class RecordEvent:
-    """Host instrumentation span (reference: platform/profiler RecordEvent)."""
+    """Host instrumentation span (reference: platform/profiler RecordEvent).
+
+    One span path, one clock: besides the host-event list of an active
+    `Profiler`, the span enters a `jax.profiler.TraceAnnotation` of the
+    same name, so it lands in the xplane of whatever device trace is
+    running (a `Profiler`'s, `jax.profiler.trace`, a profiler server's)
+    beside the device's operations. With no trace running the annotation
+    is a flag test inside the profiler library. `traced` says, once the
+    span has ended, whether a trace was recording from its start to its
+    end: whether the xplane holds it whole."""
+
+    __slots__ = ("name", "begin", "traced", "_annotation")
 
     def __init__(self, name: str, event_type=None):
         self.name = name
         self.begin = None
+        self.traced = False
+        self._annotation = None
 
     def __enter__(self):
+        self.traced = jax.profiler.TraceAnnotation.is_enabled()
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.begin = time.perf_counter()
         return self
 
@@ -102,10 +122,16 @@ class RecordEvent:
         return False
 
     def end(self):
-        if self.begin is not None and _collector.active:
-            _collector.events.append(
-                (self.name, self.begin, time.perf_counter()))
-            self.begin = None
+        if self.begin is None:
+            return
+        end = time.perf_counter()
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        self.traced = self.traced \
+            and jax.profiler.TraceAnnotation.is_enabled()
+        if _collector.active:
+            _collector.events.append((self.name, self.begin, end))
+        self.begin = None
 
 
 def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
@@ -148,6 +174,7 @@ class Profiler:
         self.state = ProfilerState.CLOSED
         self._jax_tracing = False
         self._trace_dir = None
+        self._trace_started = 0.0
         self._step_times = []
         self._last_step_t = None
 
@@ -160,22 +187,19 @@ class Profiler:
         return False
 
     def _jax_start(self):
+        """Start the device trace. A failure raises: a session that
+        silently records no xplane reports nothing in its ModelView."""
         if not self._jax_tracing and not self.timer_only:
-            self._trace_dir = os.environ.get(
-                "PT_PROFILE_DIR", "/tmp/paddle_tpu_profile")
-            try:
-                jax.profiler.start_trace(self._trace_dir)
-                self._jax_tracing = True
-            except Exception:
-                self._jax_tracing = False
+            self._trace_dir = os.environ.get("PT_PROFILE_DIR") \
+                or os.path.join(tempfile.gettempdir(), "paddle_tpu_profile")
+            self._trace_started = time.time() - 1.0   # mtime's grain
+            jax.profiler.start_trace(self._trace_dir)
+            self._jax_tracing = True
 
     def _jax_stop(self):
         if self._jax_tracing:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
             self._jax_tracing = False
+            jax.profiler.stop_trace()
 
     def start(self):
         _collector.active = True
@@ -229,6 +253,14 @@ class Profiler:
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms", views=None):
+        """The host-span table; with `views=SummaryView.ModelView` (or a
+        list holding it) the device time by pass and block instead, read
+        from the xplane this session wrote."""
+        if views is not None and SummaryView.ModelView in (
+                views if isinstance(views, (list, tuple)) else [views]):
+            table = self.model_view()
+            print(table)
+            return table
         agg = defaultdict(lambda: [0.0, 0])
         for name, b, e in _collector.events:
             agg[name][0] += (e - b) * 1e3
@@ -242,6 +274,62 @@ class Profiler:
         table = "\n".join(lines)
         print(table)
         return table
+
+    def model_view(self) -> str:
+        """Device time by pass (forward / recompute / backward /
+        optimizer) and block (`scopes.scope` names) of every program
+        `profiler.scopes` can still compile, from the newest xplane under
+        this session's trace directory: the reference profiler's
+        ModelView. The device's events are joined with each program's
+        compiled text by instruction name (`scopes.device_time_by_phase`);
+        where the trace says which program ran when (a TPU's "XLA Modules"
+        line) a program is given only its own events."""
+        paths = [] if self._trace_dir is None else sorted(
+            (p for p in glob.glob(os.path.join(
+                self._trace_dir, "**", "*.xplane.pb"), recursive=True)
+             if os.path.getmtime(p) >= self._trace_started),
+            key=os.path.getmtime)
+        if not paths:
+            return "ModelView: this session wrote no device trace"
+        ops, modules = _device_events(paths[-1])
+        lines = [f"{'Program / pass / block':<44} {'ms':>12} {'share':>8}"]
+        by_name = defaultdict(list)
+        for prog in scopes.live_programs():
+            by_name[prog.name].append(prog)
+        for program, progs in by_name.items():
+            runs = [(s, e) for n, s, e in modules
+                    if n.startswith(f"jit_{program}(")]
+            mine = [ev for ev in ops
+                    if any(s <= ev[1] and ev[2] <= e for s, e in runs)] \
+                if runs else ops
+            # several programs of one name (an engine each, a token
+            # length each): one map of what they agree on
+            seconds, found, inherited = scopes.device_time_by_phase(
+                mine, scopes.merge_phases(p.phases() for p in progs))
+            total = sum(seconds.values())
+            if found < 0.05 or total <= 0.0:
+                continue        # this program did not run in the trace
+            lines.append(f"{program:<44} {total * 1e3:>12.3f} "
+                         f"{'found ' + format(found, '.1%'):>8}")
+            by_pass = defaultdict(float)
+            for phase, t in seconds.items():
+                by_pass[phase if isinstance(phase, str) else phase[0]] += t
+            for name in (*scopes.PASSES, scopes.UNATTRIBUTED):
+                if name not in by_pass:
+                    continue
+                lines.append(f"  {name:<42} {by_pass[name] * 1e3:>12.3f} "
+                             f"{by_pass[name] / total:>8.1%}")
+                blocks = sorted(((ph[1], t) for ph, t in seconds.items()
+                                 if not isinstance(ph, str)
+                                 and ph[0] == name), key=lambda kv: -kv[1])
+                lines += [f"    {blk:<40} {t * 1e3:>12.3f} "
+                          f"{t / total:>8.1%}" for blk, t in blocks]
+            lines.append(f"  {'of which phase inherited, not named':<42} "
+                         f"{inherited * total * 1e3:>12.3f} "
+                         f"{inherited:>8.1%}")
+        if len(lines) == 1:
+            return "ModelView: no registered program ran in the trace"
+        return "\n".join(lines)
 
     # throughput timer (reference: profiler/timer.py benchmark hooks)
     def step_info(self, unit="samples"):
@@ -260,10 +348,46 @@ class Profiler:
         return msg
 
 
+_CONTAINER = re.compile(r"(^|\s)(while|conditional|call)(\.\d+)?(\(|$)")
+
+
+def _device_events(xplane_path: str):
+    """(ops, modules) of the first device in a profiler trace, each a list
+    of (name, start_s, end_s). A TPU plane has an "XLA Ops" line, whose
+    events are named by their HLO instruction's text, and an "XLA Modules"
+    line that says which program ran when. The CPU backend has neither:
+    its thunks are host events named by the instruction alone, on XLA's
+    own threads, and `modules` is empty. Operations that only contain
+    others (`while`, `conditional`, `call`) are left out."""
+    data = jax.profiler.ProfileData.from_file(xplane_path)
+
+    def events(line):
+        return [(e.name, e.start_ns * 1e-9,
+                 (e.start_ns + e.duration_ns) * 1e-9) for e in line.events
+                if not _CONTAINER.search(e.name)]
+
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            by_name = {line.name: line for line in plane.lines}
+            if "XLA Ops" in by_name:
+                return (events(by_name["XLA Ops"]),
+                        events(by_name["XLA Modules"])
+                        if "XLA Modules" in by_name else [])
+    ops = []
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                if line.name.startswith("tf_XLA"):
+                    ops += [ev for ev in events(line)
+                            if " " not in ev[0] and ":" not in ev[0]]
+    return ops, []
+
+
 # fleet observability plane — imported last: tracing layers TraceContext
 # propagation on RecordEvent (above), aggregate ships registry snapshots
 # across processes, digest is the mergeable quantile sketch both use.
 from . import digest           # noqa: E402
+from . import scopes           # noqa: E402
 from . import tracing          # noqa: E402
 from . import aggregate        # noqa: E402
 # the SLO engine (ISSUE 16): timeline = the time dimension over the

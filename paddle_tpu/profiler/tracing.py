@@ -33,11 +33,11 @@ hot path never grows a hard filesystem dependency.
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import os
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Optional
 
@@ -45,7 +45,8 @@ from . import RecordEvent
 from . import metrics as _metrics
 
 __all__ = [
-    "TraceContext", "current", "use_context", "span", "record_span",
+    "TraceContext", "current", "use_context", "span", "phase",
+    "record_span",
     "child_of", "inject", "extract", "ring_spans", "clear_ring",
     "export_chrome", "FlightRecorder", "flight", "flight_note",
     "flight_dump", "set_flight_dir",
@@ -56,8 +57,25 @@ _m_dumps = _metrics.counter("trace/flight_dumps")
 _m_dump_errors = _metrics.counter("trace/flight_dump_errors")
 
 
+# A span costs no system call: a step writes five, and on a sandboxed
+# host (the TPU machine runs under one) each call into the kernel costs
+# tens of microseconds. An id is 32 random bits of this process, drawn
+# once, and a 32-bit count; the pid is read once. A forked child draws
+# its own.
+_ids = itertools.count(1)
+
+
+def _per_process():
+    global _id_prefix, _pid
+    _id_prefix, _pid = os.urandom(4).hex(), os.getpid()
+
+
+_per_process()
+os.register_at_fork(after_in_child=_per_process)
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{_id_prefix}{next(_ids) & 0xFFFFFFFF:08x}"
 
 
 class TraceContext:
@@ -138,11 +156,12 @@ _ring = deque(maxlen=max(64, _RING_CAP))
 _ring_lock = threading.Lock()
 
 
-def _push(span_dict: dict) -> None:
+def _push(span_dict: dict, mirror: bool = True) -> None:
     with _ring_lock:
         _ring.append(span_dict)
     _m_spans.inc()
-    flight.note("span", **span_dict)
+    if mirror:
+        flight.note("span", **span_dict)
 
 
 def ring_spans():
@@ -157,12 +176,15 @@ def clear_ring():
 
 
 def record_span(name: str, begin: float, end: float, ctx=None, parent=None,
-                args: Optional[dict] = None) -> TraceContext:
+                args: Optional[dict] = None, mirror: bool = True,
+                traced: bool = False) -> TraceContext:
     """Record a completed span directly (no context manager).
 
     `begin`/`end` are `time.perf_counter()` seconds. Identity: pass
     `ctx` to use it as-is, or `parent` (TraceContext/dict/None) to mint
     a child; with neither, the ambient context parents the span.
+    `mirror=False` keeps the span out of the flight recorder's ring;
+    `traced=True` marks a span that a running device trace holds too.
     Returns the span's context so callers can chain children off it.
     """
     if ctx is None:
@@ -172,18 +194,23 @@ def record_span(name: str, begin: float, end: float, ctx=None, parent=None,
     d = {"name": name, "ts": float(begin),
          "dur": max(0.0, float(end) - float(begin)),
          "trace_id": ctx.trace_id, "span_id": ctx.span_id,
-         "parent_id": ctx.parent_id, "pid": os.getpid()}
+         "parent_id": ctx.parent_id, "pid": _pid}
     if args:
         d["args"] = dict(args)
-    _push(d)
+    if traced:
+        d["traced"] = True
+    _push(d, mirror)
     return ctx
 
 
 class span:
     """Context manager: a traced span that nests via the contextvar and
-    also drives `RecordEvent` so active Profiler sessions see it."""
+    also drives `RecordEvent`, so active Profiler sessions see it and a
+    running device trace holds it as a `TraceAnnotation` of the same
+    name. `args` may be filled in while the span is open."""
 
     __slots__ = ("name", "args", "ctx", "_t0", "_token", "_rev")
+    _mirror = True      # completions are copied into the flight recorder
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -202,8 +229,20 @@ class span:
         self._rev.__exit__(*exc)
         _current.reset(self._token)
         record_span(self.name, self._t0, end, ctx=self.ctx,
-                    args=self.args or None)
+                    args=self.args or None, mirror=self._mirror,
+                    traced=self._rev.traced)
         return False
+
+
+class phase(span):
+    """A span of one phase inside a step (`serving::pack`,
+    `trainer::dispatch`): span ring and profiler like any other, but not
+    mirrored into the flight recorder, whose 512 entries several spans a
+    step would fill in seconds, pushing the request-lifecycle spans and
+    notes of the last minute out of the crash dump."""
+
+    __slots__ = ()
+    _mirror = False
 
 
 # -- cross-process propagation -------------------------------------------

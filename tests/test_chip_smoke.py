@@ -126,13 +126,13 @@ def test_check_placement_catches_everything_on_one_device():
 def test_kernel_calls_in_counts_by_family():
     text = "\n".join([
         'x = stablehlo.custom_call @tpu_custom_call(%0) {kernel_name = '
-        '"_fa_kernel"}',
+        '"flash_attention_fwd"}',
         'y = stablehlo.custom_call @tpu_custom_call(%1) {kernel_name = '
-        '"_fa_bwd_dq_kernel"}',
+        '"flash_attention_dq"}',
         'z = stablehlo.custom_call @tpu_custom_call(%2) {kernel_name = '
-        '"_rms_norm_kernel"}',
+        '"rms_norm_noweight"}',
         'w = stablehlo.custom_call @tpu_custom_call(%3) {kernel_name = '
-        '"_vfa_kernel"}'])
+        '"varlen_attention_fwd"}'])
     assert chip_smoke.kernel_calls_in(text) == {
         "flash_attention": 2, "varlen_attention": 1, "rms_norm": 1,
         "total": 4}

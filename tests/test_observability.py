@@ -267,7 +267,11 @@ def test_serving_ttft_tpot_and_gauges():
 
     out = engine.decode_run(2)             # device-fed decode window
     assert out
-    assert M.histogram("serving/tpot_ms").count == tpot0 + 1
+    # one meaning on every path: a request's own time per output token,
+    # observed when it finishes, and none has yet
+    assert M.histogram("serving/tpot_ms").count == tpot0
+    out += engine.decode_run(1)            # the last token of both
+    assert M.histogram("serving/tpot_ms").count == tpot0 + 2
     assert M.counter("serving/tokens_generated").value \
         == tok0 + len(produced) + len(out)
 

@@ -117,6 +117,65 @@ def test_varlen_attention_forward_and_backward_compile(one_chip):
     assert n == 3
 
 
+def _kernel_programs(one_chip):
+    """{kernel name: (function, abstract arguments)}: each of the eight
+    `pl.pallas_call` sites, reached as the program reaches it."""
+    q = jax.ShapeDtypeStruct((1, 4, 512, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1024, 512), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((512,), jnp.float32, sharding=one_chip)
+
+    def flash(q_, k_, v_, s_):
+        return jnp.sum(fa._flash_attention(q_, k_, v_, None, s_, True, 0.0)
+                       .astype(jnp.float32))
+
+    def varlen(q_, k_, v_, s_):
+        return jnp.sum(va._varlen_attention(q_, k_, v_, s_, s_, True)
+                       .astype(jnp.float32))
+
+    flash_grad = jax.grad(flash, argnums=(0, 1, 2))
+    varlen_grad = jax.grad(varlen, argnums=(0, 1, 2))
+    return {
+        "flash_attention_fwd": (flash, (q, q, q, seed)),
+        "flash_attention_dkv": (flash_grad, (q, q, q, seed)),
+        "flash_attention_dq": (flash_grad, (q, q, q, seed)),
+        "varlen_attention_fwd": (varlen, (q, q, q, seg)),
+        "varlen_attention_dkv": (varlen_grad, (q, q, q, seg)),
+        "varlen_attention_dq": (varlen_grad, (q, q, q, seg)),
+        "rms_norm": (lambda a, b: rn.rms_norm(a, b, 1e-5), (x, w)),
+        "rms_norm_noweight": (lambda a: rn.rms_norm(a, None, 1e-5), (x,)),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_attention_fwd", "flash_attention_dkv", "flash_attention_dq",
+    "varlen_attention_fwd", "varlen_attention_dkv", "varlen_attention_dq",
+    "rms_norm", "rms_norm_noweight"])
+def test_kernel_names_reach_the_chip_program(one_chip, monkeypatch, kernel):
+    """Every `pl.pallas_call` names its kernel: the lowered program's
+    `kernel_name`, and in the COMPILED program both the custom call's
+    instruction name (what a device trace's event starts with) and its
+    `kernel_metadata`, which was `{}` before the kernels had names."""
+    import re
+
+    monkeypatch.setenv("PT_USE_PALLAS", "1")
+    fn, args = _kernel_programs(one_chip)[kernel]
+    lowered = jax.jit(fn).lower(*args)
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    compiled = lowered.compile().as_text()
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'kernel_metadata=\{\s*([^}]*)\}', compiled)
+    assert calls and all(meta.strip() for _, meta in calls), calls
+    # the instruction is named after the kernel, inside whatever JAX's
+    # wrappers add (`transpose_jvp_flash_attention_dq__.1` in a backward)
+    assert any(kernel in name
+               and f'"kernel":"{kernel}"' in meta.replace(" ", "")
+               for name, meta in calls), calls
+
+
 def abstract_trainer(config, mesh, **kwargs):
     """A HybridTrainer whose parameters and optimizer state are shapes
     with shardings on `mesh` — described devices hold no arrays, so the

@@ -89,6 +89,10 @@ KNOWN_METRICS = (
     "serving/preemptions", "serving/batch_occupancy",
     "serving/kv_cache_utilization", "serving/deadline_evictions",
     "serving/load_shed",
+    # what each engine step held, bumped once a step: scheduled rows,
+    # real tokens, padding up to the static token length, prompt tokens
+    "serving/step_rows", "serving/step_tokens",
+    "serving/step_pad_tokens", "serving/step_prefill_tokens",
     # fleet serving tier: shared-prefix KV reuse (inference/
     # prefix_cache.py), multi-replica routing (inference/router.py),
     # disaggregated prefill/decode hand-offs (inference/disagg.py)
