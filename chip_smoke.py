@@ -156,15 +156,29 @@ def assert_on(tree, devices) -> None:
                 f"{sorted(map(str, want))}")
 
 
+KERNEL_FAMILIES = ("flash_attention", "varlen_attention", "rms_norm",
+                   "paged_attention")
+
+
 def kernel_calls_in(text: str) -> Dict[str, int]:
-    """tpu_custom_calls in a lowered program's text, by kernel family.
-    Kernel names are the `name=` of each `pl.pallas_call` (ops/pallas/*):
-    `flash_attention_fwd`, `varlen_attention_dq`, `rms_norm_noweight` ..."""
+    """tpu_custom_calls in a program's text, by kernel family. Kernel names
+    are the `name=` of each `pl.pallas_call` (ops/pallas/*):
+    `flash_attention_fwd`, `varlen_attention_dq`, `rms_norm_noweight` ...
+    In a LOWERED program's text they are the calls' `kernel_name`; a kernel
+    inside a jitted function that the program calls sixteen times stands
+    there once. In a COMPILED program's text (`HloModule ...`), where every
+    call is inlined, they are in the custom calls' instruction names."""
     import re
 
+    if text.startswith("HloModule"):
+        names = re.findall(
+            r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+            text)
+        out = {k: sum(k in n for n in names) for k in KERNEL_FAMILIES}
+        out["total"] = len(names)
+        return out
     names = re.findall(r'kernel_name = "([^"]+)"', text)
-    out = {k: sum(n.startswith(k) for n in names)
-           for k in ("flash_attention", "varlen_attention", "rms_norm")}
+    out = {k: sum(n.startswith(k) for n in names) for k in KERNEL_FAMILIES}
     out["total"] = text.count("tpu_custom_call")
     return out
 
@@ -434,6 +448,48 @@ def dense_logits(model, seq, pad_to: int):
     return np.asarray(logits.numpy()[0, :len(seq)], np.float32)
 
 
+def abstract_step_args(engine, scfg, abstract=None, tokens=None):
+    """The arguments of the engine's step programs (`serving_step`,
+    `serving_fresh_prefill`, `serving_spec_verify`) as shapes, to lower
+    them with: `(params, buffers, tokens, enc, dec, this, cu, block
+    tables, key stack, value stack)`. `tokens`: the step's token length,
+    the token budget by default. `abstract` maps an array to the
+    `ShapeDtypeStruct` to lower with (tools/tpu_compile_smoke.py and
+    tests/test_tpu_compile.py hand the described chip's sharding); by
+    default the arrays' own."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.profiler import scopes
+
+    abstract = abstract or scopes.abstract
+    b1 = scfg.max_batch + 1
+
+    def i32(*shape):
+        return abstract(jnp.zeros(shape, jnp.int32))
+
+    return (jax.tree.map(abstract, engine._params),
+            jax.tree.map(abstract, engine._buffers),
+            i32(tokens or scfg.token_budget), i32(b1), i32(b1), i32(b1),
+            i32(b1 + 1), i32(b1, scfg.max_blocks_per_seq),
+            abstract(engine._kc), abstract(engine._vc))
+
+
+def abstract_window_args(engine, scfg, rows, n, abstract=None):
+    """The arguments of `engine._decode_window_fn(rows, n, "greedy")` as
+    shapes: the step's, with `rows` tokens, then no scales, the samplers'
+    three rows and the `[n, B + 1]` salts."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.profiler import scopes
+
+    abstract = abstract or scopes.abstract
+    b1 = scfg.max_batch + 1
+    f32, i32 = (abstract(jnp.zeros(b1, d)) for d in (jnp.float32, jnp.int32))
+    return (*abstract_step_args(engine, scfg, abstract, rows), (), f32, i32,
+            f32, abstract(jnp.zeros((n, b1), jnp.int32)))
+
+
 def phase_serve(cfg: SmokeConfig, devices) -> Dict[str, Any]:
     """ServingEngine.from_model over a PagedCausalLM: requests of unequal
     prompt length, the last cfg.n_late admitted while the others decode;
@@ -504,6 +560,14 @@ def phase_serve(cfg: SmokeConfig, devices) -> Dict[str, Any]:
         raise AssertionError("KV pages leaked")
     engine_refs = dispatches_since(ref0)
     check_no_reference_dispatch(cfg, engine_refs, "the engine's path")
+    # the mixed step as the engine ran it, compiled again from its shapes
+    # (a hit in the compilation cache): one paged-attention kernel a layer
+    # when the configuration expects kernels, none otherwise
+    calls = kernel_calls_in(engine._compiled.lower(
+        *abstract_step_args(engine, scfg)).compile().as_text())
+    if calls["paged_attention"] != (scfg.num_layers if cfg.kernels else 0):
+        raise AssertionError(
+            f"paged_attention calls in the engine's mixed step: {calls}")
     ref1 = reference_dispatches()
 
     # -- the reference: forward_dense, teacher-forced -------------------
@@ -549,6 +613,7 @@ def phase_serve(cfg: SmokeConfig, devices) -> Dict[str, Any]:
             "logit_tolerance": logit_tol, "logit_std": scale,
             "serve_s": round(serve_s, 2), "reference_s": round(ref_s, 2),
             **device_memory(devices[0]),
+            "kernel_calls": calls,
             "reference_dispatches": sum(engine_refs.values()),
             # forward_dense pads to max_seq rows, which rms_norm's 256-row
             # blocks need not divide: the REFERENCE may run jnp rms_norm

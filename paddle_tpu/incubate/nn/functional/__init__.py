@@ -564,7 +564,8 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                               quant_round_type=1, quant_max_bound=127.0,
                               quant_min_bound=-127.0, out_scale=-1,
                               compute_dtype="default", layer_idx=None,
-                              fresh_prefill=False):
+                              fresh_prefill=False, key_cache_in=None,
+                              value_cache_in=None):
     """Paged-KV-cache attention (reference block_multihead_attention):
     qkv [token_num, (HQ+2*HKV)*D] packs each batch row's tokens this step
     (prefill rows contribute seq_lens_encoder[b] tokens at positions
@@ -576,6 +577,27 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     are scattered into their pages, then each token attends its row's
     filled prefix (causal). Returns
     (out [token_num, HQ*D], qkv, key_cache, value_cache).
+
+    The attention itself is ops/pallas/paged_attention.py: on a TPU (or
+    under PT_PALLAS_INTERPRET=1) a Pallas kernel that walks each row's
+    own pages in place, for a bf16/float32 cache whose head_dim is a
+    multiple of 128 and whose block_size tiles the dtype's sublanes;
+    elsewhere — kernels off, an int8 cache, shapes that do not tile — the
+    gathered jnp formulation, counted as
+    pallas/reference_dispatch/paged_attention when kernels are on. A
+    program traced off the chip (save_paged_model's export) holds the
+    gathered formulation.
+
+    key_cache_in / value_cache_in (optional, stacked mode): the stacks as
+    they ENTERED the step, for a caller that threads key_cache /
+    value_cache through its layers. The kernel reads a layer's cached
+    pages before this call's write and takes this step's keys from qkv,
+    so it can read them there: layer `layer_idx` of the two must equal
+    layer `layer_idx` of key_cache / value_cache (no earlier call wrote
+    it). Without them it reads key_cache itself, which is as correct, but
+    a threaded stack reaches each layer in the layout XLA gives its
+    scatter, and every layer's kernel then waits for a copy of the whole
+    stack. The writes always go to key_cache / value_cache.
 
     Int8 KV cache (use_dynamic_cachekv_quant=True): caches are int8 page
     pools and cache_k_quant_scales / cache_v_quant_scales are PER-SLOT
@@ -624,6 +646,8 @@ def block_multihead_attention(qkv, key_cache, value_cache,
         vs_in = next(it) if quant else None
         b = next(it) if qkv_bias is not None else None
         rope = next(it) if rope_emb is not None else None
+        kc_read, vc_read = (next(it), next(it)) \
+            if key_cache_in is not None else (kc_in, vc_in)
         T = qkva.shape[0]
         # stacked-cache mode (layer_idx given): caches are
         # [L, num_blocks, H, bs, D] and every access uses a COMPOSITE
@@ -641,8 +665,7 @@ def block_multihead_attention(qkv, key_cache, value_cache,
             kc, vc = kc_in, vc_in
             ks, vs = ks_in, vs_in
             num_blocks, HKV, bs, D = kc.shape[1:]
-        B, max_blocks = bt.shape
-        max_seq = max_blocks * bs
+        B = bt.shape[0]
         if b is not None:
             qkva = qkva + b.reshape(1, -1)
         HQ = qkva.shape[1] // D - 2 * HKV                    # GQA: HQ >= HKV
@@ -730,53 +753,31 @@ def block_multihead_attention(qkv, key_cache, value_cache,
             if quant:
                 return out.reshape(T, HQ * D), qkva, kc, vc, ks, vs
             return out.reshape(T, HQ * D), qkva, kc, vc
-        # dense view of each row's cache — gather WHOLE pages ([B, MB]
-        # indices, 64 KB contiguous slices) instead of per-(row, pos)
-        # strided element slices: the [B, S] advanced-index gather
-        # lowered to a scalar-slice gather that dominated the decode and
-        # chunked-prefill steps on TPU
-        with scope("kv_gather"):
-            kp = kc[li + (bt,)]                      # [B, MB, HKV, bs, D]
-            vp = vc[li + (bt,)]
-            kd = kp.transpose(0, 2, 1, 3, 4).reshape(
-                B, HKV, max_seq, D)                  # [B, HKV, S, D]
-            vd = vp.transpose(0, 2, 1, 3, 4).reshape(B, HKV, max_seq, D)
-            if quant:
-                # dequant the gathered view: int8 pages * per-slot scales
-                # (cache HBM traffic already halved at this point)
-                ksd = ks[li + (bt,)].transpose(0, 2, 1, 3).reshape(
-                    B, HKV, max_seq)[..., None]      # [B, HKV, S, 1]
-                vsd = vs[li + (bt,)].transpose(0, 2, 1, 3).reshape(
-                    B, HKV, max_seq)[..., None]
-                kd = (kd.astype(jnp.float32) * ksd).astype(qkva.dtype)
-                vd = (vd.astype(jnp.float32) * vsd).astype(qkva.dtype)
-            kt = kd[t2b]                             # each token's row
-        G = HQ // HKV
-        qg = q.reshape(T, HKV, G, D)
-        # MXU dots take the low-precision operands directly with f32
-        # ACCUMULATION (preferred_element_type) — operand .astype(f32)
-        # casts materialized an f32 copy of every gathered KV view
-        # (~1.6 GB/step at flagship decode dims)
-        logits = jnp.einsum("tkgd,tksd->tkgs", qg, kt,
-                            preferred_element_type=jnp.float32) \
-            / jnp.sqrt(jnp.float32(D))
-        valid = jnp.arange(max_seq)[None, :] <= pos[:, None]   # [T, S]
-        logits = jnp.where(valid[:, None, None, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        with scope("kv_gather"):
-            vt = vd[t2b]
-        out = jnp.einsum("tkgs,tksd->tkgd", probs.astype(qkva.dtype), vt,
-                         preferred_element_type=jnp.float32) \
-            .astype(qkva.dtype)
+        # each row over its own pages (ops/pallas/paged_attention.py):
+        # the Pallas kernel where it applies, over the caches as they were
+        # before this call's write plus this step's own k/v; the gathered
+        # reference elsewhere, over the written caches
+        from ....ops.pallas import paged_attention as _pa
+
+        if _pa.use_kernel(q, kc_read, quant):
+            out = _pa.paged_attention(q, k, v, kc_read, vc_read, bt, start,
+                                      cu_q, layer_idx=layer_idx)
+        else:
+            out = _pa.paged_attention_ref(q, kc, vc, bt, start, cu_q,
+                                          layer_idx=layer_idx, k_scales=ks,
+                                          v_scales=vs)
+        out = out.reshape(T, HQ * D)
         if quant:
-            return out.reshape(T, HQ * D), qkva, kc, vc, ks, vs
-        return out.reshape(T, HQ * D), qkva, kc, vc
+            return out, qkva, kc, vc, ks, vs
+        return out, qkva, kc, vc
 
     args = [qkv, key_cache, value_cache, seq_lens_encoder,
             seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
             block_tables] \
         + ([cache_k_quant_scales, cache_v_quant_scales] if quant else []) \
-        + [t for t in (qkv_bias, rope_emb) if t is not None]
+        + [t for t in (qkv_bias, rope_emb) if t is not None] \
+        + ([key_cache_in, value_cache_in] if key_cache_in is not None
+           else [])
     return apply(fn, *args, op_name="block_multihead_attention")
 
 

@@ -57,9 +57,10 @@ class _EngineMetrics:
     the global one) instead of conflating co-hosted replicas in the
     process-wide series — see ServingEngine.set_metrics_namespace."""
 
-    __slots__ = ("ttft", "tpot", "steps", "tokens", "requests",
-                 "step_rows", "step_tokens", "step_pad", "step_prefill",
-                 "preempt", "occupancy", "kv_util", "deadline", "shed",
+    __slots__ = ("ttft", "tpot", "steps", "paged_steps", "tokens",
+                 "requests", "step_rows", "step_tokens", "step_pad",
+                 "step_prefill", "preempt", "occupancy", "kv_util",
+                 "deadline", "shed",
                  "prefix_rate", "prefix_pages", "spec_steps",
                  "spec_drafted", "spec_accepted", "spec_accept_rate",
                  "spec_tokens_per_step", "fused_regions",
@@ -69,6 +70,10 @@ class _EngineMetrics:
         self.ttft = reg.histogram("serving/ttft_ms")
         self.tpot = reg.histogram("serving/tpot_ms")
         self.steps = reg.counter("serving/steps")
+        # of them, the steps whose program holds the paged-attention
+        # kernel (ops/pallas/paged_attention.py); 0 on an engine that
+        # traces off the chip or loads an exported artifact
+        self.paged_steps = reg.counter("serving/paged_kernel_steps")
         # what each step held (bumped once a step): scheduled rows, real
         # tokens, the padding up to the step's static token length, and
         # the tokens of rows still inside their prompt
@@ -147,7 +152,12 @@ class PagedServingConfig:
     Weight streaming (~2.3 ms floor), not cache reads, bounds this
     engine's decode, so halving cache bytes buys no step time back.
     Pick int8 when KV capacity is the binding constraint (long contexts,
-    big batches); stay bf16 when step latency is.
+    big batches); stay bf16 when step latency is. On a TPU the mixed,
+    verify and decode-window steps of a bf16/float32 cache attend through
+    the paged-attention Pallas kernel (ops/pallas/paged_attention.py);
+    an int8 cache takes the gathered jnp reference instead (the kernel
+    reads floating pages), counted as
+    `pallas/reference_dispatch/paged_attention`.
     """
 
     def __init__(self, vocab_size=256, hidden_size=64, num_layers=2,
@@ -345,6 +355,12 @@ def sample_logits(logits, sampling: SamplingParams, salt: int) -> int:
     return int(np.asarray(out)[0])
 
 
+# PagedCausalLM._step_mode -> the name of the step program traced under it
+_STEP_PROGRAMS = {None: "serving_step",
+                  "fresh_prefill": "serving_fresh_prefill",
+                  "spec_verify": "serving_spec_verify"}
+
+
 class PagedCausalLM(Layer):
     """A llama-architecture causal LM (RMSNorm → GQA attention → swiglu
     MLP, untied LM head, no biases — models/llama.py at serving time)
@@ -487,7 +503,11 @@ class PagedCausalLM(Layer):
                     rope_emb=rope, layer_idx=li,
                     max_seq_len=cfg.max_seq, block_size=cfg.block_size,
                     fresh_prefill=getattr(self, "_step_mode", None)
-                    == "fresh_prefill")
+                    == "fresh_prefill",
+                    # the paged kernel reads a layer's cached pages from
+                    # the stacks as they entered the step (the threaded
+                    # ones reach it re-laid by the layers' scatters)
+                    key_cache_in=key_caches, value_cache_in=value_caches)
                 if quant:
                     out, _, new_kc, new_vc, new_ks, new_vs = outs
                 else:
@@ -673,6 +693,9 @@ class ServingEngine:
         else:
             self._fixed_token_len = None
         self._compiled_fresh = None   # set by from_model (jit engines)
+        # {step program: its trace took the paged-attention kernel},
+        # written when from_model's programs are traced
+        self._kernel_programs = {}
         self._compiled_verify = None  # all-positions logits (from_model)
         # the from_model weight_stream mode this engine's flat params
         # were built under — a weight publisher must replicate the SAME
@@ -808,7 +831,8 @@ class ServingEngine:
         cached = getattr(model, "_serving_shared", None)
         if cached is not None and cached[0] == share_key:
             (_, eng._compiled, eng._compiled_fresh,
-             eng._compiled_verify, eng._params, eng._buffers) = cached
+             eng._compiled_verify, eng._params, eng._buffers,
+             eng._kernel_programs) = cached
             return eng
         params = FB.current_params(model)
         buffers = FB.current_buffers(model)
@@ -832,18 +856,28 @@ class ServingEngine:
             flat_p = flat_p + streamer.flat()
         flat_b, tree_b = jax.tree_util.tree_flatten(buffers)
 
+        kernel_programs = eng._kernel_programs
+
         def pure(fp, fb, *ins):
+            from ..ops.pallas.paged_attention import traced_kernel_calls
+
             ps = jax.tree_util.tree_unflatten(tree_p, fp[:n_base])
             bs = jax.tree_util.tree_unflatten(tree_b, fb)
             if streamer is not None:
                 object.__setattr__(model, "_wstream_live",
                                    streamer.bind(fp[n_base:]))
+            calls = traced_kernel_calls()
             try:
                 out, _ = FB.call_functional(model, ps, bs, ins,
                                             train=False)
             finally:
                 if streamer is not None:
                     object.__setattr__(model, "_wstream_live", None)
+            # this runs when a step program is traced: whether that trace
+            # took the paged-attention kernel (_count_step reads it)
+            kernel_programs[_STEP_PROGRAMS[
+                getattr(model, "_step_mode", None)]] = \
+                traced_kernel_calls() > calls
             return tuple(out)
 
         def pure_fresh(fp, fb, *ins):
@@ -877,7 +911,8 @@ class ServingEngine:
         object.__setattr__(model, "_serving_shared",
                            (share_key, eng._compiled,
                             eng._compiled_fresh, eng._compiled_verify,
-                            eng._params, eng._buffers))
+                            eng._params, eng._buffers,
+                            eng._kernel_programs))
         return eng
 
     # -- scheduling ------------------------------------------------------
@@ -1472,10 +1507,15 @@ class ServingEngine:
         with _tracing.span("serving::step") as sp:
             return self._step(sp.args)
 
-    def _count_step(self, note, rows, tokens, pad, prefill_tokens):
+    def _count_step(self, note, program, rows, tokens, pad,
+                    prefill_tokens):
         """What one step held: the `serving/step_*` counters, each bumped
-        once a step, and the same numbers as the step span's args."""
+        once a step, and the same numbers as the step span's args; and
+        whether `program`, which it ran, holds the paged-attention kernel
+        (known once the program has been traced: call this after it)."""
         m = self._m
+        if self._kernel_programs.get(program):
+            m.paged_steps.inc()
         m.step_rows.inc(rows)
         m.step_tokens.inc(tokens)
         m.step_pad.inc(pad)
@@ -1586,8 +1626,6 @@ class ServingEngine:
             tokens = np.asarray(packed + [0] * n_pad, np.int32)
             cu = np.zeros(B1 + 1, np.int32)
             cu[1:] = np.cumsum(this)
-            self._count_step(note, len(rows), len(packed), n_pad,
-                             prefill_tokens)
 
             # fresh-prefill steps (every scheduled row starts at cache pos
             # 0) run the varlen-flash specialization: block-diagonal
@@ -1603,10 +1641,11 @@ class ServingEngine:
             fp = self._params_for(rows[0][0].weight_version)
             args = (fp, self._buffers, tokens, enc, dec, this, cu, bt,
                     self._kc, self._vc, *extra)
-            self._register_program(
-                "serving_fresh_prefill" if fresh else "serving_step",
-                compiled, args)
+            program = "serving_fresh_prefill" if fresh else "serving_step"
+            self._register_program(program, compiled, args)
             out = compiled(*args)
+            self._count_step(note, program, len(rows), len(packed), n_pad,
+                             prefill_tokens)
             logits = out[0]
             self._set_caches(out[1], out[2])
             if self._ks is not None:
@@ -1776,7 +1815,6 @@ class ServingEngine:
             tokens = np.asarray(packed + [0] * n_pad, np.int32)
             cu = np.zeros(B1 + 1, np.int32)
             cu[1:] = np.cumsum(this)
-            self._count_step(note, len(plans), len(packed), n_pad, 0)
 
             extra = (self._ks, self._vs) if self._ks is not None else ()
             args = (self._params_for(plans[0][0].weight_version),
@@ -1785,6 +1823,8 @@ class ServingEngine:
             self._register_program("serving_spec_verify",
                                    self._compiled_verify, args, tok_len)
             out = self._compiled_verify(*args)
+            self._count_step(note, "serving_spec_verify", len(plans),
+                             len(packed), n_pad, 0)
             logits = out[0]                                # [tok_len, V]
             self._set_caches(out[1], out[2])
             if self._ks is not None:
@@ -2047,6 +2087,8 @@ class ServingEngine:
             self._params_for(rows[0].weight_version), self._buffers,
             tokens, enc, dec, this, cu, bt,
             self._kc, self._vc, scales, temps, topks, topps, salts)
+        if self._kernel_programs.get("serving_step"):
+            self._m.paged_steps.inc(n)      # the window scans that step
         self._kc, self._vc = kc, vc
         if self._ks is not None:
             self._ks, self._vs = scales
@@ -2080,7 +2122,10 @@ class ServingEngine:
 
 def save_paged_model(path_prefix: str, model: PagedCausalLM):
     """Export the paged step function as a serving artifact with the
-    engine's static shapes."""
+    engine's static shapes. Traced where it is called: off the chip (the
+    usual place) the artifact holds the gathered reference attention,
+    not the paged-attention kernel, and its engine counts no
+    `serving/paged_kernel_steps`."""
     from . import PrecisionType, save_inference_model
     from ..jit.api import InputSpec
 

@@ -17,7 +17,7 @@ engines and, in production, many processes — so spans here carry a
     prefill → migrate → decode.
 
 All finished spans land in an always-on bounded ring (no Profiler
-session required; capacity `PT_TRACE_RING`, default 4096) and export to
+session required; capacity `PT_TRACE_RING`, default 32768) and export to
 chrome-trace JSON with the ids in `args`, so `tools/trace_report.py`
 can merge multi-host traces onto one timeline and a migrated request's
 pre- and post-migration spans join under one trace id.
@@ -151,7 +151,10 @@ class use_context:
 
 # -- span ring ------------------------------------------------------------
 
-_RING_CAP = int(os.environ.get("PT_TRACE_RING", "4096") or 4096)
+# room for a minute of a serving engine's steps with their four phase
+# children (a 27 ms step writes 180 spans a second; the benchmark's span
+# readers look for the traced seconds' spans when the run has ended)
+_RING_CAP = int(os.environ.get("PT_TRACE_RING", "32768") or 32768)
 _ring = deque(maxlen=max(64, _RING_CAP))
 _ring_lock = threading.Lock()
 

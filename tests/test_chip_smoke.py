@@ -81,6 +81,23 @@ def test_phase_serve_on_cpu():
     json.dumps(out)
 
 
+def test_phase_serve_demands_the_paged_kernel_when_configured(monkeypatch):
+    """The serve phase as the chip runs it, kernels expected: widths whose
+    heads and pages tile (head_dim 128, 32-token pages), kernels in
+    interpret mode. Nothing on the engine's path gives way to a reference
+    (that check comes first and passes), but an interpreted kernel is no
+    `tpu_custom_call` in the lowered step: a failed phase, not a quiet
+    pass, as for the train step."""
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    serving = dict(debug_config().serving, hidden_size=256, block_size=32,
+                   num_blocks=5 * 2 + 1, max_blocks_per_seq=2,
+                   token_budget=128)
+    with pytest.raises(AssertionError,
+                       match="paged_attention calls in the engine's mixed"):
+        chip_smoke.phase_serve(
+            debug_config(serving=serving, kernels=True), jax.devices()[:1])
+
+
 def test_phase_eager_on_cpu():
     out = chip_smoke.phase_eager(debug_config(), jax.devices()[:1])
     assert out["device"] == "cpu"
@@ -132,10 +149,21 @@ def test_kernel_calls_in_counts_by_family():
         'z = stablehlo.custom_call @tpu_custom_call(%2) {kernel_name = '
         '"rms_norm_noweight"}',
         'w = stablehlo.custom_call @tpu_custom_call(%3) {kernel_name = '
-        '"varlen_attention_fwd"}'])
+        '"varlen_attention_fwd"}',
+        'p = stablehlo.custom_call @tpu_custom_call(%4) {kernel_name = '
+        '"paged_attention"}'])
     assert chip_smoke.kernel_calls_in(text) == {
         "flash_attention": 2, "varlen_attention": 1, "rms_norm": 1,
-        "total": 4}
+        "paged_attention": 1, "total": 5}
+    # a compiled program's text: the custom calls' instruction names
+    compiled = "HloModule jit_serving_step\n" + "\n".join(
+        f'  %{name} = bf16[8,1024,128]{{2,1,0}} custom-call(%p), '
+        f'custom_call_target="tpu_custom_call", kernel_metadata={{}}'
+        for name in ("paged_attention.2", "paged_attention.3",
+                     "rms_norm.7", "closed_call_varlen_attention_fwd.1"))
+    assert chip_smoke.kernel_calls_in(compiled) == {
+        "flash_attention": 0, "varlen_attention": 1, "rms_norm": 1,
+        "paged_attention": 2, "total": 4}
 
 
 # ---------------------------------------------------------------------------

@@ -132,10 +132,22 @@ def test_scopes_leave_the_optimised_program_alone(compiled_text,
                                                   monkeypatch):
     """Scopes are metadata: with `scope` a null context the compiled step
     has the same instructions, opcode by opcode."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     null = lambda name: contextlib.nullcontext()            # noqa: E731
     monkeypatch.setattr(scopes, "scope", null)
     monkeypatch.setattr(llama, "scope", null)
-    bare = _trainer().lower(BATCH).compile().as_text()
+    # the persistent cache's key leaves metadata out: where an earlier test
+    # of this worker switched it on (bench.main does), the scoped program's
+    # entry would answer for the bare one, names and all
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        bare = _trainer().lower(BATCH).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
     assert scopes.PREFIX + "attention" not in bare
     assert _opcodes(bare) == _opcodes(compiled_text)
     assert sum(_opcodes(bare).values()) > 100

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas import rms_norm as rn
 from paddle_tpu.ops.pallas import varlen_attention as va
 
@@ -117,8 +118,117 @@ def test_varlen_attention_forward_and_backward_compile(one_chip):
     assert n == 3
 
 
+@pytest.mark.parametrize("name,t,hq,hkv,d,bs,rows,max_blocks,dtype", [
+    # Mistral-7B serve cell: GQA 32/8, 32-token pages, max_seq 1280
+    ("mistral7b_mixed_step", 256, 32, 8, 128, 32, 33, 40, jnp.bfloat16),
+    # chip_smoke's engine: llama2-7b MHA, token budget 512
+    ("llama2_7b_mha", 512, 32, 32, 128, 32, 9, 18, jnp.bfloat16),
+    # a decode window's step: as many tokens as rows
+    ("decode_window_rows4", 4, 32, 8, 128, 32, 33, 40, jnp.bfloat16),
+    ("float32_block16_head256", 128, 8, 2, 256, 16, 5, 8, jnp.float32),
+])
+def test_paged_attention_compiles(one_chip, name, t, hq, hkv, d, bs, rows,
+                                  max_blocks, dtype):
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache = s((2, 1 + rows * max_blocks // 2, hkv, bs, d), dtype)
+    n, _ = _custom_calls(jax.jit(
+        lambda *a: pa.paged_attention(*a, layer_idx=1)).lower(
+            s((t, hq, d), dtype), s((t, hkv, d), dtype),
+            s((t, hkv, d), dtype), cache, cache, s((rows, max_blocks)),
+            s((rows,)), s((rows + 1,))))
+    assert n == 1
+
+
+PAGED_ENGINE = dict(vocab_size=512, hidden_size=512, num_layers=2,
+                    num_heads=4, num_kv_heads=2, ffn_size=1024,
+                    block_size=32, num_blocks=65, max_batch=8,
+                    max_blocks_per_seq=8, token_budget=128,
+                    dtype="bfloat16")
+
+
+def _engine_programs(one_chip, kernels, monkeypatch):
+    """{program: lowered} of a small bf16 engine whose heads and pages
+    tile (head_dim 128, 32-token pages): the mixed step, a verify step and
+    a decode window, lowered for the described chip. A model each time:
+    its step programs are traced once, with or without the kernels."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import (PagedCausalLM,
+                                              PagedServingConfig,
+                                              ServingEngine)
+
+    import chip_smoke
+
+    monkeypatch.setenv("PT_USE_PALLAS", "1" if kernels else "0")
+    scfg = PagedServingConfig(**PAGED_ENGINE)
+    paddle.seed(0)
+    model = PagedCausalLM(scfg)
+    model.eval()
+    eng = ServingEngine.from_model(model, scfg, seed=0)
+
+    def shp(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    return scfg, {
+        "mixed_step": eng._compiled.lower(
+            *chip_smoke.abstract_step_args(eng, scfg, shp)),
+        "verify_step": eng._compiled_verify.lower(
+            *chip_smoke.abstract_step_args(eng, scfg, shp, tokens=16)),
+        "decode_window": eng._decode_window_fn(4, 8, "greedy").lower(
+            *chip_smoke.abstract_window_args(eng, scfg, 4, 8, shp)),
+    }
+
+
+def test_engine_steps_hold_the_paged_kernel_and_no_gathered_view(
+        one_chip, monkeypatch, capsys):
+    """The mixed step, the verify step and the decode window each hold one
+    `paged_attention` custom call a layer, and no buffer of the gathered
+    formulation (`[T, HKV, max_seq, D]`, a token's copy of its row, and
+    `[B, HKV, max_seq, D]`, the dense view) is left in the compiled text;
+    the same programs with kernels off hold both, so the search bites.
+    Prints `memory_analysis()` of each, before (reference) and after."""
+    import re
+
+    import chip_smoke
+
+    def views(scfg, text, t):
+        hkv, d = scfg.num_kv_heads, scfg.head_dim
+        return [len(re.findall(rf"bf16\[{n},{hkv},{scfg.max_seq},{d}\]", text))
+                for n in (t, scfg.max_batch + 1)]
+
+    tokens = {"mixed_step": PAGED_ENGINE["token_budget"],
+              "verify_step": 16, "decode_window": 4}
+    report = []
+    for kernels in (False, True):
+        scfg, programs = _engine_programs(one_chip, kernels, monkeypatch)
+        for name, lowered in programs.items():
+            compiled = lowered.compile()
+            # the layers share one lowering of the kernel: count where
+            # every call stands, in the compiled text
+            calls = chip_smoke.kernel_calls_in(compiled.as_text())
+            per_token, dense = views(scfg, compiled.as_text(), tokens[name])
+            m = compiled.memory_analysis()
+            report.append((name, kernels, calls["paged_attention"],
+                           m.temp_size_in_bytes,
+                           chip_smoke.predicted_bytes(compiled)))
+            if kernels:
+                assert calls["paged_attention"] == scfg.num_layers, name
+                assert per_token == 0 and dense == 0, name
+            else:
+                assert calls["total"] == 0, name
+                assert per_token > 0 and dense > 0, name
+    with capsys.disabled():
+        for name, kernels, n, temp, total in report:
+            print(f"\n{name} kernels={kernels}: paged_attention x{n}, "
+                  f"temporaries {temp} B, on the device {total} B", end="")
+    before = {name: temp for name, k, _, temp, _ in report if not k}
+    after = {name: temp for name, k, _, temp, _ in report if k}
+    assert after["mixed_step"] < before["mixed_step"]
+
+
 def _kernel_programs(one_chip):
-    """{kernel name: (function, abstract arguments)}: each of the eight
+    """{kernel name: (function, abstract arguments)}: each of the nine
     `pl.pallas_call` sites, reached as the program reaches it."""
     q = jax.ShapeDtypeStruct((1, 4, 512, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -126,6 +236,9 @@ def _kernel_programs(one_chip):
     seg = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
     x = jax.ShapeDtypeStruct((1024, 512), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((512,), jnp.float32, sharding=one_chip)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def flash(q_, k_, v_, s_):
         return jnp.sum(fa._flash_attention(q_, k_, v_, None, s_, True, 0.0)
@@ -146,13 +259,19 @@ def _kernel_programs(one_chip):
         "varlen_attention_dq": (varlen_grad, (q, q, q, seg)),
         "rms_norm": (lambda a, b: rn.rms_norm(a, b, 1e-5), (x, w)),
         "rms_norm_noweight": (lambda a: rn.rms_norm(a, None, 1e-5), (x,)),
+        "paged_attention": (
+            lambda *a: pa.paged_attention(*a, layer_idx=0),
+            (spec((64, 4, 128)), spec((64, 2, 128)), spec((64, 2, 128)),
+             spec((1, 9, 2, 32, 128)), spec((1, 9, 2, 32, 128)),
+             spec((3, 4), jnp.int32), spec((3,), jnp.int32),
+             spec((4,), jnp.int32))),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "flash_attention_fwd", "flash_attention_dkv", "flash_attention_dq",
     "varlen_attention_fwd", "varlen_attention_dkv", "varlen_attention_dq",
-    "rms_norm", "rms_norm_noweight"])
+    "rms_norm", "rms_norm_noweight", "paged_attention"])
 def test_kernel_names_reach_the_chip_program(one_chip, monkeypatch, kernel):
     """Every `pl.pallas_call` names its kernel: the lowered program's
     `kernel_name`, and in the COMPILED program both the custom call's

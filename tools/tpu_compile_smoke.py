@@ -4,7 +4,8 @@ The TPU compiler is installed in the sandbox and compiles for a chip that
 is described, not attached (`jax.experimental.topologies`). This script
 hands the trainer and the engine the described devices and abstract
 shapes, compiles the whole train step (one chip, and the four-chip
-layouts) and the engine's prefill, mixed and decode-window steps at
+layouts) and the engine's prefill, mixed (with the paged-attention kernel,
+and with the gathered reference it replaced) and decode-window steps at
 chip_smoke's real sizes, and prints what each needs on a device
 (`memory_analysis()`), which kernels it holds and which collectives the
 compiler put in. Nothing runs: it says nothing about results or times.
@@ -51,6 +52,7 @@ def report(name, lowered, t0):
            "output": m.output_size_in_bytes,
            "temp": m.temp_size_in_bytes, "alias": m.alias_size_in_bytes,
            "kernel_calls": chip_smoke.kernel_calls_in(text),
+           "compiled_kernel_calls": chip_smoke.kernel_calls_in(hlo),
            "collectives": {k: v for k, v in collectives.items() if v},
            "compile_s": round(time.perf_counter() - t0, 1)}
     print(json.dumps(out), flush=True)
@@ -68,35 +70,42 @@ def compile_train(model, cfg, devices, layout, options):
 def compile_serve(cfg, device):
     """The engine's three step programs at the smoke's serving config: the
     fresh-prefill step (varlen flash kernel), the mixed prefill/decode
-    step (page-pool gather) and one decode window."""
+    step (the paged-attention kernel; before it, the same step with the
+    gathered reference in the kernel's place, for `memory_analysis()`
+    before and after) and one decode window."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import (PagedCausalLM,
                                               PagedServingConfig,
                                               ServingEngine, _next_pow2)
+    from paddle_tpu.ops.pallas import paged_attention
 
-    paddle.seed(cfg.seed)
     scfg = PagedServingConfig(**cfg.serving)
-    model = PagedCausalLM(scfg)
-    model.eval()
-    eng = ServingEngine.from_model(model, scfg, seed=cfg.seed)
     one = SingleDeviceSharding(device)
+
+    def engine():
+        # a model each: its step programs are traced once
+        paddle.seed(cfg.seed)
+        model = PagedCausalLM(scfg)
+        model.eval()
+        return ServingEngine.from_model(model, scfg, seed=cfg.seed)
 
     def shp(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-
-    def f32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
-
-    B1 = scfg.max_batch + 1
-    fp = [shp(a) for a in eng._params]
-    fb = [shp(a) for a in eng._buffers]
-    kc, vc = shp(eng._kc), shp(eng._vc)
+    eng = engine()
+    use_kernel = paged_attention.use_kernel
+    paged_attention.use_kernel = lambda *a, **k: False
+    try:
+        t0 = time.perf_counter()
+        report(f"serve mixed step L{scfg.num_layers} "
+               f"T{scfg.token_budget}, gathered reference",
+               eng._compiled.lower(
+                   *chip_smoke.abstract_step_args(eng, scfg, shp)), t0)
+    finally:
+        paged_attention.use_kernel = use_kernel
+    eng = engine()
     T = scfg.token_budget
-    step_args = (fp, fb, i32(T), i32(B1), i32(B1), i32(B1), i32(B1 + 1),
-                 i32(B1, scfg.max_blocks_per_seq), kc, vc)
+    step_args = chip_smoke.abstract_step_args(eng, scfg, shp)
     t0 = time.perf_counter()
     report(f"serve fresh-prefill L{scfg.num_layers} T{T}",
            eng._compiled_fresh.lower(*step_args), t0)
@@ -108,9 +117,8 @@ def compile_serve(cfg, device):
     t0 = time.perf_counter()
     window = eng._decode_window_fn(rows, n, "greedy")
     report(f"serve decode window L{scfg.num_layers} rows{rows} n{n}",
-           window.lower(fp, fb, i32(rows), i32(B1), i32(B1), i32(B1),
-                        i32(B1 + 1), i32(B1, scfg.max_blocks_per_seq), kc,
-                        vc, (), f32(B1), i32(B1), f32(B1), i32(n, B1)), t0)
+           window.lower(*chip_smoke.abstract_window_args(
+               eng, scfg, rows, n, shp)), t0)
 
 
 def main():

@@ -93,6 +93,9 @@ KNOWN_METRICS = (
     # real tokens, padding up to the static token length, prompt tokens
     "serving/step_rows", "serving/step_tokens",
     "serving/step_pad_tokens", "serving/step_prefill_tokens",
+    # of serving/steps, those whose program holds the paged-attention
+    # Pallas kernel (0 off the chip and on exported artifacts)
+    "serving/paged_kernel_steps",
     # fleet serving tier: shared-prefix KV reuse (inference/
     # prefix_cache.py), multi-replica routing (inference/router.py),
     # disaggregated prefill/decode hand-offs (inference/disagg.py)
