@@ -52,8 +52,6 @@ def main(argv=None) -> int:
     devices = harness.require_tpu(cell.chips)
     config = cell.config
     ref = harness.load_module("reference", config["reference"])
-    max_seq = config["serving"]["max_blocks_per_seq"] \
-        * config["serving"]["block_size"]
     for i in range(args.seeds):
         seed = args.first_seed
         r = serve.run(cell, seed, args.seconds, False, devices,
@@ -66,7 +64,7 @@ def main(argv=None) -> int:
                                   donate=False)
             gap, std = 0.0, []
             for prompt, tokens in r.stats["sample"]:
-                kw = dict(pad_to=max_seq)
+                kw = dict(pad_to=r.stats["max_seq"])
                 full = np.asarray(ref.logits_at(
                     w, prompt + tokens, len(prompt) - 1, config,
                     **kw))[:len(tokens)]

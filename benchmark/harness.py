@@ -36,9 +36,30 @@ def load_manifest(root: str = ROOT) -> dict:
 
 
 def load_module(kind: str, name: str):
-    """`benchmark/<kind>/<name>.py`: a runner, a reader, a work file or a
-    reference, registered by nothing but its file name."""
+    """`benchmark/<kind>/<name>.py`: a runner, a program's builder, a
+    reader, a work file or a reference, registered by nothing but its file
+    name."""
     return importlib.import_module(f"benchmark.{kind}.{name}")
+
+
+# the keys by which a configuration names files, and where each file lies
+NAMED_FILES = {"entry": "runners/{}.py", "program": "programs/{}.py",
+               "step_work": "work/{}.py", "reference": "reference/{}.py",
+               "published_as": "published/{}.json"}
+
+
+def check_named_files(config: dict, bench_dir: str = BENCH_DIR) -> None:
+    """BenchmarkError that names the key and the missing file, where the
+    configuration names a file the benchmark does not have."""
+    for key, where in NAMED_FILES.items():
+        if key not in config:
+            continue
+        rel = where.format(config[key])
+        if not os.path.isfile(os.path.join(bench_dir, rel)):
+            raise BenchmarkError(
+                f"configuration {config.get('name')!r} names "
+                f"\"{key}\": {config[key]!r}, and there is no "
+                f"benchmark/{rel}")
 
 
 @dataclass

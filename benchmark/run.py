@@ -79,6 +79,7 @@ def main(argv=None) -> int:
 
     try:
         cell = harness.Cell.find(args.workload)
+        harness.check_named_files(cell.config)
         harness.setup_compile_cache()
         devices = harness.require_tpu(cell.chips)
         peak = harness.load_peak(devices[0].device_kind)

@@ -1,5 +1,9 @@
 """The serving runner: `ServingEngine.add_request` / `ServingEngine.step`
-under an open loop.
+under an open loop, over whichever model the configuration's `program`
+builds. From the engine it takes what every engine has (`cfg.max_seq`,
+`cfg.token_budget`, `cfg.vocab_size`, `add_request`, `step`, `pending`,
+`run_to_completion`, a request's `cached` and `sched_t0`), from the
+configuration no size of a model.
 
 One loop in one thread, as `inference/router.py` drives a replica: admit
 what is due, `engine.step()` while anything is pending, else sleep to the
@@ -27,43 +31,10 @@ DRAIN_LIMIT_S = 60.0         # past the window's close, for late answers
 
 
 def build_engine(config: dict, seed: int):
-    """The program under test, as a deployment builds it: the model cast
-    to the serving dtype (no float32 master), the benchmark's weights
-    written into it, the engine over it."""
-    import paddle_tpu as paddle
-    from paddle_tpu.inference.serving import (PagedCausalLM,
-                                              PagedServingConfig,
-                                              ServingEngine)
-    from paddle_tpu.jit import functional as FB
-
-    if config["rope_theta"] != 10000.0 or config["rms_norm_eps"] != 1e-6:
-        raise BenchmarkError(
-            "PagedCausalLM hard-codes rope base 10000 and RMSNorm epsilon "
-            "1e-6; the configuration states another")
-    s = config["serving"]
-    if s["max_blocks_per_seq"] * s["block_size"] > config["sliding_window"]:
-        raise BenchmarkError("the engine has no sliding-window attention")
-    scfg = PagedServingConfig(
-        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
-        num_layers=config["num_hidden_layers"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        ffn_size=config["intermediate_size"], block_size=s["block_size"],
-        num_blocks=s["num_blocks"], max_batch=s["max_batch"],
-        max_blocks_per_seq=s["max_blocks_per_seq"],
-        token_budget=s["token_budget"], dtype=s["dtype"])
-    paddle.seed(seed & 0x7FFFFFFF)
-    model = PagedCausalLM(scfg)
-    model.eval()
-    if s["dtype"] != "float32":
-        model.to(dtype=s["dtype"])
-    mine = weights.make_like(FB.current_params(model), config, seed,
-                             donate=True)
-    FB.write_back(model, mine)
-    shapes = {k: (a.shape, a.dtype) for k, a in mine.items()}
-    del mine
-    engine = ServingEngine.from_model(model, scfg, seed=seed & 0x7FFFFFFF)
-    return model, engine, scfg, shapes
+    """(model, engine, weight shapes) from the program the configuration
+    names: `benchmark/programs/<program>.py`."""
+    program = load_module("programs", config["program"])
+    return program.build_engine(config, seed)
 
 
 class _Record:
@@ -104,21 +75,21 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start):
         raise BenchmarkError("the served-logit check needs greedy requests")
     counter = CompileCounter()
     phases = {"start": time.perf_counter() - t_start}   # imports, the chip
-    model, engine, scfg, shapes = build_engine(config, seed)
+    model, engine, shapes = build_engine(config, seed)
     phases["engine"] = time.perf_counter() - t_start
+    max_seq, vocab = engine.cfg.max_seq, engine.cfg.vocab_size
     schedule = traffic_gen.open_loop(
-        mix, seed, config["vocab_size"],
-        [mix["ramp_s"], seconds, mix["tail_s"]])
+        mix, seed, vocab, [mix["ramp_s"], seconds, mix["tail_s"]])
     too_long = [r for r in schedule
-                if len(r.prompt) + r.max_new > scfg.max_seq]
+                if len(r.prompt) + r.max_new > max_seq]
     if too_long:
         raise BenchmarkError(f"{len(too_long)} request(s) pass max_seq")
 
     # warm-up: what the loop calls, nothing else. A fresh prefill, then
     # mixed steps (chunked prefill beside decode), then the sampler.
     rng = np.random.default_rng([seed, 9])
-    for n in (scfg.token_budget + 8, 24):
-        engine.add_request(rng.integers(1, config["vocab_size"], n).tolist(),
+    for n in (engine.cfg.token_budget + 8, 24):
+        engine.add_request(rng.integers(1, vocab, n).tolist(),
                            max_new_tokens=3)
         engine.step()
     engine.run_to_completion()
@@ -247,7 +218,6 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start):
               for i in picks[:mix["check_requests"]]]
 
     peak = memory_peak_bytes(devices)
-    max_seq = scfg.max_seq
     for rec in records.values():
         rec.req = None
     del engine, model, records, finished, measured
@@ -263,6 +233,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start):
             np.asarray(logits)[:len(tokens)], tokens))
     stats["checked_tokens"] = sum(len(t) for _, t in sample)
     stats["sample"], stats["weight_shapes"] = sample, shapes
+    stats["max_seq"] = max_seq
     return RunResult(
         attempted=attempted, failed=failed, end_to_end=end_to_end,
         compared={"served_logit_gap": Compared(

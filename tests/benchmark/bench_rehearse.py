@@ -30,14 +30,31 @@ def synthetic_trace(stats_spans=8):
         ann, key=lambda a: a[1]))
 
 
-def rehearse(workload, seconds=1.0, seed=2 ** 31 + 5, trace=False):
-    """(cell, RunResult, result line) of one run of a tiny cell."""
+def stand_in_tracer(monkeypatch):
+    """The profiler's trace stood in for by `synthetic_trace()` (the CPU
+    has no TPU plane), the traced seconds cut to 0.3."""
+    from benchmark import harness
+
+    monkeypatch.setattr(harness.Tracer, "start", lambda self: setattr(
+        self, "running", self.enabled) or setattr(
+            self, "started_at", time.perf_counter()))
+    monkeypatch.setattr(harness.Tracer, "stop",
+                        lambda self: setattr(self, "running", False))
+    monkeypatch.setattr(harness.Tracer, "load",
+                        lambda self: synthetic_trace())
+    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.3)
+
+
+def rehearse(workload, seconds=1.0, seed=2 ** 31 + 5, trace=False,
+             root=DATA):
+    """(cell, RunResult, result line) of one run of a tiny cell of the
+    tree at `root`."""
     import jax
 
     from benchmark import harness
     from benchmark import run as run_mod
 
-    cell = harness.Cell.find(workload, root=DATA, bench_dir=DATA)
+    cell = harness.Cell.find(workload, root=root, bench_dir=root)
     devices = jax.devices()[:cell.chips]
     runner = harness.load_module("runners", cell.config["entry"])
     result = runner.run(cell, seed, seconds, trace, devices,

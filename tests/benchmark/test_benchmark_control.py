@@ -103,7 +103,7 @@ def test_serve_control_fp8_fails():
     ref = harness.load_module("reference", cell.config["reference"])
     from benchmark.runners import serve
 
-    model, engine, scfg, shapes = serve.build_engine(cell.config, 31)
+    model, engine, shapes = serve.build_engine(cell.config, 31)
     w = weights.make_like(shapes, cell.config, 31, donate=False)
     rng = np.random.default_rng(0)
     worst = 0.0

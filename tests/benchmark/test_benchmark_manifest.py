@@ -1,11 +1,14 @@
 """BENCHMARK.json against the files it names and the contract's limits on
-names; and that a configuration, a traffic mix and a layer metric are each
-picked up as files plus one manifest entry, with no edit to a file that is
-there."""
+names; each configuration against the published sizes it names; and that a
+configuration, a traffic mix, a layer metric and a whole second
+architecture are each picked up as files plus manifest entries, with no
+edit to a file that is there."""
+import importlib.util
 import json
 import os
 import re
 import shutil
+import sys
 
 import pytest
 
@@ -18,6 +21,8 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTHS = {"hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_experts_per_tok", "sliding_window", "state_size", "expand"}
 MANIFESTS = [br.REPO, br.DATA]
+REAL_CONFIGS = os.path.join(harness.BENCH_DIR, "configs")
+SECOND_ARCH = os.path.join(os.path.dirname(br.DATA), "second_arch")
 
 
 def manifest_of(root):
@@ -27,6 +32,41 @@ def manifest_of(root):
 
 def bench_dir_of(root):
     return harness.BENCH_DIR if root == br.REPO else root
+
+
+def published_of(cfg, bench_dir):
+    """The published sizes a configuration is held to: the file it names
+    under `published_as`. A rehearsal's toy configuration need name none,
+    and then stands for itself."""
+    if "published_as" not in cfg:
+        return cfg
+    return harness.load_json("published", cfg["published_as"] + ".json",
+                             bench_dir=bench_dir)
+
+
+def check_cuts(cfg, published):
+    """A width is never cut, and no context is longer than the published
+    attention's: the window where the source has one, else its positions."""
+    for key in cfg["reduced"]:
+        assert NAME.match(key) and key in cfg["published"]
+        assert key not in WIDTHS
+        assert not key.endswith(("_dim", "_rank"))
+    assert cfg["assumed"]["max_context"] <= (
+        published.get("sliding_window")
+        or published["max_position_embeddings"])
+
+
+def check_against_published(cfg, bench_dir):
+    """Every key of the source's config stands in the configuration with
+    the published value, unless `reduced` lists it, and then `published`
+    keeps the source's value."""
+    published = dict(published_of(cfg, bench_dir))
+    assert cfg["source"] == published.pop("source")
+    for key, value in published.items():
+        kept = cfg["published"] if key in cfg["reduced"] else cfg
+        assert kept[key] == value, key
+    assert set(cfg["reduced"]) <= set(published)
+    check_cuts(cfg, published)
 
 
 @pytest.mark.parametrize("root", MANIFESTS, ids=["real", "rehearsal"])
@@ -82,14 +122,15 @@ def test_every_cell_finds_its_files(root):
             cfg = json.load(f)
         assert cfg["source"] == c["source"] and len(c["source"]) <= 200
         assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
-        assert cfg["entry"] in ("train", "serve")
+        # the runner, the program and the step's work it names all load
+        assert callable(harness.load_module("runners", cfg["entry"]).run)
+        if "program" in cfg:
+            assert callable(harness.load_module(
+                "programs", cfg["program"]).build_engine)
+        assert callable(harness.load_module("work", cfg["step_work"]).flops)
         for key in ("assumed", "departures", "deployment", "published"):
             assert key in cfg
-        for key in c["reduced"]:
-            assert NAME.match(key) and key in cfg["published"]
-            assert key not in WIDTHS           # a width is never cut
-            assert not key.endswith(("_dim", "_rank"))
-        assert cfg["assumed"]["max_context"] <= 4096
+        check_cuts(cfg, published_of(cfg, bench_dir_of(root)))
     for w in m["workloads"]:
         cell = harness.Cell.find(w["name"], root=root,
                                  bench_dir=bench_dir_of(root))
@@ -104,16 +145,14 @@ def test_every_cell_finds_its_files(root):
             assert cell.traffic["rate_per_s"] > 0   # a number, from a sweep
 
 
-def test_real_widths_are_the_published_ones():
-    published = {"hidden_size": 4096, "intermediate_size": 14336,
-                 "num_attention_heads": 32, "num_key_value_heads": 8,
-                 "vocab_size": 32000, "sliding_window": 4096,
-                 "rope_theta": 10000.0, "max_position_embeddings": 32768}
-    for c in manifest_of(br.REPO)["configs"]:
-        with open(os.path.join(br.REPO, c["file"])) as f:
-            cfg = json.load(f)
-        for k, v in published.items():
-            assert cfg[k] == v, (c["name"], k)
+@pytest.mark.parametrize("file", sorted(os.listdir(REAL_CONFIGS)))
+def test_real_widths_are_the_published_ones(file):
+    """One case a file under `benchmark/configs/`, the file-only ones
+    among them: each names its published sizes and keeps to them."""
+    with open(os.path.join(REAL_CONFIGS, file)) as f:
+        cfg = json.load(f)
+    assert "published_as" in cfg, "a real configuration names its source's"
+    check_against_published(cfg, harness.BENCH_DIR)
 
 
 @pytest.mark.parametrize("root", MANIFESTS, ids=["real", "rehearsal"])
@@ -136,6 +175,9 @@ def test_layer_metrics_agree_with_their_files(root):
             # each of its cells reports the end-to-end metric it moves
             assert x["moves"] in cell.end_to_end_names(), (x["name"], name)
             assert cell.config["entry"] == spec["applies_to"]["entry"]
+            if spec["reader"] == "step_mfu":     # counted by its own file
+                assert "work" not in spec["params"]
+                harness.load_module("work", cell.config["step_work"])
             assert cells[name]["chips"] >= spec["applies_to"]["min_chips"]
         if "roofline" in x["name"] or "mfu" in x["name"]:
             assert x["unit"] == "%"
@@ -196,6 +238,73 @@ def test_new_files_are_picked_up_with_no_edit_elsewhere(tmp_path):
     assert got == {"added.step_host_ms": {
         "value": pytest.approx(10.0), "unit": "ms"}}
     assert rt.window_of(result.trace)[0] == 0
+
+
+def test_a_second_architecture_is_files_alone(tmp_path, monkeypatch):
+    """What a `model_config` PR adds for an architecture the benchmark has
+    not run, and no edit to a file that is there (`second_arch/` holds one
+    of each, laid over a copy of the rehearsal's tree in `tmp_path`):
+
+      benchmark/configs/<config>.json      the sizes as run, under the
+                                           source's own key names, with
+                                           `program`, `reference`,
+                                           `step_work`, `published_as`
+      benchmark/published/<model>.json     the source's config.json and URL
+      benchmark/programs/<program>.py      build_engine(config, seed)
+      benchmark/reference/<reference>.py   logits_at(...), plain float32
+      benchmark/work/<step_work>.py        flops(model, stats) of its step
+      benchmark/traffic/<traffic>.json     where no mix that is there fits
+      benchmark/limits/<cell>.json         from readings on the chip
+      BENCHMARK.json                       one entry in `configs` and in
+                                           `workloads`, the cell's name in
+                                           the `workloads` of its metrics
+
+    (and its control and faults as a test under `tests/benchmark/`). The
+    configuration here shares no size key with Mistral's, and its rope base
+    is one the `paged_causal_lm` program refuses: the serving runner drives
+    it to `correct`, `serve.step_mfu` is counted by ITS work file (the
+    other would find none of its keys), and this file's tests of a
+    manifest pass on the tree."""
+    root = str(tmp_path)
+    shutil.copytree(br.DATA, root, dirs_exist_ok=True)
+    shutil.copytree(SECOND_ARCH, root, dirs_exist_ok=True)
+    for kind in ("programs", "reference", "work"):
+        for file in os.listdir(os.path.join(root, kind)):
+            name = f"benchmark.{kind}.{file[:-len('.py')]}"
+            spec = importlib.util.spec_from_file_location(
+                name, os.path.join(root, kind, file))
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+            monkeypatch.setitem(sys.modules, name, module)
+    m = manifest_of(root)
+    with open(os.path.join(root, "manifest_entries.json")) as f:
+        entries = json.load(f)
+    m["configs"].append(entries["config"])
+    m["workloads"].append(entries["workload"])
+    for metric in m["end_to_end"] + m["per_layer"]:
+        if entries["reports_what"] in metric.get("workloads", []):
+            metric["workloads"].append(entries["workload"]["name"])
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+
+    cell = harness.Cell.find("alt-lm-chat", root=root, bench_dir=root)
+    mistral = harness.load_json("published", "mistral-7b-v0.1.json")
+    assert set(cell.config) & set(mistral) == {
+        "source", "initializer_range", "max_position_embeddings"}
+    assert cell.config["rope_base"] != mistral["rope_theta"]
+    check_against_published(cell.config, root)
+    test_names_units_and_keys(root)
+    test_every_cell_finds_its_files(root)
+    test_layer_metrics_agree_with_their_files(root)
+
+    br.stand_in_tracer(monkeypatch)
+    cell, result, line = br.rehearse("alt-lm-chat", seconds=1.0, trace=True,
+                                     root=root)
+    assert line["correct"] is True, line["compared"]
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert line["compiles_in_window"] == 0
+    assert 0 < line["metrics"]["serve.step_mfu"]["value"] < 100
+    assert "serve.device_idle_share" in line["metrics"]
 
 
 def test_traffic_generator_orders_fixed_sets():

@@ -185,7 +185,8 @@ def test_kernel_roofline_reader():
 def test_step_work_hand_counts_and_mfu():
     m = {"hidden_size": 4096, "intermediate_size": 14336,
          "num_attention_heads": 32, "num_key_value_heads": 8,
-         "num_hidden_layers": 3, "vocab_size": 32000}
+         "num_hidden_layers": 3, "vocab_size": 32000,
+         "step_work": "train_step"}     # the configuration names its count
     layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     assert layer == 218_103_808
     assert train_step.matmul_params(m) == 3 * layer + 4096 * 32000
@@ -198,11 +199,11 @@ def test_step_work_hand_counts_and_mfu():
                                ("bench.sync", 0.5, 2.0)])
     stats = {"traced_work": {"tokens": 8192, "seq": 4096}}
     want = 100 * 8192 * per_token / (2.0 * 197e12)
-    assert step_mfu.read({"work": "train_step", "time": "window"},
+    assert step_mfu.read({"time": "window"},
                          ctx(tr, stats, model=m)) == pytest.approx(want)
-    assert step_mfu.read({"work": "train_step", "time": "bench.engine_step"},
+    assert step_mfu.read({"time": "bench.engine_step"},
                          ctx(tr, stats, model=m)) == pytest.approx(4 * want)
-    assert step_mfu.read({"work": "train_step", "time": "window"},
+    assert step_mfu.read({"time": "window"},
                          ctx(tr, {}, model=m)) is None
 
 

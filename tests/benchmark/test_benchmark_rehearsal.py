@@ -40,16 +40,7 @@ def test_traced_line_reports_layer_metrics(workload, monkeypatch):
     """--trace 1: the per-layer metrics whose reader finds something, the
     device's busy and window seconds, and a breakdown. The profiler's trace
     is stood in for by a hand-built one (the CPU has no TPU plane)."""
-    from benchmark import harness
-
-    monkeypatch.setattr(harness.Tracer, "start", lambda self: setattr(
-        self, "running", self.enabled) or setattr(
-            self, "started_at", __import__("time").perf_counter()))
-    monkeypatch.setattr(harness.Tracer, "stop",
-                        lambda self: setattr(self, "running", False))
-    monkeypatch.setattr(harness.Tracer, "load",
-                        lambda self: br.synthetic_trace())
-    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.3)
+    br.stand_in_tracer(monkeypatch)
     cell, result, line = br.rehearse(workload, seconds=1.0, trace=True)
     kind = workload.split("-")[1]
     assert f"{kind}.device_idle_share" in line["metrics"]
@@ -72,3 +63,27 @@ def test_run_py_refuses_a_cpu():
     assert out.returncode != 0
     assert out.stdout.strip() == ""
     assert "no TPU" in out.stderr
+
+
+@pytest.mark.parametrize("key,missing", [
+    ("program", "benchmark/programs/nowhere.py"),
+    ("step_work", "benchmark/work/nowhere.py"),
+    ("published_as", "benchmark/published/nowhere.json"),
+])
+def test_run_py_names_the_file_a_configuration_lacks(key, missing,
+                                                     monkeypatch, capsys):
+    """A configuration that names a file the benchmark does not have: exit
+    code 1, no result line, and the key and the file by name (before the
+    look for a chip, so the CPU shows it)."""
+    from benchmark import harness
+    from benchmark import run as run_mod
+
+    cell = harness.Cell.find("mistral7b-serve-chat")
+    cell.config[key] = "nowhere"
+    monkeypatch.setattr(harness.Cell, "find",
+                        classmethod(lambda cls, *a, **kw: cell))
+    assert run_mod.main(["--workload", cell.name, "--seed", "1",
+                         "--seconds", "1"]) == 1
+    said = capsys.readouterr()
+    assert said.out == ""
+    assert missing in said.err and f'"{key}"' in said.err
