@@ -182,7 +182,8 @@ def assert_on(tree, devices) -> None:
 
 
 KERNEL_FAMILIES = ("flash_attention", "varlen_attention", "rms_norm",
-                   "paged_attention", "ssm_state_update")
+                   "paged_attention", "ssm_state_update",
+                   "kv_page_write")
 
 
 def kernel_calls_in(text: str) -> Dict[str, int]:
@@ -206,6 +207,24 @@ def kernel_calls_in(text: str) -> Dict[str, int]:
     out = {k: sum(n.startswith(k) for n in names) for k in KERNEL_FAMILIES}
     out["total"] = text.count("tpu_custom_call")
     return out
+
+
+def whole_array_copies_in(compiled_text: str, array) -> int:
+    """`copy` operations of a COMPILED program's text whose result has
+    `array`'s shape and dtype (anything with `.shape` and `.dtype`): a
+    state or page stack re-laid whole. A stack that its step program was
+    given donated, and that only aliasing kernels touch, has none."""
+    import re
+
+    import numpy as np
+
+    short = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}[
+        np.dtype(array.dtype).name]
+    shape = "%s[%s]" % (short, ",".join(map(str, array.shape)))
+    if shape not in compiled_text:
+        raise AssertionError(f"no {shape} in the program's text")
+    return len(re.findall(r"= " + re.escape(shape) + r"\S* copy\(",
+                          compiled_text))
 
 
 def reference_dispatches() -> Dict[str, int]:
@@ -589,13 +608,16 @@ def phase_serve(cfg: SmokeConfig, devices) -> Dict[str, Any]:
     engine_refs = dispatches_since(ref0)
     check_no_reference_dispatch(cfg, engine_refs, "the engine's path")
     # the mixed step as the engine ran it, compiled again from its shapes
-    # (a hit in the compilation cache): one paged-attention kernel a layer
-    # when the configuration expects kernels, none otherwise
+    # (a hit in the compilation cache): one paged-attention kernel and one
+    # page-write kernel a layer when the configuration expects kernels,
+    # none otherwise
     calls = kernel_calls_in(engine._compiled.lower(
         *abstract_step_args(engine, scfg)).compile().as_text())
-    if calls["paged_attention"] != (scfg.num_layers if cfg.kernels else 0):
+    want = dict.fromkeys(("paged_attention", "kv_page_write"),
+                         scfg.num_layers if cfg.kernels else 0)
+    if {k: calls[k] for k in want} != want:
         raise AssertionError(
-            f"paged_attention calls in the engine's mixed step: {calls}")
+            f"kernel calls in the engine's mixed step: {calls}, want {want}")
     ref1 = reference_dispatches()
 
     # -- the reference: forward_dense, teacher-forced -------------------
@@ -705,7 +727,8 @@ def phase_serve_hybrid(cfg: SmokeConfig, devices) -> Dict[str, Any]:
     calls = kernel_calls_in(engine._compiled.lower(
         *abstract_step_args(engine, scfg)).compile().as_text())
     want = {"ssm_state_update": spec.count("M") if cfg.kernels else 0,
-            "paged_attention": spec.count("*") if cfg.kernels else 0}
+            "paged_attention": spec.count("*") if cfg.kernels else 0,
+            "kv_page_write": spec.count("*") if cfg.kernels else 0}
     if {k: calls[k] for k in want} != want:
         raise AssertionError(
             f"kernel calls in the hybrid mixed step: {calls}, want {want}")

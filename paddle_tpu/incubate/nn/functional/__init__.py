@@ -564,8 +564,8 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                               quant_round_type=1, quant_max_bound=127.0,
                               quant_min_bound=-127.0, out_scale=-1,
                               compute_dtype="default", layer_idx=None,
-                              fresh_prefill=False, key_cache_in=None,
-                              value_cache_in=None):
+                              fresh_prefill=False,
+                              last_row_is_padding=False):
     """Paged-KV-cache attention (reference block_multihead_attention):
     qkv [token_num, (HQ+2*HKV)*D] packs each batch row's tokens this step
     (prefill rows contribute seq_lens_encoder[b] tokens at positions
@@ -578,26 +578,22 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     filled prefix (causal). Returns
     (out [token_num, HQ*D], qkv, key_cache, value_cache).
 
-    The attention itself is ops/pallas/paged_attention.py: on a TPU (or
-    under PT_PALLAS_INTERPRET=1) a Pallas kernel that walks each row's
-    own pages in place, for a bf16/float32 cache whose head_dim is a
-    multiple of 128 and whose block_size tiles the dtype's sublanes;
-    elsewhere — kernels off, an int8 cache, shapes that do not tile — the
-    gathered jnp formulation, counted as
-    pallas/reference_dispatch/paged_attention when kernels are on. A
-    program traced off the chip (save_paged_model's export) holds the
+    The page write and the attention are Pallas kernels on a TPU (or under
+    PT_PALLAS_INTERPRET=1), for a bf16/float32 cache whose head_dim is a
+    multiple of 128 and whose block_size tiles the dtype's sublanes:
+    ops/pallas/paged_attention.py walks each row's own pages in place, as
+    they were BEFORE this call's write, and takes this step's keys from
+    qkv; then ops/pallas/kv_page_write.py puts the step's keys and values
+    into their pages where the stacks lie (the returned caches alias the
+    given ones: a caller that donates its stacks to the jitted step copies
+    nothing). Both take the stacks in the plain layout, so a stack threaded
+    through a model's layers is never re-laid. Elsewhere — kernels off, an
+    int8 cache, shapes that do not tile — the write is XLA's scatter and
+    the attention the gathered jnp formulation over the written caches,
+    counted as pallas/reference_dispatch/paged_attention when kernels are
+    on (one question decides both kernels, so one count). A program traced
+    off the chip (save_paged_model's export) holds the scatter and the
     gathered formulation.
-
-    key_cache_in / value_cache_in (optional, stacked mode): the stacks as
-    they ENTERED the step, for a caller that threads key_cache /
-    value_cache through its layers. The kernel reads a layer's cached
-    pages before this call's write and takes this step's keys from qkv,
-    so it can read them there: layer `layer_idx` of the two must equal
-    layer `layer_idx` of key_cache / value_cache (no earlier call wrote
-    it). Without them it reads key_cache itself, which is as correct, but
-    a threaded stack reaches each layer in the layout XLA gives its
-    scatter, and every layer's kernel then waits for a copy of the whole
-    stack. The writes always go to key_cache / value_cache.
 
     Int8 KV cache (use_dynamic_cachekv_quant=True): caches are int8 page
     pools and cache_k_quant_scales / cache_v_quant_scales are PER-SLOT
@@ -611,19 +607,24 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     Static per-tensor scale args (the non-dynamic CUDA path) and
     pre_caches stay unsupported.
 
+    last_row_is_padding=True is the engine's packing: the LAST batch row
+    (index B-1, where B = block_tables.shape[0]) is the trash row, whose
+    tokens pad the step to its token budget and whose page is trash by
+    definition. The page-write kernel then skips that row (it holds most
+    of a lightly loaded step's tokens); the scatter writes them into the
+    trash page as before. Padding cannot be derived from the packed
+    offsets alone: cu_seqlens_q[-1] equals the full token budget because
+    the trash row's count is included (tokens in [cu_seqlens_q[B-1],
+    cu_seqlens_q[B]) are the padding), so the identification goes through
+    the row INDEX, not through a cu_q[-1]-vs-T comparison.
+
     fresh_prefill=True asserts every scheduled row starts at cache
     position 0 (seq_lens_decoder[b] == 0 for live rows), so this step's
     packed tokens ARE each row's full key set: attention runs as
     block-diagonal varlen flash over the pack, skipping the page-pool
-    gather. Padding-row contract: the LAST batch row (index B-1, where B
-    = block_tables.shape[0]) is the engine's trash row — its tokens get
-    segment id -1 and attend nothing. Padding cannot be derived from the
-    packed offsets alone: cu_seqlens_q[-1] equals the full token budget
-    because the trash row's count is included (tokens in
-    [cu_seqlens_q[B-1], cu_seqlens_q[B]) are the padding), so the
-    identification goes through the row INDEX, not through a
-    cu_q[-1]-vs-T comparison. Callers scheduling real work into row B-1
-    must not set fresh_prefill."""
+    gather. It implies the padding-row contract above: the last row's
+    tokens get segment id -1 and attend nothing. Callers scheduling real
+    work into row B-1 must set neither."""
     if cache_k_quant_scales is not None and not use_dynamic_cachekv_quant:
         raise NotImplementedError("block_multihead_attention: static "
                                   "per-tensor cache scales are CUDA-"
@@ -646,25 +647,13 @@ def block_multihead_attention(qkv, key_cache, value_cache,
         vs_in = next(it) if quant else None
         b = next(it) if qkv_bias is not None else None
         rope = next(it) if rope_emb is not None else None
-        kc_read, vc_read = (next(it), next(it)) \
-            if key_cache_in is not None else (kc_in, vc_in)
         T = qkva.shape[0]
         # stacked-cache mode (layer_idx given): caches are
-        # [L, num_blocks, H, bs, D] and every access uses a COMPOSITE
-        # (layer, ...) index — scatter straight into the stacked buffer,
-        # gather pages with (layer, block_table) start indices. The
-        # earlier slice-out / dynamic-update-slice-back pattern
-        # materialized a full per-layer cache copy each layer (decode
-        # step time scaled with the PAGE-POOL size: 2.3 ms at 88 pages
-        # vs 5.7 ms at 248, tools/ablate_cachesize.py).
-        if layer_idx is None:
-            kc, vc = kc_in, vc_in
-            ks, vs = ks_in, vs_in
-            num_blocks, HKV, bs, D = kc.shape
-        else:
-            kc, vc = kc_in, vc_in
-            ks, vs = ks_in, vs_in
-            num_blocks, HKV, bs, D = kc.shape[1:]
+        # [L, num_blocks, H, bs, D] and every access goes by a composite
+        # (layer, page, ...) index straight into the stacked buffer — a
+        # layer sliced out and put back was a copy of its whole cache
+        kc, vc, ks, vs = kc_in, vc_in, ks_in, vs_in
+        HKV, bs, D = kc.shape[-3:]
         B = bt.shape[0]
         if b is not None:
             qkva = qkva + b.reshape(1, -1)
@@ -705,11 +694,13 @@ def block_multihead_attention(qkv, key_cache, value_cache,
             # dtype so the page scatter below matches the cache dtype
             q = rope_t(q).astype(qkva.dtype)
             k = rope_t(k).astype(qkva.dtype)
-        # scatter new k/v into pages (straight into the stacked buffer
-        # via the composite (layer, page, :, slot) index in stacked mode)
-        page = bt[t2b, pos // bs]                            # [T]
-        slot = pos % bs
-        li = (() if layer_idx is None else (layer_idx,))
+        # the step's k/v go to their pages, and each row attends over its
+        # own pages: kernels where the cache tiles (one question for both),
+        # XLA's scatter and the gathered reference elsewhere
+        from ....ops.pallas import kv_page_write as _kw
+        from ....ops.pallas import paged_attention as _pa
+
+        kernels = _pa.use_kernel(q, kc, quant)
         if quant:
             # dynamic int8: one scale per written (token, head) —
             # s = max|x|/127, store round(x/s) int8 + s in the scale pool
@@ -722,22 +713,28 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                     .astype(jnp.int8)
                 return xi, s.astype(jnp.float32)
 
+            # straight into the stacked buffer via the composite
+            # (layer, page, :, slot) index in stacked mode
+            page = bt[t2b, pos // bs]                        # [T]
+            slot = pos % bs
+            at = (() if layer_idx is None else (layer_idx,)) \
+                + (page, slice(None), slot)
             with scope("kv_write"):
                 k8, k_s = q8(k)
                 v8, v_s = q8(v)
-                kc = kc.at[li + (page, slice(None), slot)].set(k8)
-                vc = vc.at[li + (page, slice(None), slot)].set(v8)
-                ks = ks.at[li + (page, slice(None), slot)].set(k_s)
-                vs = vs.at[li + (page, slice(None), slot)].set(v_s)
-        else:
+                kc = kc.at[at].set(k8)
+                vc = vc.at[at].set(v8)
+                ks = ks.at[at].set(k_s)
+                vs = vs.at[at].set(v_s)
+        elif not kernels:
             with scope("kv_write"):
-                kc = kc.at[li + (page, slice(None), slot)].set(k)
-                vc = vc.at[li + (page, slice(None), slot)].set(v)
+                kc, vc = _kw.kv_page_write_ref(kc, vc, k, v, bt, start, cu_q,
+                                               layer_idx=layer_idx)
         if fresh_prefill:
             # every scheduled row starts at cache position 0, so keys ==
             # this step's packed tokens: block-diagonal varlen flash over
-            # the pack (segment id = batch row; trash row = -1), skipping
-            # the full page-pool gather below entirely
+            # the pack (segment id = batch row; trash row = -1), no page
+            # is read
             from ....ops.pallas.varlen_attention import \
                 varlen_flash_attention_packed
 
@@ -750,22 +747,21 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                 vr.transpose(1, 0, 2)[None], seg[None], seg[None],
                 is_causal=True)
             out = o[0].transpose(1, 0, 2)                    # [T, HQ, D]
-            if quant:
-                return out.reshape(T, HQ * D), qkva, kc, vc, ks, vs
-            return out.reshape(T, HQ * D), qkva, kc, vc
-        # each row over its own pages (ops/pallas/paged_attention.py):
-        # the Pallas kernel where it applies, over the caches as they were
-        # before this call's write plus this step's own k/v; the gathered
-        # reference elsewhere, over the written caches
-        from ....ops.pallas import paged_attention as _pa
-
-        if _pa.use_kernel(q, kc_read, quant):
-            out = _pa.paged_attention(q, k, v, kc_read, vc_read, bt, start,
-                                      cu_q, layer_idx=layer_idx)
+        elif kernels:
+            # over the caches as they are before this call's write, plus
+            # this step's own k/v
+            out = _pa.paged_attention(q, k, v, kc, vc, bt, start, cu_q,
+                                      layer_idx=layer_idx)
         else:
             out = _pa.paged_attention_ref(q, kc, vc, bt, start, cu_q,
                                           layer_idx=layer_idx, k_scales=ks,
                                           v_scales=vs)
+        if kernels:
+            # in place, once the attention has read the pages
+            with scope("kv_write"):
+                kc, vc = _kw.kv_page_write(
+                    kc, vc, k, v, bt, start, cu_q, layer_idx=layer_idx,
+                    last_row_is_padding=last_row_is_padding or fresh_prefill)
         out = out.reshape(T, HQ * D)
         if quant:
             return out, qkva, kc, vc, ks, vs
@@ -775,9 +771,7 @@ def block_multihead_attention(qkv, key_cache, value_cache,
             seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
             block_tables] \
         + ([cache_k_quant_scales, cache_v_quant_scales] if quant else []) \
-        + [t for t in (qkv_bias, rope_emb) if t is not None] \
-        + ([key_cache_in, value_cache_in] if key_cache_in is not None
-           else [])
+        + [t for t in (qkv_bias, rope_emb) if t is not None]
     return apply(fn, *args, op_name="block_multihead_attention")
 
 

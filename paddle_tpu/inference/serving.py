@@ -57,7 +57,8 @@ class _EngineMetrics:
     the global one) instead of conflating co-hosted replicas in the
     process-wide series — see ServingEngine.set_metrics_namespace."""
 
-    __slots__ = ("ttft", "tpot", "steps", "paged_steps", "tokens",
+    __slots__ = ("ttft", "tpot", "steps", "paged_steps", "inplace_steps",
+                 "tokens",
                  "requests", "step_rows", "step_tokens", "step_pad",
                  "step_prefill", "preempt", "occupancy", "kv_util",
                  "deadline", "shed",
@@ -75,6 +76,10 @@ class _EngineMetrics:
         # kernel (ops/pallas/paged_attention.py); 0 on an engine that
         # traces off the chip or loads an exported artifact
         self.paged_steps = reg.counter("serving/paged_kernel_steps")
+        # and the steps whose program writes the step's keys and values
+        # into the donated page stacks where they lie
+        # (ops/pallas/kv_page_write.py): a fresh-prefill step too
+        self.inplace_steps = reg.counter("serving/kv_inplace_steps")
         # what each step held (bumped once a step): scheduled rows, real
         # tokens, the padding up to the step's static token length, and
         # the tokens of rows still inside their prompt
@@ -522,10 +527,7 @@ class PagedCausalLM(Layer):
                     max_seq_len=cfg.max_seq, block_size=cfg.block_size,
                     fresh_prefill=getattr(self, "_step_mode", None)
                     == "fresh_prefill",
-                    # the paged kernel reads a layer's cached pages from
-                    # the stacks as they entered the step (the threaded
-                    # ones reach it re-laid by the layers' scatters)
-                    key_cache_in=key_caches, value_cache_in=value_caches)
+                    last_row_is_padding=True)
                 if quant:
                     out, _, new_kc, new_vc, new_ks, new_vs = outs
                 else:
@@ -735,8 +737,9 @@ class ServingEngine:
         else:
             self._fixed_token_len = None
         self._compiled_fresh = None   # set by from_model (jit engines)
-        # {step program: its trace took the paged-attention kernel},
-        # written when from_model's programs are traced
+        # {step program: {kernel: its trace took it}} for the two kernels
+        # over the page stacks (paged_attention, kv_page_write), written
+        # when from_model's programs are traced
         self._kernel_programs = {}
         self._compiled_verify = None  # all-positions logits (from_model)
         # the from_model weight_stream mode this engine's flat params
@@ -924,14 +927,19 @@ class ServingEngine:
         kernel_programs = eng._kernel_programs
 
         def pure(fp, fb, *ins):
-            from ..ops.pallas.paged_attention import traced_kernel_calls
+            from ..ops.pallas import kv_page_write, paged_attention
 
             ps = jax.tree_util.tree_unflatten(tree_p, fp[:n_base])
             bs = jax.tree_util.tree_unflatten(tree_b, fb)
             if streamer is not None:
                 object.__setattr__(model, "_wstream_live",
                                    streamer.bind(fp[n_base:]))
-            calls = traced_kernel_calls()
+            def traced():
+                return {"paged_attention":
+                        paged_attention.traced_kernel_calls(),
+                        "kv_page_write": kv_page_write.traced_kernel_calls()}
+
+            before = traced()
             try:
                 if functional:
                     out = model.serving_step(
@@ -942,11 +950,11 @@ class ServingEngine:
             finally:
                 if streamer is not None:
                     object.__setattr__(model, "_wstream_live", None)
-            # this runs when a step program is traced: whether that trace
-            # took the paged-attention kernel (_count_step reads it)
+            # this runs when a step program is traced: which of the two
+            # kernels over the pages that trace took (_count_step reads it)
             kernel_programs[_STEP_PROGRAMS[
-                getattr(model, "_step_mode", None)]] = \
-                traced_kernel_calls() > calls
+                getattr(model, "_step_mode", None)]] = {
+                    k: n > before[k] for k, n in traced().items()}
             return tuple(out)
 
         def pure_fresh(fp, fb, *ins):
@@ -974,14 +982,17 @@ class ServingEngine:
         pure_verify.__name__ = "serving_spec_verify"
         eng._params = jax.device_put(flat_p)
         eng._buffers = jax.device_put(flat_b)
-        # a functional model's step takes its pages and row state as
-        # donated arguments: the state stacks are updated where they lie
-        # (ins: tokens, enc, dec, this, cu, bt, kc, vc, *row state, slots)
-        donate = tuple(range(8, 10 + len(states.row_states))) \
-            if functional else ()
+        # every step program takes its pages, and the row state or the int8
+        # scale pools after them, as donated arguments: the stacks are
+        # updated where they lie, and a caller keeps what the step returns
+        # (ins: tokens, enc, dec, this, cu, bt, kc, vc, then *row state,
+        # slots or ks, vs)
+        donate = tuple(range(8, 10 + (
+            len(states.row_states) or 2 * (cfg.cache_quant == "int8"))))
         eng._compiled = jax.jit(pure, donate_argnums=donate)
         eng._compiled_fresh = jax.jit(pure_fresh, donate_argnums=donate)
-        eng._compiled_verify = None if functional else jax.jit(pure_verify)
+        eng._compiled_verify = None if functional \
+            else jax.jit(pure_verify, donate_argnums=donate)
         object.__setattr__(model, "_serving_shared",
                            (share_key, eng._compiled,
                             eng._compiled_fresh, eng._compiled_verify,
@@ -1420,9 +1431,9 @@ class ServingEngine:
     def probe_logits(self, prompt, version=None):
         """Stateless canary probe: next-token logits of `prompt`'s last
         position under `version` (default: active), WITHOUT touching
-        the KV pool, the scheduler, or any request state — the packed
-        row runs through the fresh-prefill executable against the trash
-        page and the returned caches are discarded. The probe can score
+        the KV pool's live pages, the scheduler, or any request state —
+        the packed row runs through the fresh-prefill executable against
+        the trash page. The probe can score
         a STAGED version before it is committed anywhere, which is how
         a poisoned candidate is rejected without ever serving a token.
         Returns a float32 vector of vocab logits."""
@@ -1456,19 +1467,13 @@ class ServingEngine:
         cu = np.zeros(B1 + 1, np.int32)
         cu[1:] = np.cumsum(this)
         bt = np.zeros((B1, cfg.max_blocks_per_seq), np.int32)
-        if self._row_state:
-            # the probe's row writes the trash page and the padding slot;
-            # the step was given (donated) the engine's state, so what it
-            # returns is kept
-            logits = self._run_step(
-                "serving_fresh_prefill", self._compiled_fresh, fp, tokens,
-                enc, dec, this, cu, bt, np.full(B1, cfg.max_batch, np.int32))
-            return np.asarray(logits, np.float32)[0]
-        extra = (self._ks, self._vs) if self._ks is not None else ()
-        out = self._compiled_fresh(fp, self._buffers, tokens, enc, dec,
-                                   this, cu, bt, self._kc, self._vc,
-                                   *extra)
-        return np.asarray(out[0], np.float32)[0]
+        # the probe's row writes the trash page (and the padding slot of a
+        # row state); the step was given (donated) the engine's state, so
+        # what it returns is kept
+        logits = self._run_step(
+            "serving_fresh_prefill", self._compiled_fresh, fp, tokens, enc,
+            dec, this, cu, bt, np.full(B1, cfg.max_batch, np.int32))
+        return np.asarray(logits, np.float32)[0]
 
     def _salt(self, r, n_generated):
         """Sampling salt under the request's ORIGIN identity: a request
@@ -1608,17 +1613,23 @@ class ServingEngine:
                     prefill_tokens):
         """What one step held: the `serving/step_*` counters, each bumped
         once a step, and the same numbers as the step span's args; and
-        whether `program`, which it ran, holds the paged-attention kernel
+        which of the kernels over the pages `program`, which it ran, holds
         (known once the program has been traced: call this after it)."""
         m = self._m
-        if self._kernel_programs.get(program):
-            m.paged_steps.inc()
+        self._count_kernel_steps(program, 1)
         m.step_rows.inc(rows)
         m.step_tokens.inc(tokens)
         m.step_pad.inc(pad)
         m.step_prefill.inc(prefill_tokens)
         note.update(rows=rows, tokens=tokens, pad=pad,
                     prefill_tokens=prefill_tokens)
+
+    def _count_kernel_steps(self, program, n):
+        held = self._kernel_programs.get(program, {})
+        if held.get("paged_attention"):
+            self._m.paged_steps.inc(n)
+        if held.get("kv_page_write"):
+            self._m.inplace_steps.inc(n)
 
     def _finish(self, r, now):
         """A request's last token has arrived: pages back, the decode span
@@ -1816,9 +1827,8 @@ class ServingEngine:
                   bt, slots):
         """Run one step program over the engine's state and keep what it
         returns: (logits, pages, then the int8 scales or the row states
-        and the step's own counts). A functional model's step was given
-        its pages and row state (donated): they are replaced, not
-        copied."""
+        and the step's own counts). A from_model step was given its pages,
+        scales and row state (donated): they are replaced, not copied."""
         if self._row_state:
             extra = (*self._row_state.values(), slots)
         elif self._ks is not None:
@@ -2098,7 +2108,10 @@ class ServingEngine:
                 body, (tokens, dec, kc, vc, scales), salts)
             return samples, kc, vc, scales
 
-        fn = self._window_fns[key] = jax.jit(window)
+        # the pages and the int8 scales are the window's to update in
+        # place: a from_model engine donates them, as its steps do
+        donate = (8, 9, 10) if self._compiled_fresh is not None else ()
+        fn = self._window_fns[key] = jax.jit(window, donate_argnums=donate)
         return fn
 
     def lower_fused_decode(self, n_rows=None):
@@ -2238,8 +2251,7 @@ class ServingEngine:
             self._params_for(rows[0].weight_version), self._buffers,
             tokens, enc, dec, this, cu, bt,
             self._kc, self._vc, scales, temps, topks, topps, salts)
-        if self._kernel_programs.get("serving_step"):
-            self._m.paged_steps.inc(n)      # the window scans that step
+        self._count_kernel_steps("serving_step", n)   # the window scans it
         self._kc, self._vc = kc, vc
         if self._ks is not None:
             self._ks, self._vs = scales
@@ -2274,9 +2286,10 @@ class ServingEngine:
 def save_paged_model(path_prefix: str, model: PagedCausalLM):
     """Export the paged step function as a serving artifact with the
     engine's static shapes. Traced where it is called: off the chip (the
-    usual place) the artifact holds the gathered reference attention,
-    not the paged-attention kernel, and its engine counts no
-    `serving/paged_kernel_steps`."""
+    usual place) the artifact holds XLA's scatter and the gathered
+    reference attention, not the page-write and paged-attention kernels,
+    and its engine counts no `serving/paged_kernel_steps` and no
+    `serving/kv_inplace_steps`; its stacks are not donated."""
     from . import PrecisionType, save_inference_model
     from ..jit.api import InputSpec
 

@@ -223,7 +223,6 @@ class NemotronH:
             x = p["embed"][tokens]
         counts = jnp.zeros((3,), jnp.int32)
         n = {"M": 0, "*": 0, "E": 0}
-        kc_in, vc_in = kc, vc
         for i, kind in enumerate(s.hybrid_override_pattern):
             w = {k.split(".", 2)[2]: v for k, v in p.items()
                  if k.startswith(f"layers.{i}.")}
@@ -233,7 +232,7 @@ class NemotronH:
             elif kind == "*":
                 with scope("attention"):
                     y, kc, vc = _attention(s, w, u, enc, dec, this, cu, bt,
-                                           kc, vc, kc_in, vc_in, n["*"],
+                                           kc, vc, n["*"],
                                            mode == "fresh_prefill")
             else:
                 y, c = _experts(s, w, u, meta["real"])
@@ -433,12 +432,11 @@ def _chunk_scan(s, ssm, li, xs, dt, a, b, c, d, blocks, n_blocks):
 
 # -- *: attention ---------------------------------------------------------------
 
-def _attention(s, w, u, enc, dec, this, cu, bt, kc, vc, kc_in, vc_in, li,
-               fresh):
+def _attention(s, w, u, enc, dec, this, cu, bt, kc, vc, li, fresh):
     """Grouped-query causal attention over the row's pages, no rotary
     embedding: the engine's paged path (`block_multihead_attention`: the
-    page writes, then the paged-attention kernel, or the varlen kernel
-    where every row starts at position 0)."""
+    paged-attention kernel, or the varlen kernel where every row starts at
+    position 0, then the page-write kernel over the donated stacks)."""
     from ..core.tensor import Tensor
     from ..incubate.nn import functional as IF
 
@@ -446,8 +444,7 @@ def _attention(s, w, u, enc, dec, this, cu, bt, kc, vc, kc_in, vc_in, li,
         Tensor(u @ w["qkv"]), Tensor(kc), Tensor(vc), enc, dec, this, None,
         None, cu, None, bt, rope_emb=None, layer_idx=li,
         max_seq_len=bt.shape[1] * kc.shape[3], block_size=kc.shape[3],
-        fresh_prefill=fresh, key_cache_in=Tensor(kc_in),
-        value_cache_in=Tensor(vc_in))
+        fresh_prefill=fresh, last_row_is_padding=True)
     return out._value @ w["o_proj"], kc._value, vc._value
 
 

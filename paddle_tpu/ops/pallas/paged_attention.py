@@ -20,14 +20,15 @@ this step, `block_tables[b]` its pages. All three are run-time scalars
 
 The kernel reads the caches as they were BEFORE this step's tokens were
 written, and takes this step's keys and values from the packed `k`, `v`
-themselves. Reading them back from the cache would order every layer's
-kernel behind that layer's scatter, and XLA keeps the scattered cache in a
-layout of its own (block and head swapped, a token's `[HKV, D]` update one
-tile): a Mosaic call wants the plain one, so each layer would copy both
-whole caches (seen in the sandbox compile for the chip). A token's keys
-are therefore two sets: its row's cached positions `0 .. start - 1`, in
-the row's pages, and the row's tokens of this step up to itself, in the
-pack.
+themselves: a token's keys are two sets, its row's cached positions
+`0 .. start - 1`, in the row's pages, and the row's tokens of this step up
+to itself, in the pack. So nothing orders a layer's attention behind that
+layer's page write: `block_multihead_attention` runs the write
+(`kv_page_write.py`, the stacks aliased in and out) AFTER this kernel has
+read them, and a stack threaded through a model's layers is only ever
+touched by Mosaic calls, in the plain layout. (While the write was XLA's
+scatter, XLA kept the scattered stack in a layout of its own, block and head
+swapped, and copied both whole stacks in and out of it every step: ISSUE 30.)
 
 The grid walks the packed token axis in blocks of `q_block` tokens. A
 block holds tokens of one row (a prefill chunk) or of many (decode rows of
@@ -244,8 +245,8 @@ def paged_attention(q, k, v, key_cache, value_cache, block_tables, start,
 
     q `[T, HQ, D]`, k / v `[T, HKV, D]` (after rope, in the cache's
     dtype); key_cache / value_cache `[L, num_blocks, HKV, block, D]` with
-    a static `layer_idx`, or `[num_blocks, HKV, block, D]`, as they were
-    BEFORE this step's tokens were written; block_tables
+    a static `layer_idx`, or `[num_blocks, HKV, block, D]`, as they are
+    BEFORE this step's tokens are written; block_tables
     `[B, max_blocks]`; start `[B]` the cache position of each row's first
     token this step; cu_seqlens_q `[B + 1]`. Token `t` of row `b` sees the
     row's cached positions `0 .. start[b] - 1` and the packed tokens

@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -98,6 +99,7 @@ def test_phase_serve_hybrid_on_cpu():
     assert out["decoding_at_late_admit"] > 0
     assert out["streams_equal"] == 5             # float32: token for token
     assert out["kernel_calls"]["ssm_state_update"] == 0   # kernels off
+    assert out["kernel_calls"]["kv_page_write"] == 0
     json.dumps(out)
 
 
@@ -122,7 +124,8 @@ def test_phase_serve_demands_the_paged_kernel_when_configured(monkeypatch):
                    num_blocks=5 * 2 + 1, max_blocks_per_seq=2,
                    token_budget=128)
     with pytest.raises(AssertionError,
-                       match="paged_attention calls in the engine's mixed"):
+                       match="kernel calls in the engine's mixed step.*"
+                             "'paged_attention': 2, 'kv_page_write': 2"):
         chip_smoke.phase_serve(
             debug_config(serving=serving, kernels=True), jax.devices()[:1])
 
@@ -183,7 +186,8 @@ def test_kernel_calls_in_counts_by_family():
         '"paged_attention"}'])
     assert chip_smoke.kernel_calls_in(text) == {
         "flash_attention": 2, "varlen_attention": 1, "rms_norm": 1,
-        "paged_attention": 1, "ssm_state_update": 0, "total": 5}
+        "paged_attention": 1, "ssm_state_update": 0, "kv_page_write": 0,
+        "total": 5}
     # a compiled program's text: the custom calls' instruction names
     compiled = "HloModule jit_serving_step\n" + "\n".join(
         f'  %{name} = bf16[8,1024,128]{{2,1,0}} custom-call(%p), '
@@ -192,7 +196,47 @@ def test_kernel_calls_in_counts_by_family():
                      "rms_norm.7", "closed_call_varlen_attention_fwd.1"))
     assert chip_smoke.kernel_calls_in(compiled) == {
         "flash_attention": 0, "varlen_attention": 1, "rms_norm": 1,
-        "paged_attention": 2, "ssm_state_update": 0, "total": 4}
+        "paged_attention": 2, "ssm_state_update": 0, "kv_page_write": 0,
+        "total": 4}
+
+
+@pytest.mark.parametrize("compiled", [False, True])
+def test_kernel_calls_in_knows_the_page_write_kernel(compiled):
+    """`kv_page_write` beside `paged_attention`, one call a layer, in a
+    lowered program's `kernel_name`s and in a compiled program's
+    instruction names (which JAX's wrappers may prefix)."""
+    if compiled:
+        text = "HloModule jit_serving_step\n" + "\n".join(
+            f'  %{name} = (bf16[2,9,2,32,128]{{4,3,2,1,0}}, '
+            f'bf16[2,9,2,32,128]{{4,3,2,1,0}}) custom-call(%p), '
+            f'custom_call_target="tpu_custom_call", kernel_metadata={{}}'
+            for name in ("kv_page_write.1", "closed_call_kv_page_write.2",
+                         "paged_attention.2", "paged_attention.3"))
+    else:
+        text = "\n".join(
+            f'x = stablehlo.custom_call @tpu_custom_call(%0) '
+            f'{{kernel_name = "{name}"}}'
+            for name in ("kv_page_write", "kv_page_write",
+                         "paged_attention", "paged_attention"))
+    calls = chip_smoke.kernel_calls_in(text)
+    assert calls["kv_page_write"] == calls["paged_attention"] == 2
+    assert calls["total"] == 4
+
+
+def test_whole_array_copies_in_counts_copies_of_one_shape():
+    """A `copy` whose result is the stack (in any layout) counts, a copy of
+    another shape and a fusion of the stack's shape do not; a program that
+    never holds the stack is an error, not a zero."""
+    stack = jax.ShapeDtypeStruct((2, 9, 2, 32, 128), jnp.bfloat16)
+    text = "HloModule jit_serving_step\n" + "\n".join([
+        "  %copy.1 = bf16[2,9,2,32,128]{4,2,3,1,0:T(8,128)(2,1)} copy(%p.7)",
+        "  %copy.2 = bf16[2,9,2,32,128]{4,3,2,1,0} copy(%fusion.3)",
+        "  %copy.3 = bf16[256,2,128]{2,0,1} copy(%p.4)",
+        "  %fusion.3 = bf16[2,9,2,32,128]{4,2,3,1,0} fusion(%copy.1)"])
+    assert chip_smoke.whole_array_copies_in(text, stack) == 2
+    with pytest.raises(AssertionError, match=r"no f32\[5,3\]"):
+        chip_smoke.whole_array_copies_in(
+            text, jax.ShapeDtypeStruct((5, 3), jnp.float32))
 
 
 # ---------------------------------------------------------------------------

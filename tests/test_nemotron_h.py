@@ -307,12 +307,15 @@ def test_phase_map_names_the_new_scopes():
 # taken on the parent of the PR that gave the engine its layer-kind state
 # (jax 0.9.0): the refactor must leave PagedCausalLM's programs as they
 # were. A jax upgrade that changes the text re-takes them (the function
-# below prints what it finds).
+# below prints what it finds). Re-taken by PR 30, which changed the programs
+# on purpose: the page stacks are donated (the arguments carry the mark) and
+# the scatter is `kv_page_write_ref`'s, after the attention where the kernels
+# run and before it where they do not, as here.
 _PAGED_PROGRAMS_JAX = "0.9.0"
 _PAGED_PROGRAMS = {
-    "serving_step": "1165d3b7f0ad90b615a9a1f614f336e76504423234e92d59409bd56fd6f41c56",
-    "serving_fresh_prefill": "3483edbec8498ae62e47c1ebbeb3a768298c2de88352a70d81dc98b86bdb83c9",
-    "serving_spec_verify": "cb035fd5143324f8d89a105600064210ab079cd509321223276b488e39040176",
+    "serving_step": "c4ea4b14857d11047aef603043781cd2eae1e1f7fdd342e5fd049ff8ec80b443",
+    "serving_fresh_prefill": "9a1a9ded5addc4840f53aa7e58ad14fce3f83abbd33e60a9d8a1c5346a9f1e5e",
+    "serving_spec_verify": "7f5bff99c7ecfa27f55904a453e91babb033c4bbeffdb876789f11ed926e8fae",
 }
 
 

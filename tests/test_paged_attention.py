@@ -231,8 +231,9 @@ def test_engine_streams_equal_the_reference_path(monkeypatch,
     assert _delta(c0, "pallas/reference_dispatch/paged_attention") == 0
     if path == "ngram_drafter":
         assert _delta(c0, "serving/spec_steps") > 0
-        assert eng._kernel_programs["serving_spec_verify"]
-    assert eng._kernel_programs == {
+        assert eng._kernel_programs["serving_spec_verify"]["paged_attention"]
+    assert {k: v["paged_attention"]
+            for k, v in eng._kernel_programs.items()} == {
         "serving_step": True, "serving_fresh_prefill": False,
         **({"serving_spec_verify": True} if drafter else {})}
 
@@ -244,9 +245,10 @@ def test_int8_cache_engine_takes_the_reference_and_is_counted(monkeypatch):
     streams, steps, paged = _serve(eng)
     assert [len(s) for s in streams] == [n for _, n in PROMPTS + [LATE]]
     assert paged == 0 and steps > 4
-    # counted where the step program is traced: once a layer
+    # counted where a step program is traced: once a layer, in the mixed
+    # step and in the fresh-prefill step (whose page write asks too)
     assert _delta(c0, "pallas/reference_dispatch/paged_attention") \
-        == ENGINE["num_layers"]
+        == 2 * ENGINE["num_layers"]
 
 
 def test_exported_step_holds_the_reference(tmp_path, monkeypatch):

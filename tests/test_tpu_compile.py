@@ -12,6 +12,7 @@ All in ONE file, the topology described inside a module-scoped fixture
 TPU library, so only the worker that is handed this file does.
 """
 import dataclasses
+import math
 import os
 import sys
 
@@ -23,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import kv_page_write as kw
 from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas import rms_norm as rn
 from paddle_tpu.ops.pallas import varlen_attention as va
@@ -174,9 +176,9 @@ def test_hybrid_step_holds_its_kernels_and_copies_no_state_stack(
         one_chip, monkeypatch):
     """The hybrid model's mixed step at chip_smoke's sizes, compiled for
     the described chip: one state-update kernel a state-space layer, one
-    paged-attention kernel, XLA's grouped product for the expert layers,
-    and no copy of the float32 state stack (it is donated and updated
-    where it lies)."""
+    paged-attention kernel and one page-write kernel, XLA's grouped product
+    for the expert layers, and no copy of the float32 state stack or of the
+    page stack (both donated and updated where they lie)."""
     import chip_smoke
     from paddle_tpu.inference.serving import PagedServingConfig
     from paddle_tpu.models.nemotron_h import NemotronH, NemotronHSpec
@@ -206,12 +208,11 @@ def test_hybrid_step_holds_its_kernels_and_copies_no_state_stack(
     calls = chip_smoke.kernel_calls_in(hlo)
     assert calls["ssm_state_update"] == spec.count("M") == 5
     assert calls["paged_attention"] == spec.count("*") == 1
+    assert calls["kv_page_write"] == 1       # its pages: written in place
     assert hlo.count("ragged-dot") >= 2 * spec.count("E")
-    stack = "f32[5,%d,32,64,128]" % b1
-    assert stack in hlo
-    import re
-
-    assert not re.search(r"= " + re.escape(stack) + r"\S* copy\(", hlo)
+    # the row state and the pages: in the text, and never copied whole
+    assert chip_smoke.whole_array_copies_in(hlo, rows[0]) == 0
+    assert chip_smoke.whole_array_copies_in(hlo, cache) == 0
 
 
 PAGED_ENGINE = dict(vocab_size=512, hidden_size=512, num_layers=2,
@@ -300,8 +301,97 @@ def test_engine_steps_hold_the_paged_kernel_and_no_gathered_view(
     assert after["mixed_step"] < before["mixed_step"]
 
 
+# the page stacks of the benchmark's two serve cells: (layers, pages, KV
+# heads, rows, pages a row, token budget); block 32, head 128, bf16
+PAGE_STACKS = {
+    "mistral7b-serve-chat": (16, 1025, 8, 32, 40, 256),
+    "nemotron3s-serve-chat": (1, 12289, 2, 128, 96, 512),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PAGE_STACKS))
+def test_engine_steps_write_pages_in_place_and_copy_no_stack(
+        one_chip, monkeypatch, capsys, cell):
+    """`serving_step`, `serving_fresh_prefill`, `serving_spec_verify` and
+    the decode window over a page stack of the cell's shape (a narrow model
+    under it: the stack's shape is what XLA lays out), compiled for the
+    described chip with the stacks donated as the engine donates them: one
+    `kv_page_write` call a layer, both stacks aliased, and no `copy` whose
+    result has the stack's shape (the scatter's four were half of the chat
+    cell's step). The same programs with kernels off hold the scatter and
+    such copies, so the search bites. Prints `memory_analysis()` of each,
+    before (scatter) and after."""
+    import chip_smoke
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import (PagedCausalLM,
+                                              PagedServingConfig,
+                                              ServingEngine)
+
+    layers, pages, hkv, rows, max_blocks, budget = PAGE_STACKS[cell]
+    stack = jax.ShapeDtypeStruct((layers, pages, hkv, 32, 128),
+                                 jnp.bfloat16, sharding=one_chip)
+    shape = "bf16[%d,%d,%d,32,128]" % stack.shape[:3]
+    scfg = PagedServingConfig(
+        vocab_size=512, hidden_size=hkv * 128, num_layers=layers,
+        num_heads=hkv, num_kv_heads=hkv, ffn_size=512, block_size=32,
+        num_blocks=9, max_batch=rows, max_blocks_per_seq=max_blocks,
+        token_budget=budget, dtype="bfloat16")
+
+    def shp(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def over_the_stack(args):
+        return (*args[:8], stack, stack, *args[10:])
+
+    report = {}
+    for kernels in (False, True):
+        monkeypatch.setenv("PT_USE_PALLAS", "1" if kernels else "0")
+        paddle.seed(0)
+        model = PagedCausalLM(scfg)       # a model each: traced once
+        model.eval()
+        eng = ServingEngine.from_model(model, scfg, seed=0)
+        step = chip_smoke.abstract_step_args(eng, scfg, shp)
+        programs = {
+            "serving_step": (eng._compiled, step),
+            "serving_fresh_prefill": (eng._compiled_fresh, step),
+            "serving_spec_verify": (
+                eng._compiled_verify,
+                chip_smoke.abstract_step_args(eng, scfg, shp, tokens=16)),
+            "decode_window": (
+                eng._decode_window_fn(4, 8, "greedy"),
+                chip_smoke.abstract_window_args(eng, scfg, 4, 8, shp)),
+        }
+        if not kernels:                   # one program shows the "before"
+            programs = {"serving_step": programs["serving_step"]}
+        for name, (fn, args) in programs.items():
+            compiled = fn.lower(*over_the_stack(args)).compile()
+            text = compiled.as_text()
+            copies = chip_smoke.whole_array_copies_in(text, stack)
+            calls = chip_smoke.kernel_calls_in(text)
+            m = compiled.memory_analysis()
+            report[name, kernels] = (copies, calls["kv_page_write"],
+                                     m.temp_size_in_bytes,
+                                     m.alias_size_in_bytes)
+            if kernels:
+                assert copies == 0, (name, copies)
+                assert calls["kv_page_write"] == layers, (name, calls)
+                assert (calls["paged_attention"] == layers) \
+                    == (name != "serving_fresh_prefill"), (name, calls)
+                # both stacks are the program's to write: aliased whole
+                assert m.alias_size_in_bytes >= 2 * math.prod(stack.shape) * 2
+            else:
+                assert calls["total"] == 0
+                assert copies >= 2, (name, copies)
+    with capsys.disabled():
+        for (name, kernels), (copies, n, temp, alias) in report.items():
+            print(f"\n{cell} {name} kernels={kernels}: {copies} copies of "
+                  f"{shape}, kv_page_write x{n}, temporaries {temp} B, "
+                  f"aliased {alias} B", end="")
+    assert report["serving_step", True][2] < report["serving_step", False][2]
+
+
 def _kernel_programs(one_chip):
-    """{kernel name: (function, abstract arguments)}: each of the nine
+    """{kernel name: (function, abstract arguments)}: each of the ten
     `pl.pallas_call` sites, reached as the program reaches it."""
     q = jax.ShapeDtypeStruct((1, 4, 512, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -338,13 +428,20 @@ def _kernel_programs(one_chip):
              spec((1, 9, 2, 32, 128)), spec((1, 9, 2, 32, 128)),
              spec((3, 4), jnp.int32), spec((3,), jnp.int32),
              spec((4,), jnp.int32))),
+        "kv_page_write": (
+            lambda *a: kw.kv_page_write(*a, layer_idx=0,
+                                        last_row_is_padding=True),
+            (spec((1, 9, 2, 32, 128)), spec((1, 9, 2, 32, 128)),
+             spec((64, 2, 128)), spec((64, 2, 128)),
+             spec((3, 4), jnp.int32), spec((3,), jnp.int32),
+             spec((4,), jnp.int32))),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "flash_attention_fwd", "flash_attention_dkv", "flash_attention_dq",
     "varlen_attention_fwd", "varlen_attention_dkv", "varlen_attention_dq",
-    "rms_norm", "rms_norm_noweight", "paged_attention"])
+    "rms_norm", "rms_norm_noweight", "paged_attention", "kv_page_write"])
 def test_kernel_names_reach_the_chip_program(one_chip, monkeypatch, kernel):
     """Every `pl.pallas_call` names its kernel: the lowered program's
     `kernel_name`, and in the COMPILED program both the custom call's

@@ -39,7 +39,9 @@ from paddle_tpu.distributed.topology import build_mesh  # noqa: E402
 from test_tpu_compile import abstract_trainer  # noqa: E402
 
 
-def report(name, lowered, t0):
+def report(name, lowered, t0, stack=None):
+    """`stack`: the page (or state) stack the program was given donated;
+    its whole-array copies are counted (there must be none)."""
     text = lowered.as_text()
     compiled = lowered.compile()
     m = compiled.memory_analysis()
@@ -56,6 +58,9 @@ def report(name, lowered, t0):
            "compiled_kernel_calls": chip_smoke.kernel_calls_in(hlo),
            "collectives": {k: v for k, v in collectives.items() if v},
            "compile_s": round(time.perf_counter() - t0, 1)}
+    if stack is not None:
+        out["stack"] = [str(stack.dtype), *stack.shape]
+        out["copies_of_it"] = chip_smoke.whole_array_copies_in(hlo, stack)
     print(json.dumps(out), flush=True)
 
 
@@ -71,9 +76,11 @@ def compile_train(model, cfg, devices, layout, options):
 def compile_serve(cfg, device):
     """The engine's three step programs at the smoke's serving config: the
     fresh-prefill step (varlen flash kernel), the mixed prefill/decode
-    step (the paged-attention kernel; before it, the same step with the
-    gathered reference in the kernel's place, for `memory_analysis()`
-    before and after) and one decode window."""
+    step (the paged-attention and page-write kernels; before it, the same
+    step with the gathered reference and the scatter in the kernels'
+    place, for `memory_analysis()` and the copies of the page stack before
+    and after) and one decode window. Every program takes the page stacks
+    donated, as the engine hands them over."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import (PagedCausalLM,
                                               PagedServingConfig,
@@ -94,14 +101,15 @@ def compile_serve(cfg, device):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
 
     eng = engine()
-    use_kernel = paged_attention.use_kernel
+    use_kernel = paged_attention.use_kernel    # one question, both kernels
     paged_attention.use_kernel = lambda *a, **k: False
     try:
         t0 = time.perf_counter()
         report(f"serve mixed step L{scfg.num_layers} "
                f"T{scfg.token_budget}, gathered reference",
                eng._compiled.lower(
-                   *chip_smoke.abstract_step_args(eng, scfg, shp)), t0)
+                   *chip_smoke.abstract_step_args(eng, scfg, shp)), t0,
+               stack=eng._kc)
     finally:
         paged_attention.use_kernel = use_kernel
     eng = engine()
@@ -109,17 +117,17 @@ def compile_serve(cfg, device):
     step_args = chip_smoke.abstract_step_args(eng, scfg, shp)
     t0 = time.perf_counter()
     report(f"serve fresh-prefill L{scfg.num_layers} T{T}",
-           eng._compiled_fresh.lower(*step_args), t0)
+           eng._compiled_fresh.lower(*step_args), t0, stack=eng._kc)
     t0 = time.perf_counter()
     report(f"serve mixed step L{scfg.num_layers} T{T}",
-           eng._compiled.lower(*step_args), t0)
+           eng._compiled.lower(*step_args), t0, stack=eng._kc)
     rows = min(_next_pow2(len(cfg.prompt_lens)), scfg.max_batch)
     n = 8
     t0 = time.perf_counter()
     window = eng._decode_window_fn(rows, n, "greedy")
     report(f"serve decode window L{scfg.num_layers} rows{rows} n{n}",
            window.lower(*chip_smoke.abstract_window_args(
-               eng, scfg, rows, n, shp)), t0)
+               eng, scfg, rows, n, shp)), t0, stack=eng._kc)
 
 
 def compile_serve_hybrid(device, config_file=os.path.join(
@@ -129,7 +137,9 @@ def compile_serve_hybrid(device, config_file=os.path.join(
     paged-attention layer, 64 of 512 experts held), from abstract
     parameters: what it needs on a device, its kernels, and that no
     operation COPIES the float32 state stack (it is donated and updated
-    where it lies: a `copy` of `f32[5,129,128,64,128]` is 2.7e9 B a step)."""
+    where it lies: a `copy` of `f32[5,129,128,64,128]` is 2.7e9 B a step)
+    or the page stack (`bf16[1,12289,2,32,128]`, written where it lies by
+    `kv_page_write`; the scatter cost four copies of it a step)."""
     from paddle_tpu.models.nemotron_h import NemotronH, NemotronHSpec
 
     with open(config_file) as f:
@@ -158,12 +168,11 @@ def compile_serve_hybrid(device, config_file=os.path.join(
         shp((b1 + 1,)), shp((b1, s["max_blocks_per_seq"])), cache, cache,
         *rows, shp((b1,)))
     report(f"serve hybrid mixed step {spec.hybrid_override_pattern} T{t} "
-           f"rows{b1}", lowered, t0)
-    stack = "f32[%s]" % ",".join(map(str, rows[0].shape))
-    copies = len(re.findall(r"= " + re.escape(stack) + r"\S* copy\(",
-                            lowered.compile().as_text()))
-    print(json.dumps({"state_stack": stack, "copies_of_it": copies}),
-          flush=True)
+           f"rows{b1}", lowered, t0, stack=cache)
+    print(json.dumps({
+        "state_stack": [str(rows[0].dtype), *rows[0].shape],
+        "copies_of_it": chip_smoke.whole_array_copies_in(
+            lowered.compile().as_text(), rows[0])}), flush=True)
 
 
 def main():

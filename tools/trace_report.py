@@ -95,7 +95,7 @@ KNOWN_METRICS = (
     "serving/step_pad_tokens", "serving/step_prefill_tokens",
     # of serving/steps, those whose program holds the paged-attention
     # Pallas kernel (0 off the chip and on exported artifacts)
-    "serving/paged_kernel_steps",
+    "serving/paged_kernel_steps", "serving/kv_inplace_steps",
     # state-space layers over a row slot (inference/layer_states.py):
     # rows through the one-token state update, rows through the chunked
     # scan, rows that started from a zeroed slot
