@@ -76,9 +76,9 @@ def estimate_memory_bytes(cfg: TunerCfg, n_params: int, hidden: int,
     Calibrated against XLA memory_analysis of the AdamW train step of
     Llama-2-13B-dimension blocks (hidden 5120 / 40 heads / seq 4096,
     bf16, flash attention) on a v5e chip across micro-batch 1-4, layer
-    counts 1-2, and remat on/off — all points within ~13% of measured
-    (argument + temp bytes); see tools/validate_memory_model.py and the
-    llama13b_block bench row."""
+    counts 1-2, and remat on/off (argument + temp bytes); the bar is
+    tests/test_auto_tuner_model.py::test_memory_model_within_15pct_on_chip,
+    run by tools/validate_memory_model.py."""
     shard_p = cfg.mp * cfg.pp * (cfg.sharding_degree
                                  if cfg.sharding_stage >= 3 else 1)
     shard_s = cfg.mp * cfg.pp * cfg.sharding_degree

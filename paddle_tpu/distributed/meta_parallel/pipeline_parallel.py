@@ -358,8 +358,13 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x, n_micro: int,
     giving XLA's scheduler a real window to run the ICI hop behind the
     MXU instead of serializing compute -> send.  Requires a per-sample
     stage_fn (true for transformer blocks) and an even micro-batch;
-    otherwise the call falls back to the unsplit schedule.  Numerics
-    are identical either way (the halves are independent rows).
+    otherwise the call falls back to the unsplit schedule.  Both
+    schedules send the same rows through the same products (the halves
+    are independent rows), so the results agree bit for bit wherever
+    the backend's product does not change with the row count; a half
+    of a single row may meet another product (matrix-vector for
+    matrix-matrix on the CPU) and then differs in the last place
+    (tests/test_overlap.py).
     """
     p = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)

@@ -75,10 +75,10 @@ def held_expert_product(x, idx, w, w1, w2, held, valid=None, act=relu2,
     `window` rows at a time, as many windows as hold a pair (a while loop:
     `T * K` rows are the bound, an eighth of them the rule). XLA's grouped
     product works in row tiles of min(512, rows) and spends a whole tile
-    on every expert that has a row in it: over all `T * K` rows at once the
-    two products took 4.6 ms for 1,130 pairs of 64 experts, in windows of
-    128 rows 2.8 ms, where reading the weights once is 1.1 ms (TPU v5e,
-    PERF.md section 6, PR 29)."""
+    on every expert that has a row in it, so over all `T * K` rows at once
+    the two products cost several times what reading the weights once
+    does, and in windows of 128 rows less (the times are in PERF.md
+    section 6, PR 29)."""
     t, k = idx.shape
     order, sizes, n_held = held_pairs(idx, held, valid)
     token = order // k

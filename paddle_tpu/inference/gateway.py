@@ -63,10 +63,10 @@ id) and the gateway's ``salt_seed`` — device-side salts depend only on
 across placements, requeues, drains, and load levels.  The ``overload``
 chaos pattern (``PT_FAULT_PLAN="overload@admit%1.0:x=4"``, consulted
 once per arriving request) turns each arrival into ``x`` by injecting
-synthetic best-effort clones under the ``_storm`` tenant — the 4x
-storm bench row (bench.py ``gateway_storm``) proves completed streams
-stay bitwise-identical to an unloaded run while interactive p95 TTFT
-holds.
+synthetic best-effort clones under the ``_storm`` tenant; under the 4x
+storm completed streams stay bitwise-identical to an unloaded run and
+no interactive request is lost
+(``tests/test_slo_engine.py::test_slo_engine_under_gateway_storm``).
 """
 from __future__ import annotations
 

@@ -160,16 +160,11 @@ class PagedServingConfig:
     """Engine/model dims for the paged-KV serving path.
 
     ``cache_quant="int8"`` stores KV pages as int8 with per-(token,
-    head) dynamic scales. The tradeoff, measured on the 0.886B GQA
-    engine (round 5, v5e, bs 16): **capacity up, latency down** — cache
-    bytes halve, so the same HBM holds ~2x the pages (longer contexts /
-    more sequences before preemption) and decode streams half the cache
-    traffic; but the quantize-on-append + dequantize-on-read VPU work
-    puts the decode step at **6.58 ms vs 5.37 ms bf16** at bs 16.
-    Weight streaming (~2.3 ms floor), not cache reads, bounds this
-    engine's decode, so halving cache bytes buys no step time back.
-    Pick int8 when KV capacity is the binding constraint (long contexts,
-    big batches); stay bf16 when step latency is. On a TPU the mixed,
+    head) dynamic scales: cache bytes halve, so the same HBM holds about
+    twice the pages (longer contexts, more sequences before preemption),
+    at the price of quantize-on-append and dequantize-on-read work in
+    every step. No benchmark cell runs an int8 cache, so its step time
+    against bf16 is not measured. On a TPU the mixed,
     verify and decode-window steps of a bf16/float32 cache attend through
     the paged-attention Pallas kernel (ops/pallas/paged_attention.py);
     an int8 cache takes the gathered jnp reference instead (the kernel
@@ -228,18 +223,6 @@ class PagedServingConfig:
         # to return first (resolve_backend_device).
         self.backend = backend
         self.max_seq = max_blocks_per_seq * block_size
-
-    @classmethod
-    def llama_1b(cls, **over):
-        """Flagship serving dims: the ~0.9B llama config bench.py trains
-        (hidden 2048, 16 layers), GQA 16q/8kv, bf16 cache."""
-        base = dict(vocab_size=32000, hidden_size=2048, num_layers=16,
-                    num_heads=16, num_kv_heads=8, ffn_size=5632,
-                    block_size=32, num_blocks=64, max_batch=8,
-                    max_blocks_per_seq=6, token_budget=256,
-                    dtype="bfloat16")
-        base.update(over)
-        return cls(**base)
 
 
 class SamplingParams:
@@ -506,7 +489,7 @@ class PagedCausalLM(Layer):
                     if li + 1 < cfg.num_layers else None
             else:
                 # no-prefetch baseline: dequant issued AT use — no
-                # overlap window (what the micro-bench prices against)
+                # overlap window (what measure_stream_win compares with)
                 cur_w = ws.dequant_layer(li)
             with _scopes.scope("attention"):
                 h = self.ln1[li](x)
@@ -861,11 +844,11 @@ class ServingEngine:
         ``weight_stream`` streams the decoder Linear stacks as
         per-channel int8 (inference/weight_stream.py), dequantized on use
         with the NEXT layer's group issued before the current layer's
-        compute — double-buffered, directly attacking the
-        weight-streaming-bound decode step (the PR 2 int8-KV finding).
+        compute — double-buffered, so that the next layer's weight read
+        can overlap matmuls it does not feed.
         ``"int8"`` prefetches; ``"int8-noprefetch"`` dequantizes at use
-        (the honest baseline the micro-bench prices the overlap
-        against); ``"int4"`` packs two 4-bit codes per byte with
+        (the baseline ``weight_stream.measure_stream_win`` compares the
+        overlap with); ``"int4"`` packs two 4-bit codes per byte with
         per-(input-group, out-channel) scales — quarter the streamed
         bytes of bf16 at a larger quant error.  Generations match an
         engine over the dequantized weights bitwise; vs the

@@ -1,12 +1,10 @@
 """int8 double-buffered weight streaming for the paged serving decoder.
 
-The PR 2 int8-KV finding: this engine's decode step is
-WEIGHT-streaming-bound (~2.3 ms floor at the flagship dims) — halving
-KV-cache bytes bought zero step time back because the per-step HBM
-traffic is dominated by reading every decoder weight once.  This module
-attacks that floor directly, the way the reference's weight-only-quant
-serving kernels (paddle/phi/kernels/fusion — weight_only_linear) do on
-GPU:
+A one-token decode step reads every decoder weight once, so its HBM
+traffic is dominated by the weights, not by the KV cache.  This module
+cuts those bytes, the way the reference's weight-only-quant serving
+kernels (paddle/phi/kernels/fusion — weight_only_linear) do on GPU (no
+benchmark cell streams weights, so the gain is not measured):
 
 1. **Per-channel int8 weights** — each decoder Linear stack weight
    (qkv / proj / gate_up / down) is stored as int8 with one f32 scale

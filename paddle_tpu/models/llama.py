@@ -631,7 +631,9 @@ def loss_fn_pipelined(params, batch, config: LlamaConfig, mesh,
     Requires num_hidden_layers % pp == 0.  ``overlap_sends=True``
     half-splits each tick's micro-batch so the first half's ICI hop
     overlaps the second half's block compute (latency-hidden pipeline
-    sends; numerics identical — rows are independent).
+    sends; the same rows through the same products, bit for bit where
+    the backend's product does not change with the row count — see
+    ``spmd_pipeline``).
     """
     from ..distributed.meta_parallel.pipeline_parallel import spmd_pipeline
 

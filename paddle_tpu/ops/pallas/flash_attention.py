@@ -40,8 +40,9 @@ from jax.experimental.pallas import tpu as pltpu
 from . import interpret as _interpret
 from . import kernels_enabled, note_reference_dispatch
 
-# 512 blocks measured ~2x over 128 blocks on v5e (bigger MXU tiles amortize
-# the VPU online-softmax work); the bh grid axis is parallel, q/kv arbitrary.
+# 512 blocks: bigger MXU tiles amortize the VPU online-softmax work (the
+# kernel's roofline share at this size is `train.flash_attention_roofline`
+# in PERF.md); the bh grid axis is parallel, q/kv arbitrary.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 

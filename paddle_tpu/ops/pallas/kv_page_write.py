@@ -9,8 +9,8 @@ of page `block_tables[row, pos // block]`. As an XLA scatter
 head axis, so XLA lays the scatter's operand out with block and head
 swapped; the jit boundary and the paged-attention kernel want the plain
 layout, and the whole K stack and the whole V stack were copied once in and
-once out, every step (four copies of 1.07e9 B at 16 layers x 1025 pages,
-12.8 ms of a 26.9 ms step on a v5e).
+once out, every step (four copies of 1.07e9 B at 16 layers x 1025 pages;
+what they cost is in PERF.md section 6, PR 30).
 
 Layout, as the engine keeps it: stacks `[L, num_blocks, HKV, block, D]`
 (`layer_idx` static to the caller, a run-time scalar to the kernel) or

@@ -152,7 +152,7 @@ class use_context:
 # -- span ring ------------------------------------------------------------
 
 # room for a minute of a serving engine's steps with their four phase
-# children (a 27 ms step writes 180 spans a second; the benchmark's span
+# children (five spans a step, a few tens of steps a second; the benchmark's span
 # readers look for the traced seconds' spans when the run has ended)
 _RING_CAP = int(os.environ.get("PT_TRACE_RING", "32768") or 32768)
 _ring = deque(maxlen=max(64, _RING_CAP))

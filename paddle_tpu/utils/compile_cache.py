@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache, placed from outside.
 
 Entry points call :func:`enable_compile_cache` before their first
-compile — ``chip_smoke.py``, ``bench.py``, ``replica_host``'s ``main``.
+compile — ``chip_smoke.py`` and ``replica_host``'s ``main`` (the benchmark
+has its own, ``benchmark/harness.py:setup_compile_cache``).
 Importing ``paddle_tpu`` does not: a library that sets process-wide JAX
 configuration at import takes the choice away from its caller.
 

@@ -1,8 +1,7 @@
 """Auto-tuner memory/cost model validation (VERDICT r2 #5).
 
 The quantitative 15% bar is asserted against XLA memory_analysis on the
-real chip (tools/validate_memory_model.py, gated to TPU; the llama13b
-bench row records the ratio every round). CI validates the model's
+real chip (tools/validate_memory_model.py, gated to TPU). CI validates the model's
 structure hardware-free: scaling directions, sharding reductions, and
 that the v5p-128 Llama-2-13B target admits feasible TP x PP x sharding
 configs while clearly-infeasible ones are pruned.

@@ -493,6 +493,7 @@ def test_host_kill_fells_cohosted_replicas_zero_loss(srv_model):
     out = {h: out[h] for h in hs}
 
     assert out == ref
+    assert all(len(toks) == 6 for toks in out.values())  # none cut short
     assert not router.timed_out()
     # both h1 slots burned a restart and came back on h0
     assert sup.restarts[2] == 1 and sup.restarts[3] == 1

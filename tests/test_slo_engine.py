@@ -91,6 +91,19 @@ def _fleet(model, gcfg=None, n=2, **over):
         classes=_classes())), router
 
 
+def _unloaded_reference(model, prompt, stream_key, max_new=6):
+    """One request alone on a fresh engine under the salt identity the
+    gateway pins (stream_key, salt_seed 0)."""
+    eng = _fresh_engine(model, seed=99)
+    rid = eng.add_request(list(prompt), max_new_tokens=max_new,
+                          sampling=SP)
+    eng._requests[rid].salt_rid = stream_key
+    eng._requests[rid].salt_seed = 0
+    while eng.pending():
+        eng.step()
+    return eng._requests[rid].generated
+
+
 def _tl(clock, registry=None, **kw):
     return Timeline(registry=registry or _metrics.MetricsRegistry(),
                     clock=clock, **kw)
@@ -582,15 +595,16 @@ def test_slo_engine_under_gateway_storm(model, tmp_path):
     assert tracker.evaluate() == []
 
     rng = np.random.RandomState(13)
+    injected0 = _metrics.counter("gateway/storm_injected").value
     faults.arm("overload@admit%1.0:x=4")
-    for i in range(6):
-        gw.submit(list(rng.randint(1, 90, 12)), max_new_tokens=6,
-                  sampling=SP, tenant="alpha", slo="interactive",
-                  stream_key=1000 + i)
-    for i in range(4):
-        gw.submit(list(rng.randint(1, 90, 12)), max_new_tokens=6,
-                  sampling=SP, tenant="beta", slo="batch",
-                  stream_key=2000 + i)
+    real = {}                  # ticket -> (prompt, stream_key, class)
+    for tenant, slo, key0, n in (("alpha", "interactive", 1000, 6),
+                                 ("beta", "batch", 2000, 4)):
+        for key in range(key0, key0 + n):
+            p = list(rng.randint(1, 90, 12))
+            t = gw.submit(p, max_new_tokens=6, sampling=SP, tenant=tenant,
+                          slo=slo, stream_key=key)
+            real[t] = (p, key, slo)
     advice_during = None
     for _ in range(4000):
         gw.step()
@@ -602,6 +616,21 @@ def test_slo_engine_under_gateway_storm(model, tmp_path):
             break
     faults.disarm()
     assert gw.brownout.max_level >= 1         # the storm engaged
+    # each of the 10 arrivals became 4; no interactive request is lost
+    # or cut short, and the ladder may shorten a batch stream but never
+    # alter one: bitwise against an unloaded single engine under the
+    # same pinned identity
+    assert _metrics.counter("gateway/storm_injected").value \
+        == injected0 + 3 * len(real)
+    out = gw.results()
+    for t, (p, key, slo) in real.items():
+        ref = _unloaded_reference(model, p, key)
+        toks = out.get(t) or []
+        if slo == "interactive":
+            assert toks == ref and len(toks) == 6
+        else:
+            assert toks == ref[:len(toks)]
+    assert not [t for t in gw.timed_out() if t in real]
     storm_alerts = len(tracker.alerts)
     assert storm_alerts >= 1                  # fast-window burn paged
     assert any(a.tenant == "_storm" for a in tracker.alerts)

@@ -109,9 +109,8 @@ def main():
 
 
 def _bench_eager_dispatch():
-    """Steady-state eager dispatch through the per-signature jit cache
-    (regression gate for VERDICT r2 #1 — uncached this was 5,447 µs/iter
-    on a v5e for grad-recorded matmul(1024²)+add)."""
+    """Steady-state eager dispatch through the per-signature jit cache:
+    grad-recorded matmul(1024²)+add, forward and forward+backward."""
     import paddle_tpu as paddle
 
     rng = np.random.RandomState(0)
@@ -143,28 +142,6 @@ def _bench_eager_dispatch():
                 f()
             best = min(best, (time.perf_counter() - t0) / n)
         out[name] = best
-
-    # host-path rows: the 100/300 us bars in check_op_bench.py gate
-    # these
-    import bench as _bench
-
-    def measure_us(f):
-        for _ in range(6):
-            jax.device_get(f())
-        n = 200
-        best = float("inf")
-        for _ in range(3):
-            jax.device_get(f())
-            t0 = time.perf_counter()
-            for _ in range(n):
-                f()
-            best = min(best, (time.perf_counter() - t0) / n)
-        return best * 1e6
-
-    host = _bench.host_dispatch_bench(measure_us)
-    if "error" not in host:
-        out["eager:host_fwd"] = host["matmul_add_fwd_us"] / 1e6
-        out["eager:host_fwd_bwd"] = host["matmul_add_fwd_bwd_us"] / 1e6
     return out
 
 

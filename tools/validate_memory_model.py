@@ -26,8 +26,7 @@ import jax.numpy as jnp
 
 def build_block_step(hidden, inter, heads, seq, batch, layers, remat):
     """The AdamW train step over `layers` stacked decoder blocks at the
-    given dims. Returns (step_fn, blocks, opt_state, x, n_block_params) —
-    shared by this validator and bench.py's llama13b_block row."""
+    given dims. Returns (step_fn, blocks, opt_state, x, n_block_params)."""
     from paddle_tpu.models import llama
     from paddle_tpu.models.llama import _block
 
