@@ -56,6 +56,10 @@ def migrate_request(engine: ServingEngine, rid: int, transport,
     ``engine`` to the decode worker at transport rank ``dst``.  The
     source request finishes locally (pages released); ownership moves to
     the receiver."""
+    # what ships is the settled request: the step in flight is fetched
+    # first (a dead engine drops it, and the token is sampled again where
+    # the request lands); the engine's next step() returns what that emits
+    engine.settle(hold=True)
     r = engine._requests[rid]
     if r.done:
         raise ValueError(f"request {rid} already finished")
@@ -230,7 +234,10 @@ class PrefillWorker:
         for _ in range(max_steps):
             if not self._live:
                 break
+            # the hand-off is at the first token: this worker fetches
+            # each step at once and never decodes ahead of it
             self.engine.step()
+            self.engine.settle()
             for rid in list(self._live):
                 r = self.engine._requests[rid]
                 if r.done:

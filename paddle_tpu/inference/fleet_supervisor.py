@@ -350,6 +350,11 @@ class FleetSupervisor:
         admission metadata) is honest there."""
         use_migrate = self.cfg.migrate if migrate is None else migrate
         src = self.router.replicas[idx].engine
+        # what moves is each request's settled state: a live engine
+        # fetches its step in flight (a dead one drops it), and the
+        # tokens that emits reach their streams through the router,
+        # whose handles still point here
+        self.router.carry(idx, src.settle())
         targets = self.router._ordered(
             exclude=idx,
             prefer_off_host=self.router.replicas[idx].host_id)

@@ -492,6 +492,19 @@ class RemoteEngine:
         rsp = self._rpc({"op": "step"}, site="step")
         return self._apply_step(rsp)
 
+    def settle(self):
+        """Have the child fetch its step in flight (ServingEngine.settle)
+        and take what that emits into the mirror, as from a step. A dead
+        or signalled child has no end to ask: its token in flight goes
+        with it, and is sampled again where the request lands."""
+        if self.dead or self._signalled or not self.pending():
+            return []
+        try:
+            return self._apply_step(self._rpc({"op": "settle"},
+                                              site="settle"))
+        except EngineDeadError:
+            return []
+
     def _deliver(self, act):
         kind = getattr(act, "kind", None)
         if kind == "sigkill":
@@ -517,8 +530,8 @@ class RemoteEngine:
                 continue
             r = self._requests[rid]
             r.generated.append(int(tok))
-            # the child is at this stream's decode tip at every step
-            # boundary: everything but the newest token is cached
+            # the child's settled state is at this stream's decode tip
+            # at every step boundary: all but the newest token is cached
             r.cached = r.length - 1
             out.append((rid, int(tok)))
         for crid in rsp.get("done", ()):

@@ -223,8 +223,17 @@ def serve(tp, engine, collector=None) -> int:
                     deadline_s=req.get("deadline_s"),
                     tenant=req.get("tenant"))
                 _reply({"ok": 1, "rid": rid, **_status(engine)})
-            elif op == "step":
-                produced = engine.step() if engine.pending() else []
+            elif op in ("step", "settle"):
+                # the engine keeps its step in flight across RPCs: the
+                # parent's mirror follows the SETTLED state (`_status`
+                # and the tokens of this reply), one step behind the
+                # chip as an in-process caller is. `settle` is the
+                # parent about to move requests off this engine; with
+                # nothing pending it hands over what a settle between
+                # steps held (a weight commit, a migration)
+                produced = engine.step() \
+                    if op == "step" and engine.pending() \
+                    else engine.settle()
                 ev, evicted[:] = list(evicted), []
                 _reply({"ok": 1,
                         "produced": [[int(rid), int(t)]

@@ -220,6 +220,8 @@ class ReplicaRouter:
         self.max_requeues = max(int(max_requeues), 0)
         self._handles: Dict[int, Tuple[int, int]] = {}   # h -> (idx, rid)
         self._by_engine: Dict[Tuple[int, int], int] = {}
+        # tokens emitted outside step_all (carry), by handle
+        self._carried: Dict[int, List[int]] = {}
         # handles that hopped replicas (requeue/drain): the gateway
         # reason-codes their completion "drained", not "completed"
         self.moved_handles: set = set()
@@ -446,7 +448,7 @@ class ReplicaRouter:
         reported through ``failure_hook``."""
         from ..distributed.resilience.errors import EngineDeadError
 
-        produced: Dict[int, List[int]] = {}
+        produced, self._carried = self._carried, {}
         for idx, rep in enumerate(self._snapshot()):
             if rep.retired:
                 continue
@@ -469,11 +471,13 @@ class ReplicaRouter:
                 if self.failure_hook is not None:
                     self.failure_hook(idx)
                 continue
-            if getattr(rep.engine, "dead", False) \
-                    or not rep.engine.pending():
+            if getattr(rep.engine, "dead", False):
                 continue
             try:
-                stepped = rep.engine.step()
+                # nothing pending: it may still hold what a settle
+                # between steps emitted (a weight commit, a migration)
+                stepped = rep.engine.step() if rep.engine.pending() \
+                    else rep.engine.settle()
             except EngineDeadError:
                 rep.mark_unhealthy()
                 _m_failures.inc()
@@ -486,6 +490,16 @@ class ReplicaRouter:
                     produced.setdefault(h, []).append(tok)
         return produced
 
+    def carry(self, idx: int, tokens) -> None:
+        """Take the (rid, token) pairs replica ``idx``'s engine emitted
+        outside ``step_all`` (a drain settled its step in flight): the
+        next ``step_all`` returns them first, under the handles those
+        rids have NOW, so call it before a handle moves."""
+        for rid, tok in tokens:
+            h = self._by_engine.get((idx, rid))
+            if h is not None:
+                self._carried.setdefault(h, []).append(tok)
+
     def _live_pending(self) -> bool:
         return any(rep.engine.pending() for rep in self._snapshot()
                    if not rep.retired
@@ -496,6 +510,10 @@ class ReplicaRouter:
             if not self._live_pending():
                 break
             self.step_all()
+        for idx, rep in enumerate(self._snapshot()):
+            # max_steps ran out with a step in flight: results() reads
+            # the settled tokens
+            self.carry(idx, rep.engine.settle())
         return self.results()
 
     def results(self) -> Dict[int, List[int]]:

@@ -66,7 +66,8 @@ class _EngineMetrics:
                  "spec_drafted", "spec_accepted", "spec_accept_rate",
                  "spec_tokens_per_step", "fused_regions",
                  "weight_version", "weight_swaps", "weight_rollbacks",
-                 "ssm_decode", "ssm_chunk", "ssm_resets", "step_counts")
+                 "ssm_decode", "ssm_chunk", "ssm_resets", "step_counts",
+                 "lookahead", "settle_forced")
 
     def __init__(self, reg, step_counters=()):
         self.ttft = reg.histogram("serving/ttft_ms")
@@ -87,6 +88,12 @@ class _EngineMetrics:
         self.step_tokens = reg.counter("serving/step_tokens")
         self.step_pad = reg.counter("serving/step_pad_tokens")
         self.step_prefill = reg.counter("serving/step_prefill_tokens")
+        # of serving/steps, those dispatched while the step before them
+        # was still unsettled (the chip had its next step queued), and
+        # the settles something other than the next step asked for
+        # (ServingEngine.settle)
+        self.lookahead = reg.counter("serving/lookahead_steps")
+        self.settle_forced = reg.counter("serving/settle_forced")
         self.tokens = reg.counter("serving/tokens_generated")
         self.requests = reg.counter("serving/requests")
         self.preempt = reg.counter("serving/preemptions")
@@ -343,6 +350,18 @@ _greedy_tokens_dev = _sampler(
     lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
 _sample_tokens_dev = _sampler("serving_sample", _sample_core)
 _sample_topk_dev = _sampler("serving_sample_topk", _sample_topk_core)
+
+
+def serving_feed_tokens(prev_sampled, src, host_tokens):
+    """A step's packed tokens where some are still on the device: position
+    p takes the unsettled step's sampled token of row `src[p]`, or the
+    host's token where `src[p]` is -1. The engine's own program, so the
+    model's step programs see a token vector as they always did."""
+    return jnp.where(src >= 0, prev_sampled[jnp.maximum(src, 0)],
+                     host_tokens)
+
+
+_feed_tokens_dev = jax.jit(serving_feed_tokens)
 
 
 def sample_logits(logits, sampling: SamplingParams, salt: int) -> int:
@@ -602,7 +621,8 @@ class _Request:
                  "timed_out",
                  "shared_keys", "prefix_registered", "salt_rid",
                  "salt_seed", "trace", "sched_t0", "requeues", "tenant",
-                 "spec_observed", "weight_version", "slot")
+                 "spec_observed", "weight_version", "slot", "ahead",
+                 "ahead_row")
 
     def __init__(self, rid, prompt, max_new, sampling, eos_token_id,
                  deadline_s=None, arrival_t=None):
@@ -615,6 +635,12 @@ class _Request:
         # the row slot of a model whose layers keep a fixed state a
         # request (layer_states.py); None until a step first schedules it
         self.slot = None
+        # the engine's one unsettled step (ServingEngine.settle): how many
+        # of this request's tokens it holds, and the row whose sampled
+        # token it will give the request (-1: none). `cached` and
+        # `generated` are the SETTLED ones; the scheduler adds these
+        self.ahead = 0
+        self.ahead_row = -1
         self.done = False
         self.sampling = sampling or GREEDY
         self.eos_token_id = eos_token_id
@@ -666,6 +692,18 @@ class _Request:
         return len(self.prompt) + len(self.generated)
 
 
+class _Flight:
+    """The one step an engine has dispatched and not settled: its rows
+    and their chunks, which rows reached their tip, the sampler's output
+    and the model's step counts, both still on the device."""
+
+    __slots__ = ("rows", "tip", "sampled", "counts")
+
+    def __init__(self, rows, tip, sampled, counts):
+        self.rows, self.tip = rows, tip
+        self.sampled, self.counts = sampled, counts
+
+
 class ServingEngine:
     """Continuous-batching scheduler over a PagedCausalLM step function.
 
@@ -680,6 +718,25 @@ class ServingEngine:
     Requests may be added between steps (continuous batching); prompts
     longer than the token budget prefill in chunks; finished requests
     release their cache pages.
+
+    One step is kept in flight. `step()` call k schedules, packs and
+    dispatches step k and only then fetches and emits step k-1, which ran
+    on the chip meanwhile: it returns the tokens that became known in
+    this call, those of the step the PREVIOUS call dispatched. A decode
+    row of step k takes its input token from step k-1's sampler on the
+    device. `pending()` stays true while a request has a token in
+    flight, and a `step()` that can schedule nothing settles and returns,
+    so `while engine.pending(): engine.step()` needs no change. Between
+    calls a request's `generated`, `cached`, `done` and `pages` are the
+    SETTLED ones; `settle()` fetches the step in flight at once, and
+    everything that reads or moves a request's tokens, pages or state
+    outside the plain step (`decode_run`, a drafter, pre-emption, a
+    deadline eviction, `probe_logits`, a weight commit or rollback, a
+    prefix-cache snapshot, a migration) settles first; the tokens that
+    emits are held for the next `step()` or `decode_run()` to return
+    first, so what those two return, taken together, is every stream
+    whole. A stop by `eos_token_id` is seen one step late: the token the
+    next step computed for that request is dropped, never emitted.
     """
 
     def __init__(self, path_prefix: str = None,
@@ -770,6 +827,12 @@ class ServingEngine:
         # the step's own counts (states.counters), still on the device
         # until the step's sampled tokens are fetched
         self._step_counts = None
+        # the step dispatched and not yet settled (_Flight), or None
+        self._flight = None
+        # tokens a settle emitted for a caller with no stream to give
+        # them to (settle(hold=True)): the next step(), decode_run() or
+        # settle() returns them first
+        self._held = []
         self._requests = {}
         self._next_rid = 0
         self._window_fns = {}
@@ -1010,13 +1073,17 @@ class ServingEngine:
                              "logits)")
         if len(prompt_tokens) + max_new_tokens > self.cfg.max_seq:
             raise ValueError("prompt + max_new_tokens exceeds max_seq")
-        if self.cfg.max_queue is not None \
-                and len(self.pending()) >= self.cfg.max_queue:
-            self._m.shed.inc()
-            raise EngineOverloadedError(
-                f"engine saturated: {len(self.pending())} live requests "
-                f">= max_queue={self.cfg.max_queue}; shed this request "
-                f"(retry later or on another replica)")
+        max_queue = self.cfg.max_queue
+        if max_queue is not None and len(self.pending()) >= max_queue:
+            # a request whose last token is in flight still counts as
+            # live: shed on the settled count only
+            self.settle(hold=True)
+            if len(self.pending()) >= max_queue:
+                self._m.shed.inc()
+                raise EngineOverloadedError(
+                    f"engine saturated: {len(self.pending())} live "
+                    f"requests >= max_queue={max_queue}; shed this request "
+                    f"(retry later or on another replica)")
         rid = self._next_rid
         self._next_rid += 1
         req = _Request(rid, prompt_tokens, max_new_tokens,
@@ -1070,6 +1137,9 @@ class ServingEngine:
                 "speculative decoding needs a from_model engine: the "
                 "exported serving artifact has no all-positions verify "
                 "entry")
+        # a drafter reads a request's settled tokens: while one is set
+        # every step settles before it returns
+        self.settle(hold=True)
         self._drafter = drafter
         if k is not None:
             self._spec_k = int(k)
@@ -1106,13 +1176,17 @@ class ServingEngine:
             self._m.prefix_pages.inc(len(pages))
         self._m.prefix_rate.set(cache.hit_rate())
 
-    def _maybe_register_prefix(self, req):
-        """After a request's prompt is fully prefilled, publish its full
-        prompt blocks into the prefix cache (ownership of those pages
-        transfers to the cache; the request keeps a ref)."""
+    def _maybe_register_prefix(self, req, cached=None):
+        """After a request's prompt is fully prefilled (`cached`, default
+        the settled `req.cached`, covers it), publish its full prompt
+        blocks into the prefix cache (ownership of those pages transfers
+        to the cache; the request keeps a ref). A step registers what it
+        has DISPATCHED: whoever matches those pages reads them in a later
+        step, which the chip runs after this one."""
         cache = self._prefix_cache
         if cache is None or req.prefix_registered \
-                or req.cached < len(req.prompt):
+                or (req.cached if cached is None else cached) \
+                < len(req.prompt):
             return
         req.prefix_registered = True
         req.shared_keys.extend(cache.insert(req.prompt, req.pages,
@@ -1125,16 +1199,24 @@ class ServingEngine:
         back to the pool instead of starving live traffic.  Each evicted
         request is surfaced through ``requeue_hook`` (when installed) so
         a replica router can retry it elsewhere instead of dropping it
-        on the floor."""
+        on the floor.  Returns the tokens it had to settle first."""
         now = time.perf_counter()
-        for r in self.pending():
-            if r.deadline_t is not None and now > r.deadline_t:
+        expired = [r for r in self.pending()
+                   if r.deadline_t is not None and now > r.deadline_t]
+        # a request with a token in flight is evicted with that token
+        # settled (it may be its last): what the router retries is the
+        # settled stream
+        settled = self._settle_forced() \
+            if any(r.ahead for r in expired) else []
+        for r in expired:
+            if not r.done:
                 r.timed_out = True
                 r.done = True
                 self._release(r)
                 self._m.deadline.inc()
                 if self.requeue_hook is not None:
                     self.requeue_hook(self._requeue_info(r))
+        return settled
 
     @staticmethod
     def _requeue_info(r):
@@ -1177,6 +1259,10 @@ class ServingEngine:
             return
         if act.kind == "kill":
             self.dead = True
+            # the settled fields are the migratable state: the token in
+            # flight is not emitted, and is sampled again under the same
+            # salt where the request lands
+            self._drop_flight()
             from ..distributed.resilience.errors import EngineDeadError
 
             raise EngineDeadError(self.name, site)
@@ -1194,6 +1280,7 @@ class ServingEngine:
         if root is None:
             raise ValueError("no snapshot root: pass root= or set "
                              "cfg.prefix_snapshot_root")
+        self.settle(hold=True)
         return save_snapshot(self, root, keep=keep)
 
     def restore_prefix_cache(self, root=None):
@@ -1250,6 +1337,8 @@ class ServingEngine:
             raise KeyError(
                 f"engine {self.name} cannot serve weight version "
                 f"{version} (active={self._active_wv})")
+        if r.ahead:
+            self.settle(hold=True)
         self._release(r)
         r.cached = 0
         r.prefix_registered = False
@@ -1336,6 +1425,7 @@ class ServingEngine:
         from ..distributed.resilience.errors import PublishRejectedError
 
         self._check_alive()
+        self.settle(hold=True)
         if version <= self._active_wv:
             raise PublishRejectedError(
                 "stale_version", version, fence_version=self._active_wv)
@@ -1377,6 +1467,7 @@ class ServingEngine:
         from ..distributed.resilience.errors import PublishRejectedError
 
         self._check_alive()
+        self.settle(hold=True)
         if self._prev_wv is None or self._prev_wv not in self._weight_sets:
             raise PublishRejectedError(
                 "no_previous", self._active_wv,
@@ -1431,6 +1522,7 @@ class ServingEngine:
             raise ValueError(
                 f"probe prompt length {n} must be in [1, "
                 f"{cfg.token_budget}] (one fresh-prefill shot)")
+        self.settle(hold=True)
         wv = self._active_wv if version is None else version
         if wv == self._active_wv:
             fp = self._params
@@ -1539,7 +1631,11 @@ class ServingEngine:
         """Pick <= max_batch rows and a prefill/decode chunk size for
         each within the token budget (vLLM-style chunked prefill: a
         request needing more tokens than fit this step takes the next
-        chunk of its prompt+generated sequence)."""
+        chunk of its prompt+generated sequence). A row starts where the
+        step in flight leaves it: its settled `cached` plus that step's
+        chunk, its sequence one longer by the token that step samples;
+        a row whose in-flight token is its `max_new`-th is done but for
+        the fetch and is not scheduled again."""
         cfg = self.cfg
         rows = []
         budget = cfg.token_budget
@@ -1560,9 +1656,13 @@ class ServingEngine:
                 break
             if step_wv is not None and r.weight_version != step_wv:
                 continue
-            chunk = min(r.length - r.cached, budget)
+            ahead_tok = r.ahead_row >= 0
+            if ahead_tok and len(r.generated) + 1 >= r.max_new:
+                continue
+            cached = r.cached + r.ahead
+            chunk = min(r.length + ahead_tok - cached, budget)
             cap = (len(r.pages) + avail) * cfg.block_size  # page-limited
-            chunk = min(chunk, cap - r.cached)
+            chunk = min(chunk, cap - cached)
             if chunk <= 0:
                 continue  # defer: rerun once budget/pages free up
             if slots is not None and r.slot is None:
@@ -1570,7 +1670,7 @@ class ServingEngine:
                     continue
                 slots -= 1
             pages_needed = max(
-                math.ceil((r.cached + chunk) / cfg.block_size)
+                math.ceil((cached + chunk) / cfg.block_size)
                 - len(r.pages), 0)
             budget -= chunk
             avail -= pages_needed
@@ -1580,17 +1680,122 @@ class ServingEngine:
 
     def step(self):
         """One engine iteration: schedule <= max_batch live requests
-        (prefill chunks + decode mixed) within the token budget, run the
-        step function once, sample one token per request that reached its
-        sequence tip.
+        (prefill chunks + decode mixed) within the token budget, dispatch
+        the step function and the sampler for the rows that reached their
+        sequence tip, THEN fetch and emit the step the previous call
+        dispatched, which ran on the chip meanwhile.
+
+        Returns the (rid, token) pairs that became known since the last
+        call: those of the step in flight when it was called, of anything
+        it had to settle before it could schedule, and first those a
+        call between the two settled and held (a weight commit, an
+        admission at `max_queue`, a migration: `settle(hold=True)`), so
+        that what `step()` and `decode_run()` return, taken together, is
+        every request's whole stream. The step it dispatches stays in
+        flight until the next call, or `settle()`. When nothing can be
+        scheduled it settles and returns, so a loop
+        `while engine.pending(): engine.step()` drains the engine.
 
         The step's own account of itself: one `serving::step` span (ring,
         flight recorder, and a `TraceAnnotation` on the device trace's
-        clock) with what it held in its args, and a child for each phase
-        — `serving::schedule`, `serving::pack`, `serving::sample_sync`
-        (the wait for the chip), `serving::emit`."""
+        clock) with what it held in its args (`lookahead` 1 where it was
+        dispatched with the step before it unsettled), and a child for
+        each phase: `serving::schedule`, `serving::pack`,
+        `serving::sample_sync` (this step's sampler dispatched, then the
+        wait for the PREVIOUS step's tokens, with this one enqueued
+        behind it), `serving::emit`."""
         with _tracing.span("serving::step") as sp:
-            return self._step(sp.args)
+            produced = self._step(sp.args)
+        return self._take_held() + produced
+
+    def _take_held(self):
+        held, self._held = self._held, []
+        return held
+
+    def settle(self, hold=False):
+        """Fetch and emit the step in flight, if any: afterwards every
+        request's `generated`, `cached`, `done` and `pages` say all the
+        engine has computed. Whoever reads or moves a request's tokens,
+        pages or state from outside `step()` calls this first. Returns
+        the (rid, token) pairs emitted and not yet returned by any call
+        (`[]` with nothing in flight: calling it again changes nothing);
+        they are the caller's to stream. A caller with no stream to give
+        them to passes `hold=True`: the pairs stay with the engine, the
+        next `step()`, `decode_run()` or `settle()` returns them first,
+        and this call returns `[]`. A dead engine's step in flight is
+        dropped instead: its requests keep their settled state, which is
+        what migrates."""
+        self._held += self._settle_forced()
+        return [] if hold else self._take_held()
+
+    def _settle_forced(self):
+        """A settle that something other than the next step asked for:
+        the pairs the step in flight emits (a dead engine's is dropped)."""
+        if self._flight is None:
+            return []
+        if self.dead:
+            self._drop_flight()
+            return []
+        self._m.settle_forced.inc()
+        return self._settle()
+
+    def _settle(self):
+        """The wait for the step in flight and its book-keeping, under
+        the spans a step gives them."""
+        with _tracing.phase("serving::sample_sync"):
+            flight = self._fetch_flight()
+        with _tracing.phase("serving::emit"):
+            return self._emit_flight(flight)
+
+    def _fetch_flight(self):
+        """Take the step in flight off the engine and wait for what it
+        sampled: the step, its tokens and model counts now on the host."""
+        flight, self._flight = self._flight, None
+        if flight is not None and flight.sampled is not None:
+            flight.sampled = np.asarray(flight.sampled)
+            if flight.counts is not None:
+                # the chip has finished the step: this fetch waits for
+                # nothing more
+                flight.counts = np.asarray(flight.counts)
+        return flight
+
+    def _emit_flight(self, flight):
+        """A fetched step's book-keeping: its rows advance, its tokens are
+        appended, finished requests give their pages back."""
+        produced = []
+        if flight is None:
+            return produced
+        if flight.counts is not None:
+            for c, n in zip(self._m.step_counts, flight.counts):
+                c.inc(int(n))
+        now = time.perf_counter()
+        for i, (r, chunk) in enumerate(flight.rows):
+            r.ahead, r.ahead_row = 0, -1
+            if r.done:
+                # finished, evicted or released since the step was
+                # dispatched (an end-of-stream token is seen one step
+                # late): what the step computed for it is dropped
+                continue
+            r.cached += chunk
+            if not flight.tip[i]:
+                continue
+            nxt = int(flight.sampled[i])
+            r.generated.append(nxt)
+            produced.append((r.rid, nxt))
+            self._note_first_token(r, now)
+            if len(r.generated) >= r.max_new \
+                    or (r.eos_token_id is not None
+                        and nxt == r.eos_token_id):
+                self._finish(r, now)
+        self._m.tokens.inc(len(produced))
+        return produced
+
+    def _drop_flight(self):
+        """Forget the step in flight without emitting it."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            for r, _ in flight.rows:
+                r.ahead, r.ahead_row = 0, -1
 
     def _count_step(self, note, program, rows, tokens, pad,
                     prefill_tokens):
@@ -1629,10 +1834,15 @@ class ServingEngine:
 
         with _tracing.phase("serving::schedule"):
             self._check_alive()
-            self._evict_expired()
+            settled = self._evict_expired()
             rows = self._schedule()
+            # nothing can go ahead of the tokens in flight (each row's
+            # last token is among them, or the pool is too tight to grow
+            # any): settle, and let the next call schedule, and pre-empt
+            # if it must, from settled state
+            settle_only = not rows and self._flight is not None
             preempted = set()
-            while not rows and self.pending():
+            while not rows and not settle_only and self.pending():
                 # pool deadlock: in-flight requests hold pages but none
                 # can grow — preempt the NEWEST holder (FCFS priority: the
                 # oldest request always makes progress, so symmetric
@@ -1660,15 +1870,23 @@ class ServingEngine:
                 preempted.add(victim.rid)
                 self._m.preempt.inc()
                 rows = self._schedule()
+        if settle_only:
+            settled += self._settle()
+            if self.pending():
+                # work remains that could not go ahead of these tokens
+                self._m.settle_forced.inc()
+            return settled
         if not rows:
-            return []
+            return settled
+        # where each row starts: behind what the step in flight holds
+        starts = [r.cached + r.ahead for r, _ in rows]
         # chaos sites, consulted BEFORE any page allocation or cache
         # mutation: a kill here leaves every scheduled request in a
         # consistent pre-step state (decode rows still at their tip), so
         # the fleet supervisor can migrate them losslessly
-        if any(r.cached < len(r.prompt) for r, _ in rows):
+        if any(c < len(r.prompt) for (r, _), c in zip(rows, starts)):
             self._fault_event("prefill")
-        if any(r.cached >= len(r.prompt) for r, _ in rows):
+        if any(c >= len(r.prompt) for (r, _), c in zip(rows, starts)):
             self._fault_event("decode")
         self._m.steps.inc()
         # first scheduling of a request ends its queue span
@@ -1686,11 +1904,13 @@ class ServingEngine:
         # row needs exactly its next token) runs as one draft+verify
         # step instead — transparent to every caller of step(), so the
         # router/gateway/supervisor tiers become speculative unchanged
+        # (with a drafter set nothing is in flight here: see below)
         if self._drafter is not None and all(
                 chunk == 1 and r.cached == r.length - 1
                 for r, chunk in rows):
-            return self._spec_step(rows, note)
+            return settled + self._spec_step(rows, note)
 
+        flight = self._flight
         with _tracing.phase("serving::pack"):
             B1 = cfg.max_batch + 1
             enc = np.zeros(B1, np.int32)
@@ -1702,19 +1922,29 @@ class ServingEngine:
             # each row's slot; rows the step does not hold, and the
             # padding row, point at the padding slot
             slots = np.full(B1, cfg.max_batch, np.int32)
+            # for a token still on the device (the one the step in flight
+            # samples for this row): that step's row, else -1
+            src = None
             for i, (r, chunk) in enumerate(rows):
-                dec[i] = r.cached                # chunk starts at this pos
+                cached = starts[i]
+                dec[i] = cached                  # chunk starts at this pos
                 this[i] = chunk
-                if r.cached < len(r.prompt):
+                if cached < len(r.prompt):
                     prefill_tokens += chunk
-                self._ensure_pages(r, r.cached + chunk)
+                self._ensure_pages(r, cached + chunk)
                 bt[i, :len(r.pages)] = r.pages
-                past = r.cached - len(r.prompt)
+                past = cached - len(r.prompt)
                 if past >= 0:       # a decode row: no copy of its history
-                    packed.extend(r.generated[past:past + chunk])
+                    toks = r.generated[past:past + chunk]
                 else:
-                    packed.extend((r.prompt + r.generated)[
-                        r.cached:r.cached + chunk])
+                    toks = (r.prompt + r.generated)[cached:cached + chunk]
+                if len(toks) < chunk:
+                    # the chunk ends on the token in flight
+                    if src is None:
+                        src = np.full(cfg.token_budget, -1, np.int32)
+                    src[len(packed) + len(toks)] = r.ahead_row
+                    toks = toks + [0]
+                packed.extend(toks)
                 if self._row_state:
                     if r.slot is None:
                         r.slot = self._free_slots.pop()
@@ -1726,6 +1956,8 @@ class ServingEngine:
             this[B1 - 1] = n_pad
             enc[B1 - 1] = n_pad
             tokens = np.asarray(packed + [0] * n_pad, np.int32)
+            if src is not None:
+                tokens = _feed_tokens_dev(flight.sampled, src, tokens)
             cu = np.zeros(B1 + 1, np.int32)
             cu[1:] = np.cumsum(this)
 
@@ -1734,7 +1966,7 @@ class ServingEngine:
             # attention over the packed tokens instead of the page-pool
             # gather
             fresh = self._compiled_fresh is not None \
-                and all(r.cached == 0 for r, _ in rows)
+                and not any(starts)
             compiled = self._compiled_fresh if fresh else self._compiled
             # bind the step's pinned weight version (_schedule guarantees
             # every scheduled row shares it); shapes/dtypes are identical
@@ -1745,66 +1977,60 @@ class ServingEngine:
                                     dec, this, cu, bt, slots)
             self._count_step(note, program, len(rows), len(packed), n_pad,
                              prefill_tokens)
+            for (r, chunk), cached in zip(rows, starts):
+                self._maybe_register_prefix(r, cached + chunk)
+            note["lookahead"] = int(flight is not None)
+            if flight is not None:
+                self._m.lookahead.inc()
             if self._row_state:
                 m = self._m
                 m.ssm_decode.inc(sum(c == 1 for _, c in rows))
                 m.ssm_chunk.inc(sum(c > 1 for _, c in rows))
-                m.ssm_resets.inc(sum(r.cached == 0 for r, _ in rows))
+                m.ssm_resets.inc(sum(c == 0 for c in starts))
                 # the step span says how many rows the state-update kernel
                 # served: a reader prices the kernel's calls by it
                 note["ssm_rows_decode"] = sum(c == 1 for _, c in rows)
 
             # device-side sampling for rows that reached their sequence
-            # tip
+            # tip (the token in flight is part of the sequence, and of the
+            # count the salt is drawn from)
             temps = np.zeros(B1, np.float32)
             topks = np.zeros(B1, np.int32)
             topps = np.ones(B1, np.float32)
             salts = np.zeros(B1, np.int32)
             tip = [False] * len(rows)
             for i, (r, chunk) in enumerate(rows):
-                if r.cached + chunk == r.length:
+                ahead_tok = r.ahead_row >= 0
+                if starts[i] + chunk == r.length + ahead_tok:
                     tip[i] = True
                     sp = r.sampling
                     temps[i] = sp.temperature
                     topks[i] = sp.top_k
                     topps[i] = sp.top_p
-                    salts[i] = self._salt(r, len(r.generated))
-        if not any(tip):
-            # pure prefill-chunk step: nothing to sample — skip the
-            # sampler dispatch AND the host round-trip entirely
-            with _tracing.phase("serving::emit"):
-                for r, chunk in rows:
-                    r.cached += chunk
-                    self._maybe_register_prefix(r)
-            return []
+                    salts[i] = self._salt(r, len(r.generated) + ahead_tok)
         with _tracing.phase("serving::sample_sync"):
-            sampled = self._sample(logits, temps, topks, topps, salts)
-            if self._step_counts is not None:
-                # the chip has finished the step: this fetch waits for
-                # nothing more
-                for c, n in zip(self._m.step_counts,
-                                np.asarray(self._step_counts)):
-                    c.inc(int(n))
-                self._step_counts = None
-
+            # this step's sampler is enqueued behind it (a pure
+            # prefill-chunk step samples nothing); then the wait for the
+            # step before it, which ran while this one was packed
+            sampled = counts = None
+            if any(tip):
+                sampled = self._sample_dev(logits, temps, topks, topps,
+                                           salts)
+                # a step that samples nothing leaves its model counts on
+                # the device, added up, for the next that does
+                counts, self._step_counts = self._step_counts, None
+            before = self._fetch_flight()
         with _tracing.phase("serving::emit"):
-            produced = []
-            now = time.perf_counter()
+            settled += self._emit_flight(before)
             for i, (r, chunk) in enumerate(rows):
-                r.cached += chunk
-                self._maybe_register_prefix(r)
-                if not tip[i]:
-                    continue
-                nxt = int(sampled[i])
-                r.generated.append(nxt)
-                produced.append((r.rid, nxt))
-                self._note_first_token(r, now)
-                if len(r.generated) >= r.max_new \
-                        or (r.eos_token_id is not None
-                            and nxt == r.eos_token_id):
-                    self._finish(r, now)
-            self._m.tokens.inc(len(produced))
-        return produced
+                if not r.done:        # (an end of stream just seen)
+                    r.ahead = chunk
+                    r.ahead_row = i if tip[i] else -1
+            self._flight = _Flight(rows, tip, sampled, counts)
+        if self._drafter is not None:
+            # a drafter reads settled tokens: this engine steps serially
+            settled += self._settle_forced()
+        return settled
 
     def _run_step(self, program, compiled, fp, tokens, enc, dec, this, cu,
                   bt, slots):
@@ -1858,18 +2084,21 @@ class ServingEngine:
         return prog.phases() if prog is not None else None
 
     @staticmethod
-    def _sample(logits, temps, topks, topps, salts):
-        """Sampler dispatch and the fetch of its tokens: the step's wait
-        for the chip. Fast paths: skip the full-vocab sort when no row
-        samples, or when every sampling row fits the exact top-k
-        candidate sampler."""
+    def _sample_dev(logits, temps, topks, topps, salts):
+        """Sampler dispatch; the tokens stay on the device. Fast paths:
+        skip the full-vocab sort when no row samples, or when every
+        sampling row fits the exact top-k candidate sampler."""
         if not np.any(temps > 0):
-            return np.asarray(_greedy_tokens_dev(logits))
+            return _greedy_tokens_dev(logits)
         if _topk_fast_ok(temps, topks):
-            return np.asarray(_sample_topk_dev(
-                logits, temps, topks, topps, salts))
-        return np.asarray(_sample_tokens_dev(
-            logits, temps, topks, topps, salts))
+            return _sample_topk_dev(logits, temps, topks, topps, salts)
+        return _sample_tokens_dev(logits, temps, topks, topps, salts)
+
+    @classmethod
+    def _sample(cls, *args):
+        """Sampler dispatch and the fetch of its tokens at once: a
+        speculative step's wait for the chip."""
+        return np.asarray(cls._sample_dev(*args))
 
     # -- speculative decode (draft k, verify in one paged step) ----------
     def _spec_step(self, rows, note):
@@ -2125,19 +2354,22 @@ class ServingEngine:
         each step's sampled tokens feed the next step's inputs on device.
         Requests must be at their decode tip (fully prefilled); pages for
         the whole window are reserved up front so block tables stay
-        static. Returns the produced (rid, token) list in step order."""
+        static. Returns the produced (rid, token) list in step order,
+        after those of the step in flight, which it settles first."""
         if self._row_state:
             raise ValueError(
                 "decode_run's fused window with a state-space layer: the "
                 "window program threads pages only; step() serves this "
                 "model")
         with RecordEvent("serving::decode_run"):
-            return self._decode_run(n_steps)
+            produced = self._decode_run(n_steps)
+        return self._take_held() + produced
 
     def _decode_run(self, n_steps):
         cfg = self.cfg
         self._check_alive()
-        self._evict_expired()
+        # the window reads every row's newest token: settle first
+        settled = self._settle_forced() + self._evict_expired()
         rows = [r for r in self.pending()
                 if r.length - r.cached == 1]
         if rows:
@@ -2147,7 +2379,7 @@ class ServingEngine:
             rows = [r for r in rows
                     if r.weight_version == wv][:cfg.max_batch]
         if not rows:
-            return []
+            return settled
         # same pre-mutation contract as _step: every selected row is at
         # its decode tip when a kill fires here, i.e. migratable
         self._fault_event("decode")
@@ -2162,7 +2394,7 @@ class ServingEngine:
                     - len(r.pages), 0) for r in rows) > free:
             n -= 1
         if n <= 0:
-            return []
+            return settled
         if n < n_steps:
             # bound the executable zoo: tail windows (remaining budget or
             # page pool smaller than requested) round down to a power of
@@ -2255,13 +2487,14 @@ class ServingEngine:
                             and nxt == r.eos_token_id):
                     self._finish(r, now)
         self._m.tokens.inc(len(produced))
-        return produced
+        return settled + produced
 
     def run_to_completion(self, max_steps=1000):
         for _ in range(max_steps):
             if not self.pending():
                 break
             self.step()
+        self.settle()       # max_steps ran out with a step in flight
         return {rid: list(r.generated)
                 for rid, r in self._requests.items()}
 

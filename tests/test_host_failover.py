@@ -521,6 +521,10 @@ def test_handoff_factory_carries_cross_host_migration(srv_model):
     c_mig0 = _cval("serving/cross_host_migrations")
     # decode every request to its tip, then fell one h1 replica: the
     # drain takes the migration path through the factory's transport
+    # (two calls: the second fetches the prefill's tokens. What it
+    # dispatched itself is dropped with the engine, and sampled again
+    # under the same salt where each request lands)
+    router.step_all()
     router.step_all()
     victim = 2
     router.replicas[victim].engine.dead = True

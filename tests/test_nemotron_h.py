@@ -103,11 +103,11 @@ def test_three_chunks_across_steps_equal_one_chunk():
     for budget in (16, 64):
         eng = make_engine(model, token_budget=budget)
         rid = eng.add_request(p, max_new_tokens=5)
-        steps = 0
-        while eng._requests[rid].cached < len(p):
+        steps, r = 0, eng._requests[rid]
+        while r.cached + r.ahead < len(p):      # dispatched, not fetched
             eng.step()
             steps += 1
-        slot = eng._requests[rid].slot
+        slot = r.slot
         got[budget] = (steps, np.asarray(eng._row_state["ssm"][:, slot]),
                        np.asarray(eng._row_state["conv"][:, slot]),
                        eng.run_to_completion()[rid])

@@ -259,7 +259,11 @@ def test_serving_ttft_tpot_and_gauges():
     for _ in range(2):
         engine.add_request(list(rng.randint(1, cfg.vocab_size, 6)),
                            max_new_tokens=4)
-    produced = engine.step()               # prefill tip -> first tokens
+    # prefill tip -> first tokens: dispatched by this call, known (and
+    # their first-token time observed) once the step is settled
+    produced = engine.step()
+    assert produced == [] and M.histogram("serving/ttft_ms").count == ttft0
+    produced = engine.settle()
     assert produced, "tip rows must sample on the first step"
     assert M.histogram("serving/ttft_ms").count == ttft0 + 2
     assert 0.0 < M.gauge("serving/batch_occupancy").value <= 1.0

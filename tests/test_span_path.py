@@ -105,16 +105,20 @@ def test_engine_step_children_nest_in_the_xplane(engine_parts, tmp_path):
         eng.run_to_completion()
     host = _host_events(str(tmp_path))
     steps = sorted(host["serving::step"])
-    assert len(steps) == 3
+    # three steps dispatched; the fourth call only fetches the third's
+    # token, and packs nothing
+    assert len(steps) == 4
     for name in STEP_PHASES:
-        assert len(host[name]) == len(steps), name
-        for (c0, c1), (s0, s1) in zip(sorted(host[name]), steps):
+        mine = steps[:3] if name == "serving::pack" else steps
+        assert len(host[name]) == len(mine), name
+        for (c0, c1), (s0, s1) in zip(sorted(host[name]), mine):
             assert s0 <= c0 and c1 <= s1, name
     ring = tracing.ring_spans()
     step_ids = [s["span_id"] for s in ring if s["name"] == "serving::step"]
     for name in STEP_PHASES:
         parents = [s["parent_id"] for s in ring if s["name"] == name]
-        assert parents == step_ids, name
+        assert parents == (step_ids[:3] if name == "serving::pack"
+                           else step_ids), name
 
 
 def test_engine_step_account_adds_up(engine_parts):
@@ -146,7 +150,11 @@ def test_engine_step_account_adds_up(engine_parts):
 
     ring = tracing.ring_spans()
     step_spans = [s for s in ring if s["name"] == "serving::step"]
-    assert len(step_spans) == steps
+    # the last call dispatched nothing: it fetched the last step's tokens
+    assert len(step_spans) == steps + 1 and "args" not in step_spans.pop()
+    assert [s["args"]["lookahead"] for s in step_spans] \
+        == [0] + [1] * (steps - 1)
+    assert d["serving/lookahead_steps"] == steps - 1
     assert sum(s["args"]["tokens"] for s in step_spans) \
         == d["serving/step_tokens"]
     assert sum(s["args"]["rows"] for s in step_spans) \
@@ -168,8 +176,8 @@ def test_decode_run_observes_tpot_as_the_step_paths_do(engine_parts):
     hist = lambda: metrics.snapshot()["histograms"]["serving/tpot_ms"]
     h0 = hist()
     rid = eng.add_request([1, 2, 3, 4, 5], max_new_tokens=6)
-    assert eng.step()                      # the prompt, and a first token
-    eng.decode_run(2)
+    assert eng.step() == []                # the prompt, dispatched
+    assert len(eng.decode_run(2)) == 3     # its first token, and a window
     assert hist()["count"] == h0["count"]  # a window alone observes nothing
     eng.decode_run(3)
     req = eng._requests[rid]

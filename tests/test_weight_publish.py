@@ -222,9 +222,12 @@ def test_rollback_bitwise_and_inflight_reset(model):
     prompt = [4, 5, 6, 7]
     rid = eng.add_request(prompt, max_new_tokens=6, sampling=SP)
     eng.step()
+    eng.step()
     r = eng._requests[rid]
-    assert r.weight_version == 1 and r.generated
+    # one token settled, one in flight: the rollback settles it first
+    assert r.weight_version == 1 and len(r.generated) == 1 and r.ahead == 1
     prev = eng.rollback_weight_set()
+    assert eng._flight is None
     assert prev == 0 and eng.active_weight_version == 0
     assert r.weight_version == 0 and r.generated == [] and r.cached == 0
     out = _drain(eng)

@@ -96,6 +96,10 @@ KNOWN_METRICS = (
     # of serving/steps, those whose program holds the paged-attention
     # Pallas kernel (0 off the chip and on exported artifacts)
     "serving/paged_kernel_steps", "serving/kv_inplace_steps",
+    # of serving/steps, those dispatched with the step before them still
+    # unsettled; and the settles something other than the next step
+    # asked for (ServingEngine.settle)
+    "serving/lookahead_steps", "serving/settle_forced",
     # state-space layers over a row slot (inference/layer_states.py):
     # rows through the one-token state update, rows through the chunked
     # scan, rows that started from a zeroed slot
