@@ -565,7 +565,7 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                               quant_min_bound=-127.0, out_scale=-1,
                               compute_dtype="default", layer_idx=None,
                               fresh_prefill=False,
-                              last_row_is_padding=False):
+                              last_row_is_padding=False, selection=None):
     """Paged-KV-cache attention (reference block_multihead_attention):
     qkv [token_num, (HQ+2*HKV)*D] packs each batch row's tokens this step
     (prefill rows contribute seq_lens_encoder[b] tokens at positions
@@ -624,7 +624,16 @@ def block_multihead_attention(qkv, key_cache, value_cache,
     block-diagonal varlen flash over the pack, skipping the page-pool
     gather. It implies the padding-row contract above: the last row's
     tokens get segment id -1 and attend nothing. Callers scheduling real
-    work into row B-1 must set neither."""
+    work into row B-1 must set neither.
+
+    selection (block-sparse attention, ops/pallas/sparse_paged_attention.py):
+    a dict of what each query attends among its row's pages, chosen on the
+    device in the same step: `page_mask` [T, HKV, max_blocks] bool for
+    every token, and for the rows of one token `listed` [B], `sel`
+    [B, HKV, S] logical pages and `n_sel` [B, HKV]. The kernel walks a
+    listed row's list and no other page, and every other row's pages under
+    the mask; the gathered formulation takes the mask alone. A fresh
+    prefill attends the whole pack and takes no selection."""
     if cache_k_quant_scales is not None and not use_dynamic_cachekv_quant:
         raise NotImplementedError("block_multihead_attention: static "
                                   "per-tensor cache scales are CUDA-"
@@ -747,15 +756,25 @@ def block_multihead_attention(qkv, key_cache, value_cache,
                 vr.transpose(1, 0, 2)[None], seg[None], seg[None],
                 is_causal=True)
             out = o[0].transpose(1, 0, 2)                    # [T, HQ, D]
+        elif kernels and selection is not None:
+            from ....ops.pallas.sparse_paged_attention import \
+                sparse_paged_attention
+
+            out = sparse_paged_attention(
+                q, k, v, kc, vc, bt, start, cu_q, selection["listed"],
+                selection["sel"], selection["n_sel"],
+                selection["page_mask"], layer_idx=layer_idx)
         elif kernels:
             # over the caches as they are before this call's write, plus
             # this step's own k/v
             out = _pa.paged_attention(q, k, v, kc, vc, bt, start, cu_q,
                                       layer_idx=layer_idx)
         else:
-            out = _pa.paged_attention_ref(q, kc, vc, bt, start, cu_q,
-                                          layer_idx=layer_idx, k_scales=ks,
-                                          v_scales=vs)
+            out = _pa.paged_attention_ref(
+                q, kc, vc, bt, start, cu_q, layer_idx=layer_idx,
+                k_scales=ks, v_scales=vs,
+                page_mask=None if selection is None
+                else selection["page_mask"])
         if kernels:
             # in place, once the attention has read the pages
             with scope("kv_write"):

@@ -15,14 +15,15 @@
 
 `PagedCausalLM` declares attention in every layer and no row state
 (`LayerStates.attention_only`); `models/nemotron_h.py` declares one
-attention layer of eleven and two row states.
+attention layer of eleven and two row states; `models/minicpm_sala.py`
+two attention layers of eight with a page side, and two row states.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Callable, Optional, Tuple
 
-__all__ = ["RowState", "LayerStates"]
+__all__ = ["RowState", "PageSide", "LayerStates"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,15 +35,29 @@ class RowState:
 
 
 @dataclasses.dataclass(frozen=True)
+class PageSide:
+    name: str
+    layers: int
+    shape: Tuple[int, ...]          # of one page's entries
+    dtype: str
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerStates:
     attention_layers: int
     kv_heads: int
     head_dim: int
     row_states: Tuple[RowState, ...] = ()
+    page_sides: Tuple[PageSide, ...] = ()
     # counters the step returns as one int32 vector, in this order: the
     # model owns the names, the engine adds the counts to the registry
     # where it fetches the step's tokens
     counters: Tuple[str, ...] = ()
+    # what the model wants said in the step's span beside the engine's
+    # own arguments, known on the host before the step runs:
+    # `step_args([(start, chunk), ...]) -> {name: number}` over the
+    # scheduled rows (a reader prices a kernel's calls by it)
+    step_args: Optional[Callable] = None
 
     @classmethod
     def attention_only(cls, cfg) -> "LayerStates":

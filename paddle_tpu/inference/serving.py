@@ -824,6 +824,13 @@ class ServingEngine:
             for rs in states.row_states}
         self._free_slots = list(range(cfg.max_batch)) \
             if states.row_states else []
+        # page sides: {name: [layers, num_blocks, *shape]},
+        # addressed by the block table like the pages, and like them
+        # donated to the step and kept from its return
+        self._page_side = {
+            ps.name: self._alloc((ps.layers, cfg.num_blocks) + ps.shape,
+                                 jnp.dtype(ps.dtype))
+            for ps in states.page_sides}
         # the step's own counts (states.counters), still on the device
         # until the step's sampled tokens are fetched
         self._step_counts = None
@@ -1032,9 +1039,10 @@ class ServingEngine:
         # scale pools after them, as donated arguments: the stacks are
         # updated where they lie, and a caller keeps what the step returns
         # (ins: tokens, enc, dec, this, cu, bt, kc, vc, then *row state,
-        # slots or ks, vs)
+        # *page sides, slots or ks, vs)
         donate = tuple(range(8, 10 + (
-            len(states.row_states) or 2 * (cfg.cache_quant == "int8"))))
+            len(states.row_states) + len(states.page_sides)
+            or 2 * (cfg.cache_quant == "int8"))))
         eng._compiled = jax.jit(pure, donate_argnums=donate)
         eng._compiled_fresh = jax.jit(pure_fresh, donate_argnums=donate)
         eng._compiled_verify = None if functional \
@@ -1990,6 +1998,9 @@ class ServingEngine:
                 # the step span says how many rows the state-update kernel
                 # served: a reader prices the kernel's calls by it
                 note["ssm_rows_decode"] = sum(c == 1 for _, c in rows)
+            if self._states.step_args is not None:
+                note.update(self._states.step_args(
+                    [(c, chunk) for c, (_, chunk) in zip(starts, rows)]))
 
             # device-side sampling for rows that reached their sequence
             # tip (the token in flight is part of the sequence, and of the
@@ -2035,11 +2046,13 @@ class ServingEngine:
     def _run_step(self, program, compiled, fp, tokens, enc, dec, this, cu,
                   bt, slots):
         """Run one step program over the engine's state and keep what it
-        returns: (logits, pages, then the int8 scales or the row states
-        and the step's own counts). A from_model step was given its pages,
-        scales and row state (donated): they are replaced, not copied."""
-        if self._row_state:
-            extra = (*self._row_state.values(), slots)
+        returns: (logits, pages, then the int8 scales or the row states,
+        the page sides and the step's own counts). A from_model step was
+        given its pages, scales, row state and page sides (donated): they
+        are replaced, not copied."""
+        if self._row_state or self._page_side:
+            extra = (*self._row_state.values(), *self._page_side.values(),
+                     slots)
         elif self._ks is not None:
             extra = (self._ks, self._vs)
         else:
@@ -2049,9 +2062,11 @@ class ServingEngine:
         self._register_program(program, compiled, args)
         out = compiled(*args)
         self._set_caches(out[1], out[2])
-        if self._row_state:
+        if self._row_state or self._page_side:
             n = len(self._row_state)
             self._row_state = dict(zip(self._row_state, out[3:3 + n]))
+            self._page_side = dict(zip(self._page_side, out[3 + n:]))
+            n += len(self._page_side)
             if self._states.counters:
                 # a step that samples nothing fetches nothing: its counts
                 # wait, added up on the device, for the next that does

@@ -46,6 +46,12 @@ owns reads 0.
 
 Operand precision is the reference's: cache-dtype operands into the MXU,
 float32 accumulation, float32 softmax.
+
+A row here attends every page it owns. Where a model chooses, on the device
+in the same step, which of its pages each query attends (block-sparse
+attention), the sibling `sparse_paged_attention.py` walks the pages listed
+for each (row, KV head), or all of a row's pages under a mask a token;
+`paged_attention_ref` takes that mask as `page_mask`.
 """
 from __future__ import annotations
 
@@ -63,6 +69,7 @@ from ...profiler.scopes import scope
 from .flash_attention import _MASK_MIN
 
 __all__ = ["paged_attention", "paged_attention_ref", "use_kernel",
+           "note_traced_call",
            "traced_kernel_calls"]
 
 _LANES = 128
@@ -70,6 +77,13 @@ _KV_BLOCK = 128              # keys a step of either walk
 _QUERY_ROWS = 64             # MXU rows a query block aims at (q_block * G)
 _NO_ROW = 2 ** 30            # first-token bound of a token no row owns
 _traced_kernel_calls = 0
+
+
+def note_traced_call() -> None:
+    """A trace took a kernel over the pages: this one, or its sibling over
+    selected pages (`sparse_paged_attention.py`)."""
+    global _traced_kernel_calls
+    _traced_kernel_calls += 1
 
 
 def traced_kernel_calls() -> int:
@@ -251,8 +265,7 @@ def paged_attention(q, k, v, key_cache, value_cache, block_tables, start,
     token this step; cu_seqlens_q `[B + 1]`. Token `t` of row `b` sees the
     row's cached positions `0 .. start[b] - 1` and the packed tokens
     `cu_seqlens_q[b] .. t`. Returns `[T, HQ, D]`."""
-    global _traced_kernel_calls
-    _traced_kernel_calls += 1
+    note_traced_call()
     if layer_idx is None:
         key_cache, value_cache = key_cache[None], value_cache[None]
         layer_idx = 0
@@ -345,9 +358,11 @@ def _paged_call(q, k, v, key_cache, value_cache, block_tables, start,
 
 def paged_attention_ref(q, key_cache, value_cache, block_tables, start,
                         cu_seqlens_q, *, layer_idx=None, k_scales=None,
-                        v_scales=None):
+                        v_scales=None, page_mask=None):
     """Each row's pages gathered whole into a dense `[B, HKV, max_seq, D]`
-    view, each token given its row's view, softmax over `max_seq`."""
+    view, each token given its row's view, softmax over `max_seq`.
+    `page_mask [T, HKV, max_blocks]` bool, where given, says which of its
+    row's pages each token attends (`sparse_paged_attention.py`)."""
     t, hq, d = q.shape
     hkv, bs = key_cache.shape[-3], key_cache.shape[-2]
     rows, max_blocks = block_tables.shape
@@ -381,7 +396,10 @@ def paged_attention_ref(q, key_cache, value_cache, block_tables, start,
                         preferred_element_type=jnp.float32) \
         / jnp.sqrt(jnp.float32(d))
     valid = jnp.arange(max_seq)[None, :] <= pos[:, None]     # [T, S]
-    logits = jnp.where(valid[:, None, None, :], logits, -jnp.inf)
+    valid = valid[:, None, None, :]
+    if page_mask is not None:
+        valid = valid & jnp.repeat(page_mask, bs, axis=-1)[:, :, None, :]
+    logits = jnp.where(valid, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     with scope("kv_gather"):
         vt = vd[t2b]

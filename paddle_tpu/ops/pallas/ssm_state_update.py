@@ -21,6 +21,17 @@ slot, whose block then stays where it is from one grid step to the next and
 moves nothing. `N` lies on the lanes and `P` on the sublanes, so what varies
 with `P` (`dt x`, `y`) reaches the kernel as columns: `[R, G, P, H // G]`.
 
+Groups = heads (lightning attention, `models/minicpm_sala.py`: the same
+recurrence with `dt` = 1, `A` = -slope, `B` = k, `C` = q / sqrt(d), `D` = 0,
+every head its own `B` and `C`; H 32, P = N = 128). The layout above would
+then put `H // G` = 1 on the lanes: a `[P, 1]` column is padded to 128 lanes
+in HBM and in VMEM, and the grid takes one 64 KiB state a step. So the
+grid's first axis is a BLOCK OF HEADS, not a group: where heads share their
+group's `B`, `C` a block is the group, as before (Nemotron's shapes give the
+program they gave); where each head has its own, a block is
+`_HEADS_PER_BLOCK` heads, `dt x` and `y` are `[R, H / 16, P, 16]`, and `B`,
+`C` reach the kernel as the block's `[16, N]` rows, one a head.
+
 `ssm_state_update` is the dispatch; `ssm_state_update_ref` the jnp
 formulation of the same signature, which the CPU runs and which the kernel
 gives way to where the state does not tile (counted as
@@ -42,6 +53,7 @@ __all__ = ["ssm_state_update", "ssm_state_update_ref", "use_kernel"]
 
 _LANES = 128
 _SUBLANES = 8
+_HEADS_PER_BLOCK = 16        # where every head has its own B and C
 
 
 def use_kernel(state) -> bool:
@@ -95,22 +107,27 @@ def _kernel(layer_ref, slot_ref, active_ref, reset_ref,        # prefetch
     @pl.when(active_ref[r] == 1)
     def _():
         fresh = reset_ref[r] == 1
-        b = b_ref[0, 0].astype(jnp.float32)                   # [1, N]
+        # [1, N] shared by the block's heads, or [hb, N], one row a head
+        b = b_ref[0, 0].astype(jnp.float32)
         c = c_ref[0, 0].astype(jnp.float32)
+        own = b.shape[0] > 1
         da = da_ref[0, 0]                                     # [1, hb]
         dtx = dtx_ref[0, 0]                                   # [P, hb]
         for i in range(hb):
             h = jnp.where(fresh, 0.0, h_ref[0, 0, i])         # [P, N]
-            hn = h * da[:, i:i + 1] + dtx[:, i:i + 1] * b
+            hn = h * da[:, i:i + 1] \
+                + dtx[:, i:i + 1] * (b[i:i + 1] if own else b)
             ho_ref[0, 0, i] = hn
-            y_ref[0, 0, :, i:i + 1] = jnp.sum(hn * c, axis=-1,
-                                              keepdims=True)
+            y_ref[0, 0, :, i:i + 1] = jnp.sum(
+                hn * (c[i:i + 1] if own else c), axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _call(state, da, dtx, b, c, slot, active, reset, layer, *, interpret):
     heads, p, n = state.shape[2:]
-    rows, groups = b.shape[0], b.shape[1]
+    # b, c `[R, blocks, 1 or hb, N]`: the grid's first axis is a block of
+    # `hb` heads (a group, or `_HEADS_PER_BLOCK` heads of their own B, C)
+    rows, groups, bg = b.shape[0], b.shape[1], b.shape[2]
     hb = heads // groups
 
     def by_state(g, r, layer_ref, slot_ref, *_):
@@ -128,8 +145,8 @@ def _call(state, da, dtx, b, c, slot, active, reset, layer, *, interpret):
                 pl.BlockSpec((1, 1, hb, p, n), by_state),
                 pl.BlockSpec((1, 1, 1, hb), by_row),
                 pl.BlockSpec((1, 1, p, hb), by_row),
-                pl.BlockSpec((1, 1, 1, n), by_row),
-                pl.BlockSpec((1, 1, 1, n), by_row),
+                pl.BlockSpec((1, 1, bg, n), by_row),
+                pl.BlockSpec((1, 1, bg, n), by_row),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, hb, p, n), by_state),
@@ -158,7 +175,12 @@ def ssm_state_update(state, x, dt, a, b, c, d, slots, active, reset, *,
                                     reset, layer_idx=layer_idx)
     rows, heads, p = x.shape
     groups, n = b.shape[1], b.shape[2]
-    hb = heads // groups
+    hb, bg = heads // groups, 1
+    if hb == 1 and heads > 1:
+        # groups = heads: blocks of heads, each head its own B and C
+        hb = bg = _HEADS_PER_BLOCK if heads % _HEADS_PER_BLOCK == 0 \
+            else heads
+        groups = heads // hb
     f32 = jnp.float32
     xf = x.astype(f32)
     # what varies with P goes in as columns [R, G, P, hb]; what is a number
@@ -168,8 +190,8 @@ def ssm_state_update(state, x, dt, a, b, c, d, slots, active, reset, *,
         .transpose(0, 1, 3, 2)
     act = active.astype(jnp.int32)
     state, y = _call(
-        state, da, dtx, b.reshape(rows, groups, 1, n),
-        c.reshape(rows, groups, 1, n),
+        state, da, dtx, b.reshape(rows, groups, bg, n),
+        c.reshape(rows, groups, bg, n),
         _per_row(slots, active, state.shape[1]), act,
         reset.astype(jnp.int32), jnp.full((1,), layer_idx, jnp.int32),
         interpret=_interpret())

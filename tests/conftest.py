@@ -46,15 +46,17 @@ def _seed():
 def _model_declared_counters():
     """`tests/benchmark/test_benchmark_program_readers.py::
     test_the_new_metrics_name_what_the_program_writes` looks for the
-    counters of `serve.moe_pairs_per_token` in the registry, but the engine
+    counters of `serve.moe_pairs_per_token` (and of
+    `serve.sparse_walk_overhead`) in the registry, but the engine
     makes a model's counters only when it is handed a model that declares
     them (`LayerStates.counters`), so that test passed only on a worker
-    that had served a Nemotron model before (ROADMAP C9; the test is under
+    that had served such a model before (ROADMAP C9; the test is under
     the benchmark's paths, for a `benchmark` PR to repair). Until then
     every worker starts with them made, as serving that model makes them."""
+    from paddle_tpu.models.minicpm_sala import SPARSE_COUNTERS
     from paddle_tpu.models.nemotron_h import MOE_COUNTERS
     from paddle_tpu.profiler import metrics
 
-    for name in MOE_COUNTERS:
+    for name in MOE_COUNTERS + SPARSE_COUNTERS:
         metrics.counter(name)
     yield

@@ -215,6 +215,81 @@ def test_hybrid_step_holds_its_kernels_and_copies_no_state_stack(
     assert chip_smoke.whole_array_copies_in(hlo, cache) == 0
 
 
+def test_ssm_state_update_compiles_at_groups_equal_heads(one_chip,
+                                                          monkeypatch):
+    """The state-update kernel at lightning attention's sizes (6 layers, 25
+    slots, 32 heads of [128, 128] float32, every head its own B and C):
+    blocks of 16 heads, `dt x` and `y` as `[R, 2, 128, 16]`, the stack
+    aliased in and out."""
+    from paddle_tpu.ops.pallas import ssm_state_update as ssu
+
+    monkeypatch.setenv("PT_USE_PALLAS", "1")
+    L, S, H, P, N, R = 6, 25, 32, 128, 128, 25
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    lowered = jax.jit(
+        lambda *a: ssu.ssm_state_update(*a, layer_idx=3),
+        donate_argnums=(0,)).lower(
+            s((L, S, H, P, N), f32), s((R, H, P), bf16), s((R, H), f32),
+            s((H,), f32), s((R, H, N), bf16), s((R, H, N), bf16),
+            s((H,), f32), s((R,)), s((R,)), s((R,)))
+    n, compiled = _custom_calls(lowered)
+    assert n == 1
+    assert f"f32[{R},{H // 16},{P},16]" in compiled.as_text()
+    m = compiled.memory_analysis()
+    stack = L * S * H * P * N * 4
+    assert m.alias_size_in_bytes >= stack
+    assert m.temp_size_in_bytes < stack // 20
+
+
+def test_sparse_step_holds_its_kernels_and_copies_no_stack(one_chip,
+                                                           monkeypatch):
+    """MiniCPM-SALA's mixed step at the cell's widths and serving sizes
+    (one block-sparse and one lightning layer of the eight), compiled for
+    the described chip: the walk over selected pages, the page write and
+    the state update as kernels, and no copy of the page stacks, of the
+    compressed-key cache beside them or of the lightning state."""
+    import chip_smoke
+    from paddle_tpu.models.minicpm_sala import MiniCPMSala, MiniCPMSalaSpec
+
+    monkeypatch.setenv("PT_USE_PALLAS", "1")
+    spec = MiniCPMSalaSpec(
+        vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+        mixer_types=("minicpm4", "lightning-attn"), num_attention_heads=32,
+        num_key_value_heads=2, head_dim=128, lightning_nh=32,
+        lightning_nkv=32, lightning_head_dim=128, rms_norm_eps=1e-6,
+        rope_theta=10000, scale_emb=12, scale_depth=1.4, dim_model_base=256,
+        published_layers=32)
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    model = MiniCPMSala.__new__(MiniCPMSala)
+    model.spec = spec
+    model.params = {k: s(*v) for k, v in spec.param_shapes().items()}
+    st = model.layer_states()
+    b1, t, mb, nb = 25, 512, 652, 24 * 652 + 1
+    cache = s((st.attention_layers, nb, st.kv_heads, 64, st.head_dim),
+              jnp.bfloat16)
+    kept = [s((r.layers, b1) + r.shape, r.dtype) for r in st.row_states] \
+        + [s((p.layers, nb) + p.shape, p.dtype) for p in st.page_sides]
+    lowered = jax.jit(model.serving_step,
+                      donate_argnums=tuple(range(7, 9 + len(kept)))).lower(
+        model.params, s((t,)), s((b1,)), s((b1,)), s((b1,)), s((b1 + 1,)),
+        s((b1, mb)), cache, cache, *kept, s((b1,)))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert hlo.count("%sparse_paged_attention") >= 1
+    calls = chip_smoke.kernel_calls_in(hlo)
+    assert calls["ssm_state_update"] == calls["kv_page_write"] == 1
+    for stack in [cache] + kept:
+        assert chip_smoke.whole_array_copies_in(hlo, stack) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
 PAGED_ENGINE = dict(vocab_size=512, hidden_size=512, num_layers=2,
                     num_heads=4, num_kv_heads=2, ffn_size=1024,
                     block_size=32, num_blocks=65, max_batch=8,
