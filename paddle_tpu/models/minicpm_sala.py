@@ -53,7 +53,8 @@ import jax.numpy as jnp
 
 from ..inference.layer_states import LayerStates, PageSide, RowState
 from ..ops.pallas.rms_norm import rms_norm
-from ..ops.pallas.sparse_paged_attention import page_mask_of_lists
+from ..ops.pallas.sparse_paged_attention import (page_mask_of_lists,
+                                                 wide_tiles)
 from ..ops.pallas.ssm_state_update import ssm_state_update
 from ..profiler.scopes import scope
 from .chunk_scan import chunk_scan, rows_of
@@ -70,11 +71,21 @@ LINEAR = "lightning-attn"
 # query's selection; the pages the walk reads for it (its list for a row of
 # one token, all of its context for a token of a chunk); the pages of its
 # context, which dense attention would read. The fourth counts, once a
-# step, the rows whose queries all lie within `dense_len`.
+# step, the rows whose queries all lie within `dense_len`. The last two
+# count `[block, D]` K slabs, summed over the block-sparse layers: those the
+# kernel's copies bring in (a listed row's chosen pages; any other row's
+# cached pages, every KV head's, once a wide query tile that holds a token of
+# the row) and the least a walk could (the same with each page once: what
+# `MiniCPMSalaSpec.walked_slabs` tells the step span from positions alone).
+# A chunk's query still computes against every page of its context under the
+# mask, so `sparse_pages_walked` does not move with how often a page is
+# fetched.
 SPARSE_COUNTERS = ("serving/sparse_pages_selected",
                    "serving/sparse_pages_walked",
                    "serving/sparse_pages_context",
-                   "serving/sparse_dense_rows")
+                   "serving/sparse_dense_rows",
+                   "serving/sparse_slabs_fetched",
+                   "serving/sparse_slabs_least")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,8 +295,8 @@ class MiniCPMSala:
         block-sparse layers' pages; `kept` = the lightning state and the
         key tails by slot (where the model has such layers), the compressed
         keys by page, then `slots [B + 1]`. Returns (last-token logits
-        `[B + 1, V]`, kc, vc, *kept without slots, counts `[4]` in the
-        order of `SPARSE_COUNTERS`)."""
+        `[B + 1, V]`, kc, vc, *kept without slots, counts in the order of
+        `SPARSE_COUNTERS`)."""
         if mode not in (None, "fresh_prefill"):
             raise ValueError(f"MiniCPMSala has no {mode!r} step")
         s = self.spec
@@ -592,8 +603,14 @@ def _select(s, q, meta, ck, li):
     ctx_chunk = jnp.sum(jnp.where(in_chunk, ctx_tok, 0)) * hkv
     sel_rows = jnp.sum(n_sel)
     ctx_rows = jnp.sum(jnp.where(listed, (n_row + bs - 1) // bs, 0)) * hkv
+    # K slabs: a listed row's list; any other row's cached pages, once a
+    # wide query tile (fetched) or once (the least)
+    cached = jnp.where(meta["live"] & ~listed, (start + bs - 1) // bs, 0) \
+        * hkv
     counts = jnp.stack([sel_rows + picked_chunk, sel_rows + ctx_chunk,
-                        ctx_rows + ctx_chunk, jnp.zeros((), i32)]) \
-        .astype(i32)
+                        ctx_rows + ctx_chunk, jnp.zeros((), i32),
+                        sel_rows + jnp.sum(cached * wide_tiles(meta["cu"],
+                                                               this)),
+                        sel_rows + jnp.sum(cached)]).astype(i32)
     return {"listed": listed.astype(i32), "sel": sel, "n_sel": n_sel,
             "page_mask": mask}, counts

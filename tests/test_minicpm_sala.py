@@ -228,6 +228,56 @@ def test_decode_rows_past_dense_len_walk_what_they_chose():
     eng.run_to_completion()
 
 
+def test_fetched_slabs_over_the_least_count_the_wide_tiles():
+    """`serving/sparse_slabs_fetched` over `serving/sparse_slabs_least`: a
+    full chunk of 160 tokens has its row's cached pages fetched once a wide
+    query tile of 64 tokens, 3 times; a row of one token past `dense_len`
+    reads its list once. The least is what the step spans carry
+    (`walked_slabs`, from positions alone), a block-sparse layer."""
+    from paddle_tpu.ops.pallas.sparse_paged_attention import _WIDE_TOKENS
+    from paddle_tpu.profiler import tracing
+
+    spec = MiniCPMSalaSpec.from_config(
+        SIZES, published_layers=4, chunk_size=8, dtype="float32",
+        **dict(SPARSE, window_size=160, dense_len=176))
+    model = MiniCPMSala(spec, init_params(spec, seed=3, std=0.2))
+    eng = make_engine(model, token_budget=160, max_blocks_per_seq=48)
+    layers, hkv, bs = spec.count(ms.SPARSE), 2, 8
+
+    def delta(before):
+        return {k[len("serving/sparse_"):]: v - before.get(k, 0)
+                for k, v in counters().items()
+                if k.startswith("serving/sparse_slabs")}
+
+    def span_slabs():
+        return sum(s["args"]["sparse_pages_walked"]
+                   for s in tracing.ring_spans()
+                   if s["name"] == "serving::step"
+                   and "sparse_pages_walked" in (s.get("args") or {}))
+
+    (p,) = prompts(320, seed=4)
+    before = counters()
+    tracing.clear_ring()
+    eng.add_request(p, max_new_tokens=6)
+    while any(r.cached + r.ahead < len(r.prompt) for r in eng.pending()):
+        eng.step()
+    eng.settle()
+    c = delta(before)
+    # the second chunk alone has pages cached: 160 positions, 20 pages
+    assert c["slabs_least"] == layers * hkv * (160 // bs) \
+        == layers * span_slabs()
+    assert c["slabs_fetched"] == c["slabs_least"] * -(-160 // _WIDE_TOKENS)
+    before = counters()
+    tracing.clear_ring()
+    for _ in range(4):
+        eng.step()
+    eng.settle()
+    c = delta(before)
+    assert c["slabs_fetched"] == c["slabs_least"] == layers * span_slabs() \
+        > 0
+    eng.run_to_completion()
+
+
 def test_what_a_recurrent_state_rules_out_is_refused():
     with pytest.raises(ValueError, match="state-space layer"):
         make_engine(prefix_cache=True)
@@ -340,6 +390,129 @@ def test_sparse_walk_of_the_kernel_against_the_masked_reference(
                                    layer_idx=1)
     assert float(jnp.abs(dense[1:8] - want[1:8]).max()) > 1e-3
     assert float(jnp.abs(dense[0] - want[0]).max()) > 1e-3
+
+
+# -- the two walks of the kernel, one set of shapes (one lowering) -----------
+# rows as (cached positions, tokens of this step, what the tokens attend):
+# "list" a listed row of one token, "ones" every page (a row under
+# `dense_len`), "random" a mask a token, "straddle" ones for the first 40
+# tokens and a mask a token for the rest. Pages of 8 keys: a key tile of the
+# wide walk is 128 pages, one 128-lane piece of the mask.
+_WALK = dict(hq=4, hkv=2, d=128, bs=8, mb=160, rows=6, t=150, max_sel=12)
+WALKS = {
+    "chunk-under-dense_len": [(700, 100, "ones")],
+    "chunk-straddling-dense_len": [(700, 100, "straddle")],
+    "chunk-past-dense_len": [(1100, 100, "random")],
+    # 70 tokens from token 1 on: two wide tiles, neither whole; 1,093
+    # cached positions: 137 pages (a key tile and nine pages), the last one
+    # partly filled
+    "ragged-chunk-and-context": [(150, 1, "list"), (1093, 70, "random")],
+    "two-chunks-a-listed-row-between": [
+        (150, 1, "list"), (700, 60, "random"), (300, 1, "list"),
+        (400, 50, "random"), (90, 1, "list")],
+    "one-token-rows-under-dense_len": [
+        (150, 1, "list"), (333, 1, "ones"), (20, 1, "ones")],
+    "no-chunk": [(150, 1, "list"), (300, 1, "list")],
+    "no-listed-row": [(700, 100, "random"), (40, 30, "ones")],
+}
+
+
+def _walk_case(rows, seed=0):
+    """The kernel's operands for `rows` at `_WALK`'s shapes: (q, k, v, kc,
+    vc, bt, starts, cu, listed, sel, n_sel, mask), tokens no row owns at
+    the end of the pack."""
+    w = _WALK
+    hkv, bs, mb, n_rows, t = w["hkv"], w["bs"], w["mb"], w["rows"], w["t"]
+    nb = n_rows * mb + 1
+    rng = np.random.default_rng(seed)
+    f32 = jnp.float32
+    kc = jnp.asarray(rng.normal(size=(2, nb, hkv, bs, w["d"])), f32)
+    vc = jnp.asarray(rng.normal(size=(2, nb, hkv, bs, w["d"])), f32)
+    q = jnp.asarray(rng.normal(size=(t, w["hq"], w["d"])), f32)
+    k = jnp.asarray(rng.normal(size=(t, hkv, w["d"])), f32)
+    v = jnp.asarray(rng.normal(size=(t, hkv, w["d"])), f32)
+    bt = rng.permutation(np.arange(1, nb)).reshape(n_rows, mb) \
+        .astype(np.int32)
+    starts = np.zeros(n_rows, np.int32)
+    this = np.zeros(n_rows, np.int32)
+    listed = np.zeros(n_rows, np.int32)
+    sel = np.zeros((n_rows, hkv, w["max_sel"]), np.int32)
+    n_sel = np.zeros((n_rows, hkv), np.int32)
+    mask = np.ones((t, hkv, mb), bool)
+    tok = 0
+    for b, (start, n, kind) in enumerate(rows):
+        starts[b], this[b] = start, n
+        pages = -(-start // bs)
+        if kind == "list":
+            listed[b] = 1
+            for h in range(hkv):
+                m = int(rng.integers(3, w["max_sel"]))
+                lst = [0, pages - 1] + rng.choice(
+                    np.arange(1, pages - 1), m - 2, replace=False).tolist()
+                sel[b, h, :m], n_sel[b, h] = lst, m
+        elif kind != "ones":
+            chosen = rng.random((n, hkv, mb)) < 0.4
+            chosen[:, :, max(pages - 3, 0):] = True
+            if kind == "straddle":
+                chosen[:40] = True
+            mask[tok:tok + n] = chosen
+        tok += n
+    cu = np.concatenate([[0], np.cumsum(this)]).astype(np.int32)
+    return (q, k, v, kc, vc) + tuple(
+        jnp.asarray(x) for x in (bt, starts, cu, listed, sel, n_sel, mask))
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_wide_and_list_walks_against_the_masked_reference(case, monkeypatch):
+    """`sparse_paged_attention` in interpret mode, a row's walk chosen by
+    `listed` on the device: chunks under, across and past `dense_len` in
+    wide query tiles and key tiles that neither the chunk nor the context
+    fills, rows of one token on either walk, each walk with nothing to do;
+    against the gathered formulation over the written caches. A token no
+    row owns reads 0."""
+    from paddle_tpu.ops.pallas import kv_page_write as kw
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.ops.pallas import sparse_paged_attention as spa
+
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    q, k, v, kc, vc, bt, starts, cu, listed, sel, n_sel, mask = \
+        _walk_case(WALKS[case])
+    full = spa.page_mask_of_lists(mask, sel, n_sel, listed, cu[:-1])
+    got = spa.sparse_paged_attention(q, k, v, kc, vc, bt, starts, cu, listed,
+                                     sel, n_sel, mask, layer_idx=1)
+    kc2, vc2 = kw.kv_page_write_ref(kc, vc, k, v, bt, starts, cu,
+                                    layer_idx=1)
+    want = pa.paged_attention_ref(q, kc2, vc2, bt, starts, cu, layer_idx=1,
+                                  page_mask=full)
+    n = int(cu[-1])
+    np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
+                               atol=2e-5)
+    assert not np.asarray(got[n:]).any()
+    if any(kind in ("random", "straddle") for _, _, kind in WALKS[case]):
+        dense = pa.paged_attention_ref(q, kc2, vc2, bt, starts, cu,
+                                       layer_idx=1)
+        assert float(jnp.abs(dense[:n] - want[:n]).max()) > 1e-3
+
+
+def test_list_walk_is_bit_for_bit_the_kernel_before_the_wide_tiles(
+        monkeypatch):
+    """A decode-only step (listed rows of one token, no other row): the
+    kernel's output equals, bit for bit, that of the kernel as PR 34 left
+    it (`tests/sparse_walk_pr34.py`, every row in narrow query blocks),
+    both interpreted on this machine."""
+    import sparse_walk_pr34 as before
+
+    from paddle_tpu.ops.pallas import sparse_paged_attention as spa
+
+    rows = [(150, 1, "list"), (1093, 1, "list"), (700, 1, "list"),
+            (90, 1, "list"), (1270, 1, "list")]
+    args = _walk_case(rows, seed=5)
+    layer = jnp.full((1,), 1, jnp.int32)
+    got = spa._sparse_call(*args, layer, interpret=True)
+    was = before._sparse_call(*args, layer, interpret=True)
+    n = len(rows)
+    assert float(jnp.abs(was[:n]).max()) > 0.1
+    np.testing.assert_array_equal(np.asarray(got[:n]), np.asarray(was[:n]))
 
 
 def test_engine_through_the_kernels_in_interpret_mode(monkeypatch):

@@ -282,12 +282,37 @@ def test_sparse_step_holds_its_kernels_and_copies_no_stack(one_chip,
         s((b1, mb)), cache, cache, *kept, s((b1,)))
     compiled = lowered.compile()
     hlo = compiled.as_text()
-    assert hlo.count("%sparse_paged_attention") >= 1
+    # ONE call a block-sparse layer, list walk and wide walk inside it, with
+    # the operands the roofline's work file tells the kernel by
+    (call,) = [ln.strip() for ln in hlo.splitlines()
+               if ln.lstrip().startswith("%sparse_paged_attention")
+               and " custom-call(" in ln]
+    from benchmark.work import sparse_paged_attention as work_file
+
+    flops, nbytes = work_file.work(_with_operand_shapes(call), {}, rows=1)
+    assert flops == 4 * 128 * 64 * 16 and nbytes == 2 * 64 * 128 * 2
     calls = chip_smoke.kernel_calls_in(hlo)
     assert calls["ssm_state_update"] == calls["kv_page_write"] == 1
     for stack in [cache] + kept:
         assert chip_smoke.whole_array_copies_in(hlo, stack) == 0
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def _with_operand_shapes(call):
+    """A compiled custom call's text as a trace's event names it: each
+    operand with its shape (the compiled text lists the shapes under
+    `operand_layout_constraints`, in the operands' order)."""
+    import re
+
+    head, rest = call.split(" custom-call(", 1)
+    names, tail = rest.split("), custom_call_target=", 1)
+    shapes = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", tail.split(
+        "operand_layout_constraints={", 1)[1].split("}}", 1)[0] + "}")
+    names = [n.split("*/")[-1] for n in names.split(", ")]
+    assert len(shapes) == len(names)
+    return (f"{head} custom-call("
+            + ", ".join(f"{s} {n}" for s, n in zip(shapes, names))
+            + "), custom_call_target=" + tail)
 
 
 PAGED_ENGINE = dict(vocab_size=512, hidden_size=512, num_layers=2,
